@@ -61,7 +61,14 @@ def _build() -> Optional[str]:
             f.write(want)
         os.replace(tmp_stamp, stamp)
         return _SO
-    except Exception:
+    except Exception as e:
+        # a fresh checkout ships no .so (.gitignore): the build is the
+        # normal path, so a failed one must be said, not swallowed
+        import warnings
+        warnings.warn(
+            f"apex_tpu._native: could not build libapex_c.so with g++ "
+            f"({type(e).__name__}: {e}); the NumPy fallbacks engage",
+            RuntimeWarning, stacklevel=2)
         return None
 
 

@@ -38,15 +38,10 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Literal
 
 from apex_tpu.amp import lists
 from apex_tpu.amp.policies import Policy
-
-# jax.extend.core is the supported home for jaxpr types in newer jax
-try:
-    from jax.extend.core import ClosedJaxpr, Literal
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Literal
 
 
 def _is_float(x) -> bool:

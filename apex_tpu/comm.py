@@ -351,18 +351,9 @@ def axis_size(name: str) -> int:
 
 
 def bound_axis_size(name: str) -> int:
-    """Size of a BOUND axis from inside traced code, version-compat.
-
-    ``jax.lax.axis_size`` only exists on newer jax releases (0.4.x
-    raises AttributeError — the single bug behind every parallel/
-    pipeline tier-1 failure of the seed).  ``psum`` of the literal 1 is
-    the portable spelling: jax evaluates it statically in the axis env
-    on every release, so the result is a Python int usable in shape
-    math (loop trip counts, buffer sizes) exactly like axis_size."""
-    ax = getattr(jax.lax, "axis_size", None)
-    if ax is not None:
-        return ax(name)
-    return jax.lax.psum(1, name)
+    """Size of a BOUND axis from inside traced code: a Python int
+    usable in shape math (loop trip counts, buffer sizes)."""
+    return jax.lax.axis_size(name)
 
 
 def data_parallel_size() -> int:
@@ -395,14 +386,8 @@ def num_devices() -> int:
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat ``shard_map``: jax>=0.8 `jax.shard_map(check_vma=)`,
-    older releases `jax.experimental.shard_map(check_rep=)`.  Single home
-    for the shim used by the package, tests, examples, and the driver
+    """``jax.shard_map`` with replication checking off: the single
+    spelling used by the package, tests, examples and the driver
     entry."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (TypeError, AttributeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -11,10 +11,15 @@ Spawns ``nproc`` worker processes with the launcher env contract set —
 use passes ``--nnodes``/``--node-rank``/``--coordinator`` so every node
 agrees on the rendezvous (rank = node_rank * nproc + local_rank).
 
-On TPU pods this launcher is usually unnecessary — the pod runtime
-announces itself and ``initialize_distributed()`` autodetects — but
-CPU/GPU-style multi-process development, CI, and the reference's
-launch idiom port 1:1 through it.
+TPU use is ONE PROCESS PER HOST: a chip belongs to one process at a
+time and the launcher assigns no chips to its children, so ``--nproc N``
+with N > 1 on one TPU host would have every child claim every chip.
+On a single TPU host run the script directly — one process drives the
+whole mesh of the host's chips (README "Scope").  Across TPU hosts,
+start one launcher per host with ``--nproc 1 --nnodes H --node-rank r
+--coordinator host:port``.  ``--nproc N`` is for CPU/GPU-style
+multi-process development, CI (workers pin ``JAX_PLATFORMS=cpu``) and
+the reference's launch idiom.
 """
 
 from __future__ import annotations
@@ -64,6 +69,14 @@ def main(argv=None) -> int:
     if args.nnodes > 1 and not args.coordinator:
         ap.error("--coordinator host:port is required with --nnodes>1 "
                  "(every node must name the same rendezvous)")
+    if args.nproc > 1 and os.environ.get(
+            "JAX_PLATFORMS", "").split(",")[0] in ("", "tpu"):
+        print(f"apex_tpu.launch: --nproc {args.nproc} starts "
+              f"{args.nproc} processes on this host and assigns them no "
+              "chips: on a TPU host each would claim every chip and all "
+              "but one fail or hang.  TPU use is one process per host "
+              "(--nproc 1); multi-process workers must pin another "
+              "backend (JAX_PLATFORMS=cpu).", file=sys.stderr)
     coordinator = args.coordinator or f"127.0.0.1:{_free_port()}"
     world = args.nnodes * args.nproc
 

@@ -65,10 +65,14 @@ def primitive_counts(jaxpr) -> collections.Counter:
 
 
 def concat_out_shapes(jaxpr) -> List[Tuple[int, ...]]:
-    """Output shapes of every ``concatenate`` — the gradient-pack
-    signature: a pack shows up as exactly one bucket-sized concat."""
+    """Output shapes of every FLOATING ``concatenate`` — the
+    gradient-pack signature: a pack shows up as exactly one bucket-sized
+    concat.  Integer concatenates are not packs (the bucket plan builds
+    its segment-id map in-program as one)."""
+    import jax.numpy as jnp       # bf16-aware, unlike numpy's issubdtype
     return [tuple(e.outvars[0].aval.shape) for e in iter_eqns(jaxpr)
-            if e.primitive.name == "concatenate"]
+            if e.primitive.name == "concatenate"
+            and jnp.issubdtype(e.outvars[0].aval.dtype, jnp.floating)]
 
 
 def host_transfer_prims(jaxpr) -> List[str]:

@@ -6,10 +6,12 @@ a Pallas kernel compiles for hardware or runs interpreted (CPU CI), and
 whether to prefer the plain-XLA path where fusion already wins.
 
 Measured preferences are **per topology** since the autotuner
-(tools/autotune.py): next to the shipped default table
-(``dispatch_prefs.json``, topology-agnostic) the ops directory may hold
+(tools/autotune.py): the ops directory may hold a default table
+(``dispatch_prefs.json``, topology-agnostic) and
 ``dispatch_prefs.<topology>.json`` files, one per (chip kind, device
-count) the sweep has measured.  At dispatch time the runtime topology
+count) a sweep has measured.  The repo ships NONE: no table means the
+design defaults (prefer Pallas, static block geometry), which is also
+what every tier-1 test exercises (tests/conftest.py).  At dispatch time the runtime topology
 selects the matching table; a missing or untrusted per-topology table
 falls back to the default table with the existing loud-warning
 discipline (a discarded table must never be silent — the operator
@@ -241,9 +243,9 @@ def _normalize_doc(raw: dict, source: Optional[str]) -> DispatchTables:
 
 def _trusted(raw: dict, where: str, runtime_topo: Optional[str]) -> bool:
     """The two discard rules, both loud (RuntimeWarning, once per
-    message): tables without the amortized-methodology stamp measured
-    the relay round trip, not the kernels (pre-benchlib era — every
-    sub-ms body timed at the 10-19 ms RTT regardless of shape), and a
+    message): tables without the amortized-methodology stamp timed one
+    dispatch per iteration, so every sub-ms body measured the dispatch
+    overhead and not the kernel (apex_tpu/benchlib.py), and a
     table measured on a DIFFERENT topology must not steer this one
     (block geometry and collective decomposition winners do not
     transfer between chip generations or fleet sizes)."""
@@ -493,16 +495,16 @@ def on_tpu() -> bool:
 
 
 def interpret_mode() -> bool:
-    """True when pallas_call must run interpreted (non-TPU backends)."""
-    if os.environ.get("APEX_TPU_FORCE_MOSAIC", "") == "1":
-        # AOT TPU lowering on a CPU host: Mosaic kernel serialization and
-        # its verifier run at lowering time, no device needed
-        # (tests/test_tpu_lowering.py) — checked per call so tests can
-        # flip it with monkeypatch
+    """True when pallas_call must run interpreted: a CPU-test facility
+    only.  On a TPU backend nothing selects it — the kernels compile
+    through Mosaic or the program fails."""
+    if on_tpu():
         return False
-    if os.environ.get("APEX_TPU_PALLAS_INTERPRET", "") == "1":
-        return True
-    return not on_tpu()
+    # AOT TPU lowering/compiling on a CPU host (tests/test_tpu_lowering.py,
+    # tests/test_tpu_compile.py): Mosaic serialization and its verifier
+    # run at lowering time, no device needed — checked per call so tests
+    # can flip it with monkeypatch
+    return os.environ.get("APEX_TPU_FORCE_MOSAIC", "") != "1"
 
 
 def pallas_enabled() -> bool:

@@ -43,12 +43,7 @@ from apex_tpu import comm
 from apex_tpu.ops import _dispatch
 from apex_tpu.ops._dispatch import interpret_mode, op_enabled
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; support
-# both so the kernels trace on either side of the rename (the old name
-# is what CPU CI ships; BENCH_r05 caught the new-name-only spelling
-# crashing every flash bench leg on the 0.4.x interpreter path)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+_CompilerParams = pltpu.CompilerParams
 
 _NEG = -1e30
 _LANES = 128

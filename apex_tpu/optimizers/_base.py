@@ -40,47 +40,18 @@ from apex_tpu.telemetry import _tape
 Pytree = Any
 tree_map = jax.tree_util.tree_map
 
-# in-jit "move to device memory" marker: jax.memory.Space.Device where it
-# exists, else the older TransferToMemoryKind spelling
-try:
-    _DEVICE_MEMORY = jax.memory.Space.Device
-except AttributeError:
-    try:
-        from jax.sharding import TransferToMemoryKind as _TTMK
-    except ImportError:  # pre-public spelling
-        from jax._src.sharding_impls import TransferToMemoryKind as _TTMK
-    _DEVICE_MEMORY = _TTMK("device")
-
-
-def _memory_kinds(x: jax.Array):
-    dev = next(iter(x.sharding.device_set))
-    try:
-        return {m.kind for m in dev.addressable_memories()}
-    except Exception:
-        return set()
+# in-jit "move to device memory" marker
+_DEVICE_MEMORY = jax.memory.Space.Device
 
 
 def _host_sharding(x: jax.Array):
-    """The array's own sharding, re-homed to host memory: pinned_host
-    (the TPU host-offload target) where the backend exposes it, else
-    unpinned_host (what older-jax CPU backends call their only space)."""
-    kinds = _memory_kinds(x)
-    if "pinned_host" in kinds:
-        return x.sharding.with_memory_kind("pinned_host")
-    if "unpinned_host" in kinds:
-        return x.sharding.with_memory_kind("unpinned_host")
-    return x.sharding
+    """The array's own sharding, re-homed to pinned host memory (the
+    TPU host-offload target; the CPU backend exposes it too)."""
+    return x.sharding.with_memory_kind("pinned_host")
 
 
 def _device_sharding(x: jax.Array):
-    kinds = _memory_kinds(x)
-    if "device" in kinds:
-        return x.sharding.with_memory_kind("device")
-    dev = next(iter(x.sharding.device_set))
-    try:
-        return x.sharding.with_memory_kind(dev.default_memory().kind)
-    except Exception:
-        return x.sharding
+    return x.sharding.with_memory_kind("device")
 
 
 def place_on_host(tree: Pytree) -> Pytree:
@@ -97,12 +68,30 @@ def place_on_device(tree: Pytree) -> Pytree:
         if isinstance(x, jax.Array) else x, tree)
 
 
+def _replica_mesh(tree: Pytree):
+    """The mesh ``tree``'s arrays are replicated over, or None for the
+    single-device case (decided on the first array leaf: the gradients
+    of one step share a placement)."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is None or len(sharding.device_set) <= 1:
+            return None
+        mesh = getattr(sharding, "mesh", None)
+        if mesh is None:
+            from apex_tpu import comm
+            mesh = comm.mesh()
+        return mesh
+    return None
+
+
 def _device_copy(buf: jax.Array) -> jax.Array:
-    """Async device-side copy of one flat buffer (dispatch returns
-    immediately).  The bucket-native checkpoint path routes every copy
-    through this seam so tests can assert structurally that a packed
-    snapshot is exactly one copy per buffer and nothing else."""
-    return buf.copy()
+    """Async copy of one flat buffer within its own memory space
+    (dispatch returns immediately; ``buf.copy()`` would land a
+    host-offloaded buffer in device memory).  The bucket-native
+    checkpoint path routes every copy through this seam so tests can
+    assert structurally that a packed snapshot is exactly one copy per
+    buffer and nothing else."""
+    return jax.device_put(buf, buf.sharding, may_alias=False)
 
 
 def unzip_tree(like: Pytree, tree_of_tuples: Pytree, n: int):
@@ -238,6 +227,7 @@ class FusedOptimizerBase:
             self.opt_state = self.init_state(work)
             self._full_step_impl = self._full_step
         self.step_count = jnp.int32(0)
+        self._mesh_steps: Dict[Any, Any] = {}
         # Host-offloaded optimizer state (beyond-reference; the HBM
         # relief the reference gets from ZeRO sharding alone).  On TPU
         # the step is ONE program: state transfers in from pinned host,
@@ -589,28 +579,23 @@ class FusedOptimizerBase:
             grads = grads.bufs
         grad_scale = _fold_clip(grad_scale, clip_coef)
         self.step_count = self.step_count + 1
-        state = self.opt_state
         eager_offload = self.offload_state and not self._fused_offload
+        args = self._step_args(grads, grad_scale, found_inf)
         if eager_offload:   # CPU fallback: explicit round trip
-            state = place_on_device(state)
-        traced_hypers = {
-            k: jnp.asarray(v, jnp.float32) if isinstance(v, float) else v
-            for k, v in self.hypers.items()
-            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+            args = args[:2] + (place_on_device(args[2]),) + args[3:]
+        # bucketed path only: its buffers are whole by construction
+        # (the packer declines sharded leaves), and it is the one that
+        # runs Mosaic kernels; per-leaf math partitions under plain jit
+        mesh = _replica_mesh(grads) if self._plan is not None else None
+        step_fn = (self._jit_step if mesh is None
+                   else self._replicated_step(mesh))
+        new_params, new_masters, self.opt_state = step_fn(*args)
         if self._plan is not None:
-            self._param_bufs, self._master_bufs, self.opt_state = \
-                self._jit_step(self._param_bufs, self._master_bufs, state,
-                               grads, self.step_count,
-                               jnp.asarray(grad_scale, jnp.float32),
-                               traced_hypers, found_inf)
+            self._param_bufs, self._master_bufs = new_params, new_masters
             self._params_cache = None
             self._masters_cache = None
         else:
-            self._params_tree, self._masters_tree, self.opt_state = \
-                self._jit_step(self._params_tree, self._masters_tree, state,
-                               grads, self.step_count,
-                               jnp.asarray(grad_scale, jnp.float32),
-                               traced_hypers, found_inf)
+            self._params_tree, self._masters_tree = new_params, new_masters
         if eager_offload:
             self.opt_state = place_on_host(self.opt_state)
         if found_inf is not None:
@@ -619,6 +604,38 @@ class FusedOptimizerBase:
                                         self.step_count - 1,
                                         self.step_count)
         return self.params
+
+    def _replicated_step(self, mesh):
+        """The step program for gradients that arrive replicated over
+        ``mesh`` — the data-parallel layout: every device holds the
+        full params and runs the same update after the reduction.
+        Mosaic kernels cannot be partitioned automatically, so the same
+        body runs under ``shard_map`` with every operand replicated
+        (a plain multi-device jit refuses the flat kernels outright)."""
+        fn = self._mesh_steps.get(mesh)
+        if fn is None:
+            spec = jax.sharding.PartitionSpec()
+            fn = self._mesh_steps[mesh] = jax.jit(
+                jax.shard_map(self._full_step_impl, mesh=mesh,
+                              in_specs=spec, out_specs=spec,
+                              check_vma=False),
+                donate_argnums=(2,))
+        return fn
+
+    def _step_args(self, grads, grad_scale=1.0, found_inf=None):
+        """The positional arguments of one ``_jit_step`` call on the
+        current state (``step()`` builds its call with this; lowering
+        the step program for inspection needs exactly the same)."""
+        traced_hypers = {
+            k: jnp.asarray(v, jnp.float32) if isinstance(v, float) else v
+            for k, v in self.hypers.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+        params, masters = ((self._param_bufs, self._master_bufs)
+                           if self._plan is not None else
+                           (self._params_tree, self._masters_tree))
+        return (params, masters, self.opt_state, grads, self.step_count,
+                jnp.asarray(grad_scale, jnp.float32), traced_hypers,
+                found_inf)
 
     def zero_grad(self):
         """No-op for parity: JAX grads are freshly computed, never stored."""

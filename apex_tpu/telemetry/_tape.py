@@ -43,29 +43,17 @@ import jax.numpy as jnp
 _REDUCES = ("last", "max", "sum", "rss")
 
 
-def _current_trace():
-    """The active trace object (identity is the capture-safety token),
-    or None where this jax version hides it — then the coarser
-    trace_state_clean fallback below applies."""
-    try:
-        from jax._src import core as _core
-        return _core.trace_ctx.trace
-    except Exception:
-        return None
-
-
 class Tape:
     """One step's collected metrics (name -> traced f32 scalar)."""
 
-    __slots__ = ("values", "trace", "traced")
+    __slots__ = ("values", "trace")
 
     def __init__(self):
         self.values: Dict[str, jax.Array] = {}
         # the trace this tape belongs to: only tracers of THIS trace
         # may be captured (anything else would escape its trace when
         # the instrument wrapper writes the ring)
-        self.trace = _current_trace()
-        self.traced = not jax.core.trace_state_clean()
+        self.trace = jax.core.trace_ctx.trace
 
 
 # THREAD-LOCAL, like pyprof.nvtx's range stack and for the same
@@ -112,17 +100,11 @@ def emit(name: str, value, reduce: str = "last") -> None:
     if not stack:
         return
     tape = stack[-1]
-    if isinstance(value, jax.core.Tracer):
-        cur = _current_trace()
-        if cur is not None and tape.trace is not None:
-            if cur is not tape.trace:
-                # foreign trace (nested jit / transform): capturing
-                # would leak the tracer (module docstring)
-                return
-        elif not tape.traced:
-            # fallback on jax versions without trace identity: an
-            # eager tape never captures tracers
-            return
+    if (isinstance(value, jax.core.Tracer)
+            and jax.core.trace_ctx.trace is not tape.trace):
+        # foreign trace (nested jit / transform): capturing would leak
+        # the tracer (module docstring)
+        return
     v = jnp.asarray(value, jnp.float32)
     old = tape.values.get(name)
     if old is None or reduce == "last":

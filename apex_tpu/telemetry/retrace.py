@@ -3,12 +3,11 @@
 apexlint's APX301-303 flag retrace *hazards* statically; this module
 counts retraces that actually happen at run time.  Two hooks:
 
-- ``jax.monitoring`` (where available): JAX stamps every trace /
+- ``jax.monitoring``: JAX stamps every trace /
   lowering / backend compile with a
   ``/jax/core/compile/...`` duration event; a registered listener
   counts them (and accumulates compile seconds) process-wide.  These
-  events carry no function identity in the jax versions we support,
-  so they answer "how much compiling is this run doing", not "who".
+  events carry no function identity, so they answer "how much compiling is this run doing", not "who".
 - ``wrap(fn, name)``: the per-function fallback.  The wrapper bumps
   ``counts[name]`` from INSIDE the function body, so under ``jax.jit``
   it fires exactly once per trace (a cache hit never re-enters the
@@ -30,6 +29,9 @@ import threading
 from typing import Dict, List, Optional
 
 COMPILE_EVENT_PREFIX = "/jax/core/compile"
+# one per program handed to the backend compiler (a persistent-cache
+# hit included): ``events[BACKEND_COMPILE_EVENT]`` counts compilations
+BACKEND_COMPILE_EVENT = COMPILE_EVENT_PREFIX + "/backend_compile_duration"
 
 
 class RetraceCounter:
@@ -47,15 +49,11 @@ class RetraceCounter:
 
     # ---- jax.monitoring hook --------------------------------------------
     def install(self) -> bool:
-        """Register the process-wide compile-event listener; returns
-        False (and stays a no-op) on jax versions without
-        ``jax.monitoring``.  Idempotent."""
+        """Register the process-wide compile-event listener.
+        Idempotent; always returns True."""
         if self._listener is not None:
             return True
-        try:
-            from jax import monitoring
-        except ImportError:
-            return False
+        from jax import monitoring
 
         def _on_duration(event, duration, **kwargs):
             if event.startswith(COMPILE_EVENT_PREFIX):
@@ -70,14 +68,8 @@ class RetraceCounter:
     def uninstall(self) -> None:
         if self._listener is None:
             return
-        try:
-            from jax._src import monitoring as _m
-            _m._unregister_event_duration_listener_by_callback(
-                self._listener)
-        except Exception:
-            # no public unregister on this jax: the dangling listener
-            # only increments dead counters, which is harmless
-            pass
+        from jax import monitoring
+        monitoring.unregister_event_duration_listener(self._listener)
         self._listener = None
 
     # ---- per-function wrapper -------------------------------------------

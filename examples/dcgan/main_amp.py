@@ -60,8 +60,9 @@ def main():
     p.add_argument("--cpu", action="store_true",
                    help="force the CPU backend (see apex_tpu.platform)")
     args = p.parse_args()
-    from apex_tpu.platform import select_platform
-    select_platform("cpu" if args.cpu else None)
+    if args.cpu:
+        from apex_tpu.platform import select_platform
+        select_platform("cpu")
 
     netG, netD = Generator(), Discriminator()
     z0 = jnp.zeros((args.batch_size, args.nz))

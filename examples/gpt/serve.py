@@ -116,8 +116,6 @@ def parse_sample(spec):
 
 def main(argv=None):
     args = parse_args(argv)
-    from apex_tpu.platform import select_platform
-    select_platform()          # honor APEX_TPU_PLATFORM (e.g. cpu)
     print(f"apex_tpu {apex_tpu.__version__} serving on "
           f"{jax.default_backend()}")
 

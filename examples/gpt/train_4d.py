@@ -3,7 +3,7 @@ parallelism with interleaved-1F1B pipelining — the full apex_tpu
 distributed stack in one user-facing script (reference scope:
 apex/transformer used from Megatron-style pretraining loops).
 
-    APEX_TPU_PLATFORM=cpu python examples/gpt/train_4d.py \
+    JAX_PLATFORMS=cpu python examples/gpt/train_4d.py \
         [--dp 2 --pp 2 --tp 2] [--virtual 2] [--steps 30]
 
 Axes:
@@ -43,15 +43,12 @@ def main():
     args = ap.parse_args()
     n = args.dp * args.pp * args.tp
 
-    if os.environ.get("APEX_TPU_PLATFORM") == "cpu":
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
         flags = os.environ.get("XLA_FLAGS", "")
         if "--xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 flags + f" --xla_force_host_platform_device_count={n}"
             ).strip()
-    from apex_tpu.platform import select_platform
-    select_platform()
-
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P

@@ -93,7 +93,8 @@ def parse_args():
     p.add_argument("--batch-size", type=int, default=0)
     p.add_argument("--lr", type=float, default=3e-4)
     p.add_argument("--cpu", action="store_true",
-                   help="force the CPU backend (see apex_tpu.platform)")
+                   help="the small CPU proxy the tests run: CPU "
+                        "backend, small sizes unless given")
     p.add_argument("--offload-activations", action="store_true",
                    help="remat blocks with the ffn hidden streamed to "
                         "pinned host memory (apex_tpu.offload); "
@@ -103,15 +104,18 @@ def parse_args():
 
 def main():
     args = parse_args()
-    from apex_tpu.platform import select_platform
-    select_platform("cpu" if args.cpu else None)
-    on_tpu = jax.default_backend() == "tpu"
-    layers = args.layers or (12 if on_tpu else 2)
-    hidden = args.hidden or (768 if on_tpu else 128)
-    heads = args.heads or (12 if on_tpu else 4)
-    seq = args.seq_len or (512 if on_tpu else 64)
-    batch = args.batch_size or (8 if on_tpu else 2)
-    vocab = 2048 if not on_tpu else 50257
+    if args.cpu:
+        from apex_tpu.platform import select_platform
+        select_platform("cpu")
+        print("--cpu: CPU backend, small proxy sizes (L2 h128 b2 s64 "
+              "vocab 2048 unless given)")
+    small = args.cpu
+    layers = args.layers or (2 if small else 12)
+    hidden = args.hidden or (128 if small else 768)
+    heads = args.heads or (4 if small else 12)
+    seq = args.seq_len or (64 if small else 512)
+    batch = args.batch_size or (2 if small else 8)
+    vocab = 2048 if small else 50257
 
     model = GPTBlocks(vocab, hidden, heads, layers, max_seq=max(seq, 128),
                       offload_activations=args.offload_activations)

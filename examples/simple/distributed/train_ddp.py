@@ -37,8 +37,6 @@ class SmallNet(nn.Module):
 
 
 def main():
-    from apex_tpu.platform import select_platform
-    select_platform()          # honor APEX_TPU_PLATFORM (e.g. cpu)
     n = len(jax.devices())
     comm.initialize(data=n, pipe=1, ctx=1, model=1)
     mesh = comm.mesh()

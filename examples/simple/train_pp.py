@@ -36,15 +36,13 @@ def main():
                     help="virtual chunks per stage (0: non-interleaved)")
     args = ap.parse_args()
     import os
-    from apex_tpu.platform import select_platform
-    if os.environ.get("APEX_TPU_PLATFORM") == "cpu":
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
         # virtual 8-device CPU mesh (must precede first backend use)
         flags = os.environ.get("XLA_FLAGS", "")
         if "--xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8"
             ).strip()
-    select_platform()          # honor APEX_TPU_PLATFORM (e.g. cpu)
     mesh = comm.initialize(data=2, pipe=4)
     pp = comm.pipeline_parallel_size()
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} on "

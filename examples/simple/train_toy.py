@@ -134,8 +134,6 @@ def parse_args(argv=None):
 def main(argv=None):
     args = parse_args(argv)
 
-    from apex_tpu.platform import select_platform
-    select_platform()          # honor APEX_TPU_PLATFORM (e.g. cpu)
     print(f"apex_tpu {apex_tpu.__version__} on {jax.default_backend()}")
     key = jax.random.key(0)
     params = init_params(key)

@@ -17,8 +17,6 @@ IN, HID = 32, 64
 
 
 def main():
-    from apex_tpu.platform import select_platform
-    select_platform()          # honor APEX_TPU_PLATFORM (e.g. cpu)
     mesh = comm.initialize(data=2, model=4)
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} on "
           f"{jax.default_backend()}")

@@ -96,6 +96,7 @@ SUITES = {
     "run_profiler": ["tests/test_profiler.py"],
     # AOT Mosaic lowering for the TPU platform — runs in CPU CI
     "run_tpu_lowering": ["tests/test_tpu_lowering.py"],
+    "run_tpu_compile": ["tests/test_tpu_compile.py"],
     # TPU-only: needs APEX_TPU_SMOKE=1 and a real chip (else skips)
     "run_tpu_smoke": ["tests/test_tpu_smoke.py"],
 }

@@ -652,7 +652,7 @@ def test_dispatch_prefs_attn_caps_parse(tmp_path, monkeypatch):
     assert caps == {"128": 256, "256": 512}
 
     # a table without the amortized-methodology stamp is provisional
-    # (pre-amortization runs timed the relay RTT, not the kernels —
+    # (pre-amortization runs timed the dispatch, not the kernels —
     # routing AND cap winners alike were drawn from noise): the whole
     # table is inert until a re-measure stamps it
     p.write_text(_json.dumps({

@@ -545,7 +545,6 @@ def test_cpu_smoke_end_to_end(tmp_path, monkeypatch):
     monkeypatch.setattr(_dispatch, "_PIPELINE", None)
     monkeypatch.setattr(_dispatch, "_INSTALLED", None)
     monkeypatch.setattr(_dispatch, "_CACHE", None)
-    monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
     out = tmp_path / "autotune"
 
     assert at.main(["--cpu-smoke", "--out", str(out)]) == 0

@@ -39,7 +39,7 @@ def _clean_env() -> dict:
     for k in ("XLA_FLAGS", "JAX_COORDINATOR_ADDRESS",
               "COORDINATOR_ADDRESS", "WORLD_SIZE", "RANK",
               "NUM_PROCESSES", "PROCESS_ID", "JAX_PLATFORMS",
-              "APEX_TPU_PLATFORM", "APEX_TPU_SMOKE"):
+              "APEX_TPU_SMOKE"):
         env.pop(k, None)
     return env
 

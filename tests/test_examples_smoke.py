@@ -550,9 +550,14 @@ def test_imagenet_preempt_and_resume(tmp_path, capsys):
     assert "resumed at step 4" in out and "(step 6)" in out
 
 
+# --steps 3: the examples time from after their 2 warm-up steps (the
+# compile step and the second-call variant), so 3 is the least that
+# prints a throughput line
+
+
 def test_imagenet_tiny_cpu(capsys):
     _run("examples/imagenet/main_amp.py",
-         ["--cpu", "--steps", "2", "--batch-size", "2",
+         ["--cpu", "--steps", "3", "--batch-size", "2",
           "--image-size", "32", "--arch", "resnet18"])
     assert "throughput" in capsys.readouterr().out
 
@@ -562,7 +567,7 @@ def test_imagenet_grad_accum_flat(capsys):
     # drives the same loop — 2 microbatches per step, fused adds, the
     # latched found_inf feeding the branch-free skip
     _run("examples/imagenet/main_amp.py",
-         ["--cpu", "--steps", "2", "--batch-size", "4",
+         ["--cpu", "--steps", "3", "--batch-size", "4",
           "--image-size", "32", "--arch", "resnet18",
           "--grad-accum", "2"])
     out = capsys.readouterr().out
@@ -573,7 +578,7 @@ def test_imagenet_space_to_depth_stem(capsys):
     # the MXU-efficient stem bench.py enables on hardware, reachable
     # from the reference-shaped CLI too
     _run("examples/imagenet/main_amp.py",
-         ["--cpu", "--steps", "2", "--batch-size", "2",
+         ["--cpu", "--steps", "3", "--batch-size", "2",
           "--image-size", "32", "--arch", "resnet18",
           "--stem-space-to-depth"])
     assert "throughput" in capsys.readouterr().out
@@ -589,14 +594,14 @@ def test_dcgan_two_scalers(capsys):
 @pytest.mark.slow
 def test_bert_pretrain_mlm_tiny(capsys):
     _run("examples/bert/pretrain_mlm.py",
-         ["--cpu", "--steps", "2"])
+         ["--cpu", "--steps", "3"])
     assert "step time" in capsys.readouterr().out
 
 
 @pytest.mark.slow
 def test_bert_pretrain_mlm_packed(capsys):
     _run("examples/bert/pretrain_mlm.py",
-         ["--cpu", "--steps", "2", "--packed"])
+         ["--cpu", "--steps", "3", "--packed"])
     out = capsys.readouterr().out
     assert "packed" in out and "step time" in out
 

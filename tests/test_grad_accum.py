@@ -601,7 +601,7 @@ def test_registered_overlap_and_accum_specs_pass():
 
 def test_lhs_flags_withheld_unless_tpu_target(monkeypatch):
     from apex_tpu import platform
-    monkeypatch.setenv("APEX_TPU_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     prov = platform.enable_latency_hiding_scheduler()
     assert prov["applied"] is False
     assert prov["xla_flags_added"] == []
@@ -610,7 +610,6 @@ def test_lhs_flags_withheld_unless_tpu_target(monkeypatch):
     # no platform selection at all (the common non-TPU machine):
     # withheld too — "default" must never get TPU-only XLA_FLAGS that
     # a non-TPU backend could reject at init
-    monkeypatch.delenv("APEX_TPU_PLATFORM", raising=False)
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     prov = platform.enable_latency_hiding_scheduler()
     assert prov["applied"] is False and prov["xla_flags_added"] == []
@@ -621,7 +620,7 @@ def test_lhs_flags_appended_idempotently_for_tpu_target(monkeypatch):
     import warnings
 
     from apex_tpu import platform
-    monkeypatch.setenv("APEX_TPU_PLATFORM", "tpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
     monkeypatch.setenv("XLA_FLAGS", "--xla_something_else=1")
     monkeypatch.delenv("LIBTPU_INIT_ARGS", raising=False)
     with warnings.catch_warnings():

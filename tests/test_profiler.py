@@ -599,8 +599,8 @@ def test_gate_main_exit_codes_and_report_mode(tmp_path, capsys):
 
 
 def test_gate_clean_on_committed_trajectory():
-    """The acceptance criterion: zero exit on the repo's own BENCH
-    trajectory with the shipped budget."""
+    """Zero exit on the repo's own (now empty: the old rounds were
+    removed in PR 21) BENCH trajectory with the shipped budget."""
     assert perf_gate.main(["--json"]) == 0
 
 
@@ -608,23 +608,40 @@ def test_gate_clean_on_committed_trajectory():
 # bench.py structured errors (satellite)
 
 
-def test_bench_structured_errors_and_renderer():
+def test_bench_structured_errors():
     bench = _load_path("bench_mod", os.path.join(_ROOT, "bench.py"))
     e = bench._err("resnet50", "train_bench", "OOM at b256")
     assert e == {"leg": "resnet50", "stage": "train_bench",
                  "error": "OOM at b256"}
-    assert bench._err_str(e) == "resnet50[train_bench]: OOM at b256"
-    assert bench._err_str("legacy string") == "legacy string"
 
 
-def test_bench_cached_result_stubs_dict_errors(tmp_path):
+def test_bench_leg_failure_is_recorded_and_fails_the_run(capsys):
+    """A failed leg is not swallowed: it lands structurally under
+    ``errors`` on the flushed line (a non-empty ``errors`` is what
+    makes run_child exit non-zero) and the next leg still runs."""
     bench = _load_path("bench_mod", os.path.join(_ROOT, "bench.py"))
-    p = tmp_path / "bench_tpu.json"
-    p.write_text(json.dumps({
-        "metric": "m", "value": 2108.2, "backend": "tpu",
-        "errors": [{"leg": "flash_8192", "stage": "fwd_bwd",
-                    "error": "x" * 500}],
-        "extra": {}}))
-    c = bench._cached_tpu_result(str(p))
-    assert c["errors"][0].startswith("captured: flash_8192[fwd_bwd]: ")
-    assert len(c["errors"][0]) <= len("captured: ") + 150
+    out = bench._empty_result()
+
+    def boom():
+        raise RuntimeError("OOM at b256")
+
+    bench._leg(out, "resnet50", boom)
+    bench._leg(out, "after", lambda: out["extra"].update(ran=True))
+    assert out["errors"][0]["leg"] == "resnet50"
+    assert "OOM at b256" in out["errors"][0]["error"]
+    assert out["extra"]["ran"] is True
+    lines = [json.loads(l) for l in
+             capsys.readouterr().out.strip().splitlines()]
+    assert len(lines) == 2 and all(l["errors"] for l in lines)
+    assert all(l["backend"] == "tpu" for l in lines)
+
+
+def test_bench_refuses_a_non_tpu_backend(monkeypatch, capsys):
+    """No CPU stand-in, no replayed record: pinned to another backend
+    the bench body prints no result line and returns 2."""
+    bench = _load_path("bench_mod", os.path.join(_ROOT, "bench.py"))
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert bench.run_child() == 2
+    captured = capsys.readouterr()
+    assert captured.out == "" and "TPU" in captured.err
+    assert not hasattr(bench, "_cached_tpu_result")

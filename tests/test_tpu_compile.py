@@ -1,0 +1,294 @@
+"""Ask the CHIP'S compiler, without the chip.
+
+libtpu is installed here, and it compiles for a TPU that is described
+and not attached (``jax.experimental.topologies``): these tests compile
+the main training path's Pallas kernels at their REAL widths for one
+chip of a described ``v5e:2x2`` and read the compiled program.  That is
+what interpret mode (every other CPU test) and Mosaic serialisation
+(tests/test_tpu_lowering.py) cannot show — an unaligned slice, a VMEM
+budget, a kernel that cannot be partitioned, a program that does not
+fit 16 GB — and it costs no chip time.  Each kernel case also asserts
+``tpu_custom_call`` is IN the compiled text, so a shape gate that
+silently gives way to the XLA oracle fails here.
+
+Nothing runs (there is no device to hold an array): shapes in, compiled
+text out.  A compile that passes is not a chip run; ``chip_smoke.py``
+is.
+
+The topology is described inside the module-scoped ``topo`` fixture —
+never at import, in a ``skipif``/``parametrize`` argument, in conftest
+or in a child process: only one process may load libtpu, every xdist
+worker imports every test file, and only the worker that RUNS this
+file may load it.  Keep all such tests in this ONE file.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _force_mosaic(monkeypatch):
+    # code under test asks jax.default_backend() and sees the CPU: steer
+    # it to emit real (non-interpreted) kernels, as on the chip
+    monkeypatch.setenv("APEX_TPU_FORCE_MOSAIC", "1")
+
+
+def _compile(f, sharding, *specs):
+    """Compile ``f`` for the described device(s); ``specs`` are
+    (shape, dtype) pairs.  Returns the compiled text after checking the
+    program fits one chip's HBM."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in specs]
+    compiled = jax.jit(f).lower(*args).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 2**30:.1f} GiB on a 16 GiB chip"
+    return compiled.as_text()
+
+
+def _grads(f, n):
+    return jax.grad(lambda *a: jnp.sum(f(*a).astype(F32) ** 2),
+                    argnums=tuple(range(n)))
+
+
+def _assert_kernels(text, *names):
+    assert "tpu_custom_call" in text, \
+        "no Mosaic kernel in the compiled program: the gate gave way " \
+        "to the XLA oracle"
+    for name in names:
+        assert name in text, f"kernel {name} absent from the program"
+
+
+# ---------------------------------------------------------------------------
+# BERT-Large's kernels: LayerNorm at (b8*s512, 1024), attention at s512
+# ---------------------------------------------------------------------------
+
+def test_layer_norm_fwd_bwd_bert_large_width(one_chip):
+    from apex_tpu.ops.layer_norm import fused_layer_norm
+    specs = (((4096, 1024), BF16), ((1024,), BF16), ((1024,), BF16))
+    _assert_kernels(_compile(fused_layer_norm, one_chip, *specs),
+                    "apex_fused_layer_norm_fwd")
+    _assert_kernels(_compile(_grads(fused_layer_norm, 3), one_chip,
+                             *specs),
+                    "apex_fused_layer_norm_bwd")
+
+
+@pytest.mark.parametrize("shape,causal", [((8, 16, 512, 64), False),
+                                          ((2, 16, 2048, 64), True)],
+                         ids=["bert_s512", "causal_s2048"])
+def test_flash_attention_fwd_bwd(one_chip, shape, causal):
+    from apex_tpu.ops.attention import flash_attention
+    f = functools.partial(flash_attention, causal=causal)
+    specs = ((shape, BF16),) * 3
+    _assert_kernels(_compile(f, one_chip, *specs),
+                    "apex_flash_attention_fwd")
+    _assert_kernels(_compile(_grads(f, 3), one_chip, *specs),
+                    "apex_flash_attention_dq", "apex_flash_attention_dkv")
+
+
+# ---------------------------------------------------------------------------
+# the flat optimizer / AMP kernels at real bucket sizes
+# ---------------------------------------------------------------------------
+
+N_LAMB_BUCKET = 32_537_600      # one 128 MiB-capped BERT-Large bucket
+N_RESNET50 = 25_557_032
+
+
+def test_flat_lamb_one_bert_large_bucket(one_chip):
+    from apex_tpu.ops import multi_tensor as mt
+    n = N_LAMB_BUCKET
+
+    def step(p, g, m, v, seg):
+        return mt.flat_lamb(p, g, m, v, seg, 28, lr=1e-3, beta1=0.9,
+                            beta2=0.999, eps=1e-6, weight_decay=0.01,
+                            step=3)
+    text = _compile(step, one_chip, *(((n,), F32),) * 4, ((n,), I32))
+    _assert_kernels(text, "apex_multi_tensor_lamb_moments",
+                    "apex_multi_tensor_lamb_apply")
+
+
+def test_flat_sgd_resnet50_size(one_chip):
+    from apex_tpu.ops import multi_tensor as mt
+    n = N_RESNET50
+    text = _compile(
+        lambda p, g, m: mt.flat_sgd(p, g, m, lr=0.1, momentum=0.9,
+                                    weight_decay=1e-4),
+        one_chip, *(((n,), F32),) * 3)
+    _assert_kernels(text, "apex_multi_tensor_sgd")
+
+
+def test_flat_unscale_norm_resnet50_size(one_chip):
+    from apex_tpu.ops import multi_tensor as mt
+    text = _compile(lambda g: mt.flat_unscale_norm(g, 1 / 128.0),
+                    one_chip, ((N_RESNET50,), BF16))
+    _assert_kernels(text, "apex_multi_tensor_unscale_norm")
+
+
+def test_segment_ids_are_built_in_the_program(one_chip):
+    """BucketPlan.segment_ids under jit must be a concatenate of
+    broadcasts, never a bucket-sized literal: as a baked-in constant
+    BERT-Large's one-bucket LAMB step was 1.25 GB of program."""
+    from apex_tpu.multi_tensor_apply.packer import BucketPlan
+    from apex_tpu.ops import multi_tensor as mt
+    leaves = [jnp.zeros((1024, 1024), F32)] * 8 + [jnp.zeros((1024,), F32)]
+    plan = BucketPlan.from_tree(leaves)
+    n = plan.buckets[0].size
+
+    def sumsq(x):
+        return mt.flat_segment_sumsq(x, plan.segment_ids(0),
+                                     plan.num_segments(0))
+    compiled = jax.jit(sumsq).lower(
+        jax.ShapeDtypeStruct((n,), F32, sharding=one_chip)).compile()
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code < n, f"{code} bytes of program for a {n}-element bucket"
+
+
+# ---------------------------------------------------------------------------
+# ResNet-50 (SyncBatchNorm) and the loss kernel
+# ---------------------------------------------------------------------------
+
+def test_welford_resnet50_last_stage(one_chip):
+    from apex_tpu.ops.welford import welford_mean_var
+    _assert_kernels(_compile(welford_mean_var, one_chip,
+                             ((6272, 2048), F32)),
+                    "apex_syncbn_welford")
+
+
+def test_xentropy_fwd_bwd_lane_aligned_vocab(one_chip):
+    """At a 128-multiple vocabulary the fused loss is a kernel.  BERT's
+    own 30528 and GPT-2's 50257 are not multiples: there the lane gate
+    hands the loss to XLA (chip_smoke.py prints that, by design)."""
+    from apex_tpu.ops.xentropy import softmax_cross_entropy
+    n, c = 4096, 30592
+
+    def fwd_bwd(logits, labels):
+        return jax.value_and_grad(
+            lambda z: jnp.sum(softmax_cross_entropy(z, labels)))(logits)
+    _assert_kernels(_compile(fwd_bwd, one_chip, ((n, c), F32),
+                             ((n,), I32)),
+                    "apex_xentropy_fwd", "apex_xentropy_bwd")
+
+
+# ---------------------------------------------------------------------------
+# four chips: the DDP gradient reduce under shard_map
+# ---------------------------------------------------------------------------
+
+def test_ddp_flat_reduce_over_four_described_chips(topo):
+    """A ``data=4`` Mesh over the described devices: the bucketed DDP
+    all-reduce followed by the AMP unscale+norm kernel, per shard."""
+    import numpy as np
+
+    from apex_tpu import comm
+    from apex_tpu.ops import multi_tensor as mt
+    from apex_tpu.parallel.distributed import all_reduce_flat_buffers
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (comm.AXIS_DATA,))
+    n = 4 * 1024 * 1024
+
+    def shard_step(g):                          # g: this shard's bucket
+        (g,) = all_reduce_flat_buffers([g], comm.AXIS_DATA)
+        out, norm_sq, bad = mt.flat_unscale_norm(g, 1 / 128.0)
+        return out, norm_sq[None], bad[None]
+
+    step = comm.shard_map(
+        shard_step, mesh, in_specs=(P(comm.AXIS_DATA),),
+        out_specs=(P(comm.AXIS_DATA),) * 3)
+    text = _compile(step, NamedSharding(mesh, P(comm.AXIS_DATA)),
+                    ((4 * n,), BF16))
+    assert "all-reduce" in text
+    _assert_kernels(text, "apex_multi_tensor_unscale_norm")
+
+
+def test_fused_sgd_step_replicated_over_four_described_chips(topo):
+    """The data-parallel optimizer step: FusedSGD's flat kernel on
+    gradients REPLICATED over a 4-chip mesh.  A plain multi-device jit
+    refuses it ("Mosaic kernels cannot be automatically partitioned" —
+    what the first four-chip run of PR 21 died of, and what interpret
+    mode on virtual CPU devices cannot show); the optimizer's
+    replicated step wraps the same body in shard_map."""
+    import numpy as np
+
+    from apex_tpu import comm
+    from apex_tpu.optimizers import FusedSGD
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (comm.AXIS_DATA,))
+    params = {"w": jnp.zeros((512, 1024), BF16),
+              "b": jnp.zeros((1024,), BF16)}
+    opt = FusedSGD(params, lr=0.1, momentum=0.9, master_weights=True)
+    replicated = NamedSharding(mesh, P())
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype,
+                                       sharding=replicated),
+        opt._step_args(params, found_inf=jnp.int32(0)))
+    text = opt._replicated_step(mesh).lower(*args).compile().as_text()
+    _assert_kernels(text, "apex_multi_tensor_sgd")
+
+
+# ---------------------------------------------------------------------------
+# serving: the example's prefill and decode-window programs
+# ---------------------------------------------------------------------------
+
+def test_serving_prefill_and_decode_window_at_example_geometry(one_chip):
+    """examples/gpt/serve.py's geometry (2 layers, hidden 32, page 4 x
+    8 pages, 2 slots, window 4), lowered from the step functions with
+    described-device shapes — the engine is not touched.  Its first
+    real configuration is ROADMAP R1's."""
+    from apex_tpu import serving
+    from apex_tpu.serving import steps
+
+    cfg = serving.DecoderConfig(vocab_size=128, hidden=32, n_layers=2,
+                                n_heads=2, n_kv_heads=2, ffn=64,
+                                max_seq=64, eos_token=1)
+    spec = serving.ArenaSpec(n_layers=2, n_kv_heads=2, head_dim=16,
+                             page_size=4, n_pages=32, max_slots=2,
+                             pages_per_slot=8)
+    params = serving.init_params(jax.random.key(0), cfg)
+    arena = serving.KVArena(spec)
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(jnp.shape(l),
+                                           jnp.asarray(l).dtype,
+                                           sharding=one_chip), tree)
+
+    window = jax.jit(steps.decode_window_fn(cfg, spec, 4),
+                     donate_argnums=(1,)).lower(
+        sds(params), sds(steps.init_state(arena, 4, 0))).compile()
+    assert "while" in window.as_text()          # the fori_loop window
+
+    bucket = 16
+    scalars = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+               for s, d in (((bucket // 4,), I32), ((bucket,), I32),
+                            ((), I32), ((2,), jnp.uint32), ((), F32),
+                            ((), I32), ((), F32))]
+    prefill = jax.jit(steps.prefill_fn(cfg, spec, bucket),
+                      donate_argnums=(1, 2, 3, 4)).lower(
+        sds(params), *sds((arena.k, arena.v, arena.k_scale,
+                           arena.v_scale)), *scalars).compile()
+    _assert_kernels(prefill.as_text(), "apex_flash_attention_fwd")
