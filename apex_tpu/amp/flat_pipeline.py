@@ -302,6 +302,7 @@ class FlatGradPipeline:
                 leaves[s.index] = o
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
+    @jax.named_scope("apex_amp/unscale")
     def unscale_and_norm(self, bufs: List[jax.Array],
                          state=None, inv_scale=None) -> FlatGrads:
         """One ``flat_unscale_norm`` kernel per bucket + tiny combine.

@@ -20,6 +20,7 @@ from apex_tpu.amp.policies import (Policy, Properties, opt_level_properties)
 from apex_tpu.amp.scaler import (LossScaleConfig, LossScaleState,
                                  re_anchor, update_state)
 from apex_tpu.amp.wrap import auto_cast, cast_inputs
+from apex_tpu.telemetry.spans import span
 
 Pytree = Any
 
@@ -209,11 +210,12 @@ def update_scaler(state: AmpState, found_inf, skipped=None) -> AmpState:
     """``skipped``: the step was skipped externally (watchdog
     quarantine) — the growth tracker holds instead of counting the
     window as clean (:func:`apex_tpu.amp.scaler.update_state`)."""
-    return dataclasses.replace(
-        state, scaler=update_state(state.scaler,
-                                   jnp.asarray(found_inf, jnp.int32),
-                                   state.scaler_config,
-                                   skipped=skipped))
+    with span("apex/amp/update_scaler"):
+        return dataclasses.replace(
+            state, scaler=update_state(state.scaler,
+                                       jnp.asarray(found_inf, jnp.int32),
+                                       state.scaler_config,
+                                       skipped=skipped))
 
 
 def state_dict(*states: AmpState) -> dict:

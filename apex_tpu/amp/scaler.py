@@ -53,16 +53,19 @@ class LossScaleConfig:
     dynamic: bool = True
 
 
+@jax.named_scope("apex_amp/scale_loss")
 def scale_loss(loss: jax.Array, state: LossScaleState) -> jax.Array:
     return loss * state.loss_scale.astype(loss.dtype)
 
 
+@jax.named_scope("apex_amp/unscale")
 def unscale_grads(grads: Pytree, state: LossScaleState) -> Pytree:
     inv = 1.0 / state.loss_scale
     return jax.tree_util.tree_map(
         lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype), grads)
 
 
+@jax.named_scope("apex_amp/unscale")
 def check_finite(grads: Pytree) -> jax.Array:
     """i32 flag: 1 iff any grad element is non-finite.  Stays on device."""
     leaves = jax.tree_util.tree_leaves(grads)
@@ -208,7 +211,8 @@ def scaled_value_and_grad(loss_fn, state: LossScaleState, *args,
         aux = None
     found_inf = check_finite(grads)
     grads = unscale_grads(grads, state)
-    loss = scaled / state.loss_scale
+    with jax.named_scope("apex_amp/unscale"):
+        loss = scaled / state.loss_scale
     _tape.emit("amp/found_inf", found_inf, reduce="max")
     _tape.emit("amp/loss_scale", state.loss_scale)
     _tape.emit("loss", loss)
@@ -274,10 +278,11 @@ def _microbatched_tree(scaled_fn, state, args, has_aux, n, kwargs):
         lambda p: jnp.zeros(p.shape, jnp.float32), params)
     (acc, scaled_sum, found_inf), auxes = jax.lax.scan(
         body, (acc0, jnp.float32(0.0), jnp.int32(0)), xs)
-    inv = 1.0 / (state.loss_scale * jnp.float32(n))
-    grads = jax.tree_util.tree_map(
-        lambda a, p: (a * inv).astype(p.dtype), acc, params)
-    loss = scaled_sum / (jnp.float32(n) * state.loss_scale)
+    with jax.named_scope("apex_amp/unscale"):
+        inv = 1.0 / (state.loss_scale * jnp.float32(n))
+        grads = jax.tree_util.tree_map(
+            lambda a, p: (a * inv).astype(p.dtype), acc, params)
+        loss = scaled_sum / (jnp.float32(n) * state.loss_scale)
     _tape.emit("amp/found_inf", found_inf, reduce="max")
     _tape.emit("amp/loss_scale", state.loss_scale)
     _tape.emit("loss", loss)

@@ -38,6 +38,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend import source_info_util
 from jax.extend.core import ClosedJaxpr, Literal
 
 from apex_tpu.amp import lists
@@ -48,6 +49,7 @@ def _is_float(x) -> bool:
     return jnp.issubdtype(jnp.result_type(x), jnp.floating)
 
 
+@jax.named_scope("apex_amp/cast")
 def _cast_floats(vals, dtype):
     return [v.astype(dtype) if _is_float(v)
             and jnp.result_type(v) != dtype else v for v in vals]
@@ -62,6 +64,7 @@ def _promote_floats(vals):
     return _cast_floats(vals, widest)
 
 
+@jax.named_scope("apex_amp/cast")
 def _restore_dtypes(vals, invars):
     """Cast drifted operands back to the dtypes the eqn was traced at
     (used for opaque primitives whose sub-jaxprs are dtype-bound)."""
@@ -87,6 +90,7 @@ def _half_params(params, half):
     return params
 
 
+@jax.named_scope("apex_amp/cast")
 def _cast_to_dtypes(vals, dtypes):
     """Cast each float val back to its traced dtype (None = leave)."""
     return [v.astype(d) if d is not None and _is_float(v)
@@ -269,38 +273,45 @@ def _eval_jaxpr(jaxpr, consts, args, half):
         vals = [read(x) for x in eqn.invars]
         params = eqn.params
 
-        if name in lists.RECURSE_PRIMS:
-            sub = params.get("jaxpr") or params.get("call_jaxpr")
-            if sub is not None:
-                if isinstance(sub, ClosedJaxpr):
-                    ans = _eval_jaxpr(sub.jaxpr, sub.consts, vals, half)
-                else:
-                    ans = _eval_jaxpr(sub, (), vals, half)
-            else:  # unexpected shape: run opaque
-                ans = _bind(prim, _restore_dtypes(vals, eqn.invars),
-                            params)
-        elif name in lists.HALF_PRIMS:
-            ans = _bind(prim, _cast_floats(vals, half),
-                        _half_params(params, half))
-        elif name in lists.FP32_PRIMS:
-            ans = _bind(prim, _cast_floats(vals, jnp.float32), params)
-        elif name == "scan" and "jaxpr" in params:
-            ans = _rewrite_scan(_restore_dtypes(vals, eqn.invars),
-                                params, half)
-        elif name == "while" and "body_jaxpr" in params:
-            ans = _rewrite_while(_restore_dtypes(vals, eqn.invars),
-                                 params, half)
-        elif name == "cond" and "branches" in params:
-            ans = _rewrite_cond(_restore_dtypes(vals, eqn.invars),
-                                params, eqn.outvars, half)
-        elif "jaxpr" in params or "call_jaxpr" in params or \
-                "branches" in params or "cond_jaxpr" in params or \
-                "fwd_jaxpr_thunk" in params or "num_consts" in params:
-            # opaque (custom_vjp, pallas_call, ...): dtype-bound bodies
-            _warn_opaque(name, params, eqn.invars)
-            ans = _bind(prim, _restore_dtypes(vals, eqn.invars), params)
-        else:
-            ans = _bind(prim, _promote_floats(vals), params)
+        # re-issue the equation under the name stack it was traced
+        # with (as core.eval_jaxpr does): the ``apex_*`` scopes of the
+        # wrapped function must reach the rewritten program's op names
+        with source_info_util.user_context(
+                eqn.source_info.traceback,
+                name_stack=source_info_util.current_name_stack()
+                + eqn.source_info.name_stack):
+            if name in lists.RECURSE_PRIMS:
+                sub = params.get("jaxpr") or params.get("call_jaxpr")
+                if sub is not None:
+                    if isinstance(sub, ClosedJaxpr):
+                        ans = _eval_jaxpr(sub.jaxpr, sub.consts, vals, half)
+                    else:
+                        ans = _eval_jaxpr(sub, (), vals, half)
+                else:  # unexpected shape: run opaque
+                    ans = _bind(prim, _restore_dtypes(vals, eqn.invars),
+                                params)
+            elif name in lists.HALF_PRIMS:
+                ans = _bind(prim, _cast_floats(vals, half),
+                            _half_params(params, half))
+            elif name in lists.FP32_PRIMS:
+                ans = _bind(prim, _cast_floats(vals, jnp.float32), params)
+            elif name == "scan" and "jaxpr" in params:
+                ans = _rewrite_scan(_restore_dtypes(vals, eqn.invars),
+                                    params, half)
+            elif name == "while" and "body_jaxpr" in params:
+                ans = _rewrite_while(_restore_dtypes(vals, eqn.invars),
+                                     params, half)
+            elif name == "cond" and "branches" in params:
+                ans = _rewrite_cond(_restore_dtypes(vals, eqn.invars),
+                                    params, eqn.outvars, half)
+            elif "jaxpr" in params or "call_jaxpr" in params or \
+                    "branches" in params or "cond_jaxpr" in params or \
+                    "fwd_jaxpr_thunk" in params or "num_consts" in params:
+                # opaque (custom_vjp, pallas_call, ...): dtype-bound bodies
+                _warn_opaque(name, params, eqn.invars)
+                ans = _bind(prim, _restore_dtypes(vals, eqn.invars), params)
+            else:
+                ans = _bind(prim, _promote_floats(vals), params)
 
         for v, a in zip(eqn.outvars, ans):
             env[v] = a
@@ -357,13 +368,14 @@ def cast_inputs(fn: Callable, dtype, argnums=None) -> Callable:
     def wrapped(*args, **kwargs):
         cast = lambda x: (x.astype(dtype)
                           if hasattr(x, "dtype") and _is_float(x) else x)
-        if argnums is None:
-            args = jax.tree_util.tree_map(cast, args)
-            kwargs = jax.tree_util.tree_map(cast, kwargs)
-        else:
-            args = tuple(jax.tree_util.tree_map(cast, a)
-                         if i in argnums else a
-                         for i, a in enumerate(args))
+        with jax.named_scope("apex_amp/cast"):
+            if argnums is None:
+                args = jax.tree_util.tree_map(cast, args)
+                kwargs = jax.tree_util.tree_map(cast, kwargs)
+            else:
+                args = tuple(jax.tree_util.tree_map(cast, a)
+                             if i in argnums else a
+                             for i, a in enumerate(args))
         return fn(*args, **kwargs)
 
     return wrapped

@@ -12,6 +12,7 @@ exactly the reference's masked backward.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops.xentropy import softmax_cross_entropy
@@ -21,8 +22,9 @@ def softmax_cross_entropy_loss(logits, labels, smoothing=0.0,
                                padding_idx=0, half_to_float=False):
     """Per-example losses (N,), zeroed where labels == padding_idx."""
     losses = softmax_cross_entropy(logits, labels, smoothing, half_to_float)
-    return jnp.where(labels == padding_idx,
-                     jnp.zeros((), losses.dtype), losses)
+    with jax.named_scope("apex_xentropy"):
+        return jnp.where(labels == padding_idx,
+                         jnp.zeros((), losses.dtype), losses)
 
 
 class SoftmaxCrossEntropyLoss:

@@ -25,6 +25,8 @@ from typing import Any, Iterable, Iterator, Optional
 
 import jax
 
+from apex_tpu.telemetry.spans import span
+
 _SENTINEL = object()
 
 
@@ -102,7 +104,8 @@ class DevicePrefetcher:
     def __next__(self):
         if self._done:
             raise StopIteration
-        item = self._q.get()
+        with span("apex/data/next"):    # the wait for a device batch
+            item = self._q.get()
         if self._done and item is not _SENTINEL:
             # close() ran while we were blocked in get(): `item` is a
             # stale batch that slipped in after close()'s drain (the

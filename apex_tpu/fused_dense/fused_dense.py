@@ -96,6 +96,7 @@ def _fp8_matmul_bwd(policy, res, g):
 _fp8_matmul.defvjp(_fp8_matmul_fwd, _fp8_matmul_bwd)
 
 
+@jax.named_scope("apex_linear")
 def fp8_matmul(x, w, *, policy: Optional[Fp8Policy] = None,
                x_scale=None, w_scale=None, g_scale=None):
     """``(..., K) @ (K, N)`` through the fp8 path.
@@ -118,6 +119,7 @@ def fp8_matmul(x, w, *, policy: Optional[Fp8Policy] = None,
     return _fp8_matmul(policy, x, w, x_scale, w_scale, g_scale)
 
 
+@jax.named_scope("apex_linear")
 def fused_dense_function(x, weight, bias=None, fp8=None, w_scale=None):
     """y = x @ W^T + b (torch Linear weight layout: (out, in)).
 

@@ -165,9 +165,10 @@ class BertModel(nn.Module):
                 and not self.sequence_parallel):
             x = tp.copy_to_tensor_model_parallel_region(x)
         w = variables["params"]["embed"]["weight"]
-        return jnp.dot(x.astype(self.dtype),
-                       jnp.transpose(w).astype(self.dtype),
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("apex_linear"):    # the tied-embedding head
+            return jnp.dot(x.astype(self.dtype),
+                           jnp.transpose(w).astype(self.dtype),
+                           preferred_element_type=jnp.float32)
 
 
 def bert_large(**kw) -> BertModel:

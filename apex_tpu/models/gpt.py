@@ -263,9 +263,10 @@ class GPTModel(nn.Module):
         elif comm.model_parallel_size() > 1:
             x = mappings.copy_to_tensor_model_parallel_region(x)
         w = self.get_variable("params", "embed")["weight"]
-        logits = jnp.dot(x.astype(self.dtype),
-                         jnp.transpose(w).astype(self.dtype),
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope("apex_linear"):    # the tied-embedding head
+            logits = jnp.dot(x.astype(self.dtype),
+                             jnp.transpose(w).astype(self.dtype),
+                             preferred_element_type=jnp.float32)
         return logits                                  # (s, b, V/tp) f32
 
     def loss(self, variables, tokens, labels, segment_ids=None,

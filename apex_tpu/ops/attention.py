@@ -822,6 +822,7 @@ def packed_segment_ids(segment_ids, xp=jnp):
             xp.where(segment_ids > 0, segment_ids, -2))
 
 
+@jax.named_scope("apex_attention")
 def flash_attention(q, k, v, causal=False, scale=None,
                     segment_ids: Optional[Tuple[jax.Array,
                                                 jax.Array]] = None,
@@ -902,6 +903,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     return _flash(q, k, v, segment_ids, seed, causal, scale, rate)
 
 
+@jax.named_scope("apex_attention")
 def attention_ref(q, k, v, causal=False, scale=None,
                   mask: Optional[jax.Array] = None,
                   dropout_rate: float = 0.0, dropout_seed=None):
@@ -1090,6 +1092,7 @@ def _ring_vjp_bwd(causal, scale, axis, res, do):
 _ring.defvjp(_ring_vjp_fwd, _ring_vjp_bwd)
 
 
+@jax.named_scope("apex_attention")
 def ring_attention(q, k, v, causal=False, scale=None,
                    axis: str = comm.AXIS_CTX):
     """Context-parallel attention: sequences sharded over ``axis``.
@@ -1189,6 +1192,7 @@ def ring_attention_ref(q, k, v, causal=False, scale=None,
 # long-context strategy next to the ppermute ring
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("apex_attention")
 def ulysses_attention(q, k, v, causal=False, scale=None,
                       axis: str = comm.AXIS_CTX):
     """All-to-all sequence parallelism over ``axis`` (Ulysses style).

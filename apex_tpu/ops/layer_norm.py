@@ -223,6 +223,7 @@ def _norm_affine_bwd(eps, rms, res, dy):
 _norm_affine.defvjp(_norm_affine_fwd, _norm_affine_bwd)
 
 
+@jax.named_scope("apex_layernorm")
 def fused_layer_norm(x, weight: Optional[jax.Array] = None,
                      bias: Optional[jax.Array] = None, eps: float = 1e-5,
                      memory_efficient: bool = True):
@@ -239,6 +240,7 @@ def fused_layer_norm(x, weight: Optional[jax.Array] = None,
     return y
 
 
+@jax.named_scope("apex_layernorm")
 def fused_rms_norm(x, weight: Optional[jax.Array] = None, eps: float = 1e-5,
                    memory_efficient: bool = True):
     """RMSNorm over the last dim (reference fused_layer_norm_cuda RMS fwd)."""

@@ -751,43 +751,48 @@ def flat_novograd(p, g, m, v_seg, seg_ids, *, lr, beta1, beta2, eps,
     inv_scale = 1.0 / jnp.asarray(grad_scale, jnp.float32)
     b2 = jnp.asarray(beta2, jnp.float32)
     first = jnp.asarray(first_run, jnp.bool_)
-    g_norm_sq = flat_segment_sumsq(_f32(g) * inv_scale, seg_ids, num_seg)
-    if init_zero:
-        v_new = jnp.where(first, (1 - b2) * g_norm_sq,
-                          b2 * v_seg + (1 - b2) * g_norm_sq)
-    else:
-        v_new = jnp.where(first, g_norm_sq,
-                          b2 * v_seg + (1 - b2) * g_norm_sq)
-    inv_denom = 1.0 / (jnp.sqrt(v_new) + jnp.asarray(eps, jnp.float32))
-    d_elem = inv_denom[seg_ids]              # one gather, not per leaf
-    s = jnp.stack([
-        jnp.asarray(lr, jnp.float32), jnp.asarray(beta1, jnp.float32),
-        jnp.asarray(weight_decay, jnp.float32), inv_scale,
-        jnp.asarray(first, jnp.float32),
-    ])
-    p2d, n = _as_tiles(p)
-    g2d, _ = _as_tiles(g)
-    m2d, _ = _as_tiles(m)
-    d2d, _ = _as_tiles(d_elem)
-    kernel = functools.partial(_novograd_apply_kernel,
-                               bool(grad_averaging),
-                               bool(reg_inside_moment))
-    po, mo = pl.pallas_call(
-        kernel,
-        grid=(_grid(p2d.shape[0]),),
-        in_specs=[_smem_spec()] + [_vec_spec()] * 4,
-        out_specs=[_vec_spec()] * 2,
-        out_shape=[
-            jax.ShapeDtypeStruct(p2d.shape, p.dtype),
-            jax.ShapeDtypeStruct(m2d.shape, jnp.float32),
-        ],
-        input_output_aliases={1: 0, 3: 1},
-        interpret=interpret_mode(),
-        name="apex_multi_tensor_novograd",
-    )(s, p2d, g2d, m2d, d2d)
-    return _from_tiles(po, n), _from_tiles(mo, n), v_new
+    with jax.named_scope("apex_optim/grad_norm"):
+        g_norm_sq = flat_segment_sumsq(_f32(g) * inv_scale, seg_ids,
+                                       num_seg)
+        if init_zero:
+            v_new = jnp.where(first, (1 - b2) * g_norm_sq,
+                              b2 * v_seg + (1 - b2) * g_norm_sq)
+        else:
+            v_new = jnp.where(first, g_norm_sq,
+                              b2 * v_seg + (1 - b2) * g_norm_sq)
+        inv_denom = 1.0 / (jnp.sqrt(v_new)
+                           + jnp.asarray(eps, jnp.float32))
+        d_elem = inv_denom[seg_ids]          # one gather, not per leaf
+    with jax.named_scope("apex_optim/moments"):
+        s = jnp.stack([
+            jnp.asarray(lr, jnp.float32), jnp.asarray(beta1, jnp.float32),
+            jnp.asarray(weight_decay, jnp.float32), inv_scale,
+            jnp.asarray(first, jnp.float32),
+        ])
+        p2d, n = _as_tiles(p)
+        g2d, _ = _as_tiles(g)
+        m2d, _ = _as_tiles(m)
+        d2d, _ = _as_tiles(d_elem)
+        kernel = functools.partial(_novograd_apply_kernel,
+                                   bool(grad_averaging),
+                                   bool(reg_inside_moment))
+        po, mo = pl.pallas_call(
+            kernel,
+            grid=(_grid(p2d.shape[0]),),
+            in_specs=[_smem_spec()] + [_vec_spec()] * 4,
+            out_specs=[_vec_spec()] * 2,
+            out_shape=[
+                jax.ShapeDtypeStruct(p2d.shape, p.dtype),
+                jax.ShapeDtypeStruct(m2d.shape, jnp.float32),
+            ],
+            input_output_aliases={1: 0, 3: 1},
+            interpret=interpret_mode(),
+            name="apex_multi_tensor_novograd",
+        )(s, p2d, g2d, m2d, d2d)
+        return _from_tiles(po, n), _from_tiles(mo, n), v_new
 
 
+@jax.named_scope("apex_optim/moments")
 def flat_novograd_ref(p, g, m, v_seg, seg_ids, *, lr, beta1, beta2, eps,
                       weight_decay=0.0, first_run=False,
                       grad_averaging=True, init_zero=False,
@@ -869,44 +874,47 @@ def flat_lamb(p, g, m, v, seg_ids, num_segments: int, *, lr, beta1, beta2,
             beta2=beta2, eps=eps, weight_decay=weight_decay, step=step,
             bias_correction=bias_correction, grad_scale=grad_scale,
             clip_coeff=clip_coeff, use_nvlamb=use_nvlamb)
-    s = jnp.stack([b1, b2, jnp.asarray(eps, jnp.float32), wd,
-                   c1r, c2r, gmul])
-    p2d, n = _as_tiles(p)
-    g2d, _ = _as_tiles(g)
-    m2d, _ = _as_tiles(m)
-    v2d, _ = _as_tiles(v)
-    mo, vo, update2d = pl.pallas_call(
-        _lamb_moment_kernel,
-        grid=(_grid(p2d.shape[0]),),
-        in_specs=[_smem_spec()] + [_vec_spec()] * 4,
-        out_specs=[_vec_spec()] * 3,
-        out_shape=[
-            jax.ShapeDtypeStruct(m2d.shape, jnp.float32),
-            jax.ShapeDtypeStruct(v2d.shape, jnp.float32),
-            jax.ShapeDtypeStruct(p2d.shape, jnp.float32),
-        ],
-        input_output_aliases={3: 0, 4: 1},
-        interpret=interpret_mode(),
-        name="apex_multi_tensor_lamb_moments",
-    )(s, p2d, g2d, m2d, v2d)
-    update = _from_tiles(update2d, n)
+    with jax.named_scope("apex_optim/moments"):
+        s = jnp.stack([b1, b2, jnp.asarray(eps, jnp.float32), wd,
+                       c1r, c2r, gmul])
+        p2d, n = _as_tiles(p)
+        g2d, _ = _as_tiles(g)
+        m2d, _ = _as_tiles(m)
+        v2d, _ = _as_tiles(v)
+        mo, vo, update2d = pl.pallas_call(
+            _lamb_moment_kernel,
+            grid=(_grid(p2d.shape[0]),),
+            in_specs=[_smem_spec()] + [_vec_spec()] * 4,
+            out_specs=[_vec_spec()] * 3,
+            out_shape=[
+                jax.ShapeDtypeStruct(m2d.shape, jnp.float32),
+                jax.ShapeDtypeStruct(v2d.shape, jnp.float32),
+                jax.ShapeDtypeStruct(p2d.shape, jnp.float32),
+            ],
+            input_output_aliases={3: 0, 4: 1},
+            interpret=interpret_mode(),
+            name="apex_multi_tensor_lamb_moments",
+        )(s, p2d, g2d, m2d, v2d)
+        update = _from_tiles(update2d, n)
     factor_elem = _lamb_trust_factor(p, update, seg_ids, num_segments,
                                      lr, wd, use_nvlamb)
-    f2d, _ = _as_tiles(factor_elem)
-    u2d, _ = _as_tiles(update)
-    po = pl.pallas_call(
-        _apply_update_kernel,
-        grid=(_grid(p2d.shape[0]),),
-        in_specs=[_vec_spec()] * 3,
-        out_specs=_vec_spec(),
-        out_shape=jax.ShapeDtypeStruct(p2d.shape, p.dtype),
-        input_output_aliases={0: 0},
-        interpret=interpret_mode(),
-        name="apex_multi_tensor_lamb_apply",
-    )(p2d, u2d, f2d)
-    return _from_tiles(po, n), _from_tiles(mo, n), _from_tiles(vo, n)
+    with jax.named_scope("apex_optim/apply"):
+        f2d, _ = _as_tiles(factor_elem)
+        u2d, _ = _as_tiles(update)
+        po = pl.pallas_call(
+            _apply_update_kernel,
+            grid=(_grid(p2d.shape[0]),),
+            in_specs=[_vec_spec()] * 3,
+            out_specs=_vec_spec(),
+            out_shape=jax.ShapeDtypeStruct(p2d.shape, p.dtype),
+            input_output_aliases={0: 0},
+            interpret=interpret_mode(),
+            name="apex_multi_tensor_lamb_apply",
+        )(p2d, u2d, f2d)
+        return _from_tiles(po, n), _from_tiles(mo, n), _from_tiles(vo, n)
 
 
+@jax.named_scope("apex_optim/trust_ratio")
 def _lamb_trust_factor(p, update, seg_ids, num_segments, lr, wd,
                        use_nvlamb):
     """Per-element lr*trust buffer from per-segment norms (one gather)."""
@@ -936,18 +944,20 @@ def flat_lamb_ref(p, g, m, v, seg_ids, num_segments: int, *, lr, beta1,
     b1 = jnp.asarray(beta1, jnp.float32)
     b2 = jnp.asarray(beta2, jnp.float32)
     wd = jnp.asarray(weight_decay, jnp.float32)
-    pf = _f32(p)
-    gf = _f32(g) * (jnp.asarray(clip_coeff, jnp.float32)
-                    / jnp.asarray(grad_scale, jnp.float32))
-    m = b1 * m + (1 - b1) * gf
-    v = b2 * v + (1 - b2) * gf * gf
-    if bias_correction:
-        c1r = 1.0 / (1.0 - b1 ** step)
-        c2r = 1.0 / (1.0 - b2 ** step)
-    else:
-        c1r = c2r = jnp.float32(1.0)
-    update = (m * c1r) / (jnp.sqrt(v * c2r)
-                          + jnp.asarray(eps, jnp.float32)) + wd * pf
+    with jax.named_scope("apex_optim/moments"):
+        pf = _f32(p)
+        gf = _f32(g) * (jnp.asarray(clip_coeff, jnp.float32)
+                        / jnp.asarray(grad_scale, jnp.float32))
+        m = b1 * m + (1 - b1) * gf
+        v = b2 * v + (1 - b2) * gf * gf
+        if bias_correction:
+            c1r = 1.0 / (1.0 - b1 ** step)
+            c2r = 1.0 / (1.0 - b2 ** step)
+        else:
+            c1r = c2r = jnp.float32(1.0)
+        update = (m * c1r) / (jnp.sqrt(v * c2r)
+                              + jnp.asarray(eps, jnp.float32)) + wd * pf
     factor = _lamb_trust_factor(pf, update, seg_ids, num_segments,
                                 lr, wd, use_nvlamb)
-    return (pf - factor * update).astype(p.dtype), m, v
+    with jax.named_scope("apex_optim/apply"):
+        return (pf - factor * update).astype(p.dtype), m, v

@@ -129,6 +129,7 @@ def _scaled_masked_softmax_p(x, mask, scale):
     return _sms_fwd(x, mask, scale)[0]
 
 
+@jax.named_scope("apex_softmax")
 def scaled_masked_softmax(x, mask, scale):
     """softmax(x*scale masked_fill(mask, -10000)) over the last dim.
 
@@ -213,6 +214,7 @@ def _scaled_upper_triang_masked_softmax_p(x, scale):
     return _suts_fwd(x, scale)[0]
 
 
+@jax.named_scope("apex_softmax")
 def scaled_upper_triang_masked_softmax(x, scale):
     """Causal softmax(x*scale) for (attn_batches, sq, sq) inputs.
     scale: static Python number.  Reference:

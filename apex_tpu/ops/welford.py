@@ -67,6 +67,7 @@ def _welford_kernel(total_rows, x_ref, cnt_ref, mean_ref, m2_ref):
     m2_ref[...] = m2
 
 
+@jax.named_scope("apex_welford")
 def welford_mean_var(x2d: jax.Array) -> Tuple[jax.Array, jax.Array,
                                               jax.Array]:
     """Local Welford stats of an (N, C) array, reduced over N.
@@ -103,6 +104,7 @@ def welford_mean_var(x2d: jax.Array) -> Tuple[jax.Array, jax.Array,
     return mean[0], var, count
 
 
+@jax.named_scope("apex_welford")
 def welford_mean_var_ref(x2d: jax.Array):
     xf = x2d.astype(jnp.float32)
     n = xf.shape[0]

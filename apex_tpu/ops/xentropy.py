@@ -100,6 +100,7 @@ def _loss_dtype(logits, half_to_float):
     return jnp.float32 if half_to_float else logits.dtype
 
 
+@jax.named_scope("apex_xentropy")
 def _xent_fwd(logits, labels, smoothing, half_to_float):
     n, c = logits.shape
     labels = labels.astype(jnp.int32)
@@ -126,6 +127,7 @@ def _xent_fwd(logits, labels, smoothing, half_to_float):
     return loss, (logits, labels, lse2d[:n, 0])
 
 
+@jax.named_scope("apex_xentropy")
 def _xent_bwd(smoothing, half_to_float, res, dy):
     logits, labels, lse = res
     n, c = logits.shape
@@ -159,6 +161,7 @@ def _xent_bwd(smoothing, half_to_float, res, dy):
 softmax_cross_entropy.defvjp(_xent_fwd, _xent_bwd)
 
 
+@jax.named_scope("apex_xentropy")
 def softmax_cross_entropy_ref(logits, labels, smoothing=0.0,
                               half_to_float=False):
     """Pure-XLA oracle (the reference's test oracle is label-smoothed

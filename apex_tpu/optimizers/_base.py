@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from apex_tpu.multi_tensor_apply.packer import BucketPlan
 from apex_tpu.telemetry import _tape
+from apex_tpu.telemetry.spans import span
 
 Pytree = Any
 tree_map = jax.tree_util.tree_map
@@ -115,6 +116,7 @@ def _select(keep, new_tree, old_tree):
     return tree_map(lambda a, b: jnp.where(keep, a, b), new_tree, old_tree)
 
 
+@jax.named_scope("apex_optim/skip_select")
 def _skip_on_overflow(found_inf, new_work, old_work, new_state,
                       old_state):
     """The branch-free found_inf skip, shared by every step body:
@@ -269,7 +271,9 @@ class FusedOptimizerBase:
         if self._plan is None:
             return self._params_tree
         if self._params_cache is None:
-            self._params_cache = self._unpack_model_jit(self._param_bufs)
+            with span("apex/optim/unpack_model"):
+                self._params_cache = self._unpack_model_jit(
+                    self._param_bufs)
         return self._params_cache
 
     @params.setter
@@ -287,7 +291,9 @@ class FusedOptimizerBase:
         if self._master_bufs is None:
             return None
         if self._masters_cache is None:
-            self._masters_cache = self._unpack_work_jit(self._master_bufs)
+            with span("apex/optim/unpack_model"):
+                self._masters_cache = self._unpack_work_jit(
+                    self._master_bufs)
         return self._masters_cache
 
     @masters.setter
@@ -340,8 +346,9 @@ class FusedOptimizerBase:
                      if k in opt_state}
         core = {k: v for k, v in opt_state.items()
                 if k not in fp8_state}
-        extra = self._flat_prologue(work_bufs, grad_bufs, step,
-                                    grad_scale, hypers)
+        with jax.named_scope("apex_optim/grad_norm"):
+            extra = self._flat_prologue(work_bufs, grad_bufs, step,
+                                        grad_scale, hypers)
         new_bufs: List[Any] = []
         new_state: Dict[str, List[Any]] = {k: [] for k in core}
         for bi, (p, g) in enumerate(zip(work_bufs, grad_bufs)):
@@ -352,8 +359,9 @@ class FusedOptimizerBase:
             for k in new_state:
                 new_state[k].append(ns[k])
         if fp8_state:
-            new_state.update(self._fp8_slot_update(new_bufs, fp8_state,
-                                                   step))
+            with jax.named_scope("apex_optim/fp8_slots"):
+                new_state.update(self._fp8_slot_update(
+                    new_bufs, fp8_state, step))
         return new_bufs, new_state
 
     def _fp8_slot_update(self, new_work_bufs, fp8_state, step):
@@ -429,9 +437,11 @@ class FusedOptimizerBase:
             new_work, new_state = _skip_on_overflow(
                 found_inf, new_work, work, new_state, opt_state)
         if masters is not None:
-            new_params = tree_map(lambda p, m: m.astype(p.dtype)
-                                  if jnp.issubdtype(p.dtype, jnp.floating)
-                                  else m, params, new_work)
+            with jax.named_scope("apex_optim/cast_model"):
+                new_params = tree_map(
+                    lambda p, m: m.astype(p.dtype)
+                    if jnp.issubdtype(p.dtype, jnp.floating) else m,
+                    params, new_work)
             return new_params, new_work, new_state
         return new_work, None, new_state
 
@@ -442,16 +452,20 @@ class FusedOptimizerBase:
         case zero pack work happens here — then ONE flat kernel chain
         per bucket; params/masters/state go in and come out packed."""
         work_bufs = master_bufs if master_bufs is not None else param_bufs
-        grad_bufs = (list(grads) if self._plan.is_packed(grads)
-                     else self._plan.pack(grads))
+        if self._plan.is_packed(grads):
+            grad_bufs = list(grads)
+        else:
+            with jax.named_scope("apex_optim/pack_grads"):
+                grad_bufs = self._plan.pack(grads)
         new_work, new_state = self._flat_step_math(
             work_bufs, grad_bufs, opt_state, step, grad_scale, hypers)
         if found_inf is not None:
             new_work, new_state = _skip_on_overflow(
                 found_inf, new_work, work_bufs, new_state, opt_state)
         if master_bufs is not None:
-            new_params = [w.astype(b.model_dtype) for w, b in
-                          zip(new_work, self._plan.buckets)]
+            with jax.named_scope("apex_optim/cast_model"):
+                new_params = [w.astype(b.model_dtype) for w, b in
+                              zip(new_work, self._plan.buckets)]
             return new_params, new_work, new_state
         return new_work, None, new_state
 
@@ -529,9 +543,10 @@ class FusedOptimizerBase:
         gs = _fold_clip(grad_scale, clip_coef)
         hypers = dict(self.hypers)
         if packed:
-            work_bufs = self._plan.pack_work(params)
-            grad_bufs = (list(grads) if self._plan.is_packed(grads)
-                         else self._plan.pack(grads))
+            with jax.named_scope("apex_optim/pack_grads"):
+                work_bufs = self._plan.pack_work(params)
+                grad_bufs = (list(grads) if self._plan.is_packed(grads)
+                             else self._plan.pack(grads))
             new_bufs, new_state = self._flat_step_math(
                 work_bufs, grad_bufs, opt_state, step, gs, hypers)
             if found_inf is not None:
@@ -564,6 +579,12 @@ class FusedOptimizerBase:
         ``clip_coef``: optional traced global-norm clip coefficient in
         (0, 1]; folded into the kernels' grad scaling (see
         ``_fold_clip``) so clipping costs zero extra gradient passes."""
+        with span("apex/optim/step"):
+            return self._step(grads, grad_scale, found_inf, clip_coef)
+
+    def _step(self, grads, grad_scale, found_inf, clip_coef):
+        """``step``'s body, in the host spans ``apex/optim/args``,
+        ``clock``, ``dispatch`` (children of ``apex/optim/step``)."""
         if hasattr(grads, "bufs") and hasattr(grads, "found_inf"):
             # amp.FlatGrads (duck-typed: amp must stay import-light here)
             if self._plan is None:
@@ -577,19 +598,25 @@ class FusedOptimizerBase:
             if clip_coef is None:
                 clip_coef = getattr(grads, "clip_coef", None)
             grads = grads.bufs
-        grad_scale = _fold_clip(grad_scale, clip_coef)
-        self.step_count = self.step_count + 1
-        eager_offload = self.offload_state and not self._fused_offload
-        args = self._step_args(grads, grad_scale, found_inf)
-        if eager_offload:   # CPU fallback: explicit round trip
-            args = args[:2] + (place_on_device(args[2]),) + args[3:]
-        # bucketed path only: its buffers are whole by construction
-        # (the packer declines sharded leaves), and it is the one that
-        # runs Mosaic kernels; per-leaf math partitions under plain jit
-        mesh = _replica_mesh(grads) if self._plan is not None else None
-        step_fn = (self._jit_step if mesh is None
-                   else self._replicated_step(mesh))
-        new_params, new_masters, self.opt_state = step_fn(*args)
+        with span("apex/optim/clock"):
+            self.step_count = self.step_count + 1
+        with span("apex/optim/args"):
+            grad_scale = _fold_clip(grad_scale, clip_coef)
+            eager_offload = self.offload_state and not self._fused_offload
+            args = self._step_args(grads, grad_scale, found_inf)
+            if eager_offload:   # CPU fallback: explicit round trip
+                args = args[:2] + (place_on_device(args[2]),) + args[3:]
+            # bucketed path only: its buffers are whole by construction
+            # (the packer declines sharded leaves), and it is the one
+            # that runs Mosaic kernels; per-leaf math partitions under
+            # plain jit
+            mesh = _replica_mesh(grads) if self._plan is not None else None
+            step_fn = (self._jit_step if mesh is None
+                       else self._replicated_step(mesh))
+        # the one call that can block: the donated state of the
+        # previous step may still be in use on the device
+        with span("apex/optim/dispatch"):
+            new_params, new_masters, self.opt_state = step_fn(*args)
         if self._plan is not None:
             self._param_bufs, self._master_bufs = new_params, new_masters
             self._params_cache = None
@@ -600,9 +627,10 @@ class FusedOptimizerBase:
             self.opt_state = place_on_host(self.opt_state)
         if found_inf is not None:
             # a skipped step must not advance the bias-correction clock
-            self.step_count = jnp.where(jnp.asarray(found_inf) > 0,
-                                        self.step_count - 1,
-                                        self.step_count)
+            with span("apex/optim/clock"):
+                self.step_count = jnp.where(jnp.asarray(found_inf) > 0,
+                                            self.step_count - 1,
+                                            self.step_count)
         return self.params
 
     def _replicated_step(self, mesh):

@@ -7,6 +7,7 @@ in-kernel ``inv_scale`` (optimizers/_base._fold_clip)."""
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops import multi_tensor as mt
@@ -37,7 +38,8 @@ class FusedAdagrad(FusedOptimizerBase):
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
                           hypers, extra):
         h = self._merge_hypers(hypers)
-        po, ho = mt.flat_adagrad(
-            p, g, state["sum"], lr=h["lr"], eps=h["eps"],
-            weight_decay=h["weight_decay"], grad_scale=grad_scale)
+        with jax.named_scope("apex_optim/moments"):
+            po, ho = mt.flat_adagrad(
+                p, g, state["sum"], lr=h["lr"], eps=h["eps"],
+                weight_decay=h["weight_decay"], grad_scale=grad_scale)
         return po, {"sum": ho}

@@ -60,11 +60,12 @@ class FusedAdam(FusedOptimizerBase):
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
                           hypers, extra):
         h = self._merge_hypers(hypers)
-        po, mo, vo = mt.flat_adam(
-            p, g, state["exp_avg"], state["exp_avg_sq"],
-            lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],
-            weight_decay=h["weight_decay"], step=step,
-            adam_w_mode=self.hypers["adam_w_mode"],
-            bias_correction=self.hypers["bias_correction"],
-            grad_scale=grad_scale)
+        with jax.named_scope("apex_optim/moments"):
+            po, mo, vo = mt.flat_adam(
+                p, g, state["exp_avg"], state["exp_avg_sq"],
+                lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],
+                weight_decay=h["weight_decay"], step=step,
+                adam_w_mode=self.hypers["adam_w_mode"],
+                bias_correction=self.hypers["bias_correction"],
+                grad_scale=grad_scale)
         return po, {"exp_avg": mo, "exp_avg_sq": vo}

@@ -10,6 +10,7 @@ buffers and a traced ``clip_coef`` folded into ``flat_sgd``'s in-kernel
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops import multi_tensor as mt
@@ -53,11 +54,12 @@ class FusedSGD(FusedOptimizerBase):
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
                           hypers, extra):
         h = self._merge_hypers(hypers)
-        po, bo = mt.flat_sgd(
-            p, g, state["momentum_buffer"], lr=h["lr"],
-            momentum=self.hypers["momentum"],
-            dampening=self.hypers["dampening"],
-            weight_decay=h["weight_decay"],
-            nesterov=self.hypers["nesterov"],
-            first_run=step == 1, grad_scale=grad_scale)
+        with jax.named_scope("apex_optim/moments"):
+            po, bo = mt.flat_sgd(
+                p, g, state["momentum_buffer"], lr=h["lr"],
+                momentum=self.hypers["momentum"],
+                dampening=self.hypers["dampening"],
+                weight_decay=h["weight_decay"],
+                nesterov=self.hypers["nesterov"],
+                first_run=step == 1, grad_scale=grad_scale)
         return po, {"momentum_buffer": bo}

@@ -80,6 +80,7 @@ def all_reduce_gradients(grads: Pytree,
     post = world / pre if average else 1.0 / pre
     _emit_reduce_telemetry(jax.tree_util.tree_leaves(grads))
 
+    @jax.named_scope("apex_ddp/reduce")
     def reduce_leaf(g):
         # same cast discipline as the bucketed path: f32 leaves pay no
         # convert in either direction
@@ -94,6 +95,7 @@ def all_reduce_gradients(grads: Pytree,
     return jax.tree_util.tree_map(reduce_leaf, grads)
 
 
+@jax.named_scope("apex_ddp/reduce")
 def _reduce_one_flat_buffer(b, axis_name, world, pre, post,
                             decompose: str = "psum",
                             out_dtype=None):

@@ -31,6 +31,7 @@ def _axis_bound(axis_name: str) -> bool:
     return comm.axis_is_bound(axis_name)
 
 
+@jax.named_scope("apex_syncbn")
 def sync_batch_norm_stats(x2d: jax.Array, axis_name: Optional[str]):
     """Global (mean, biased var) of an (N, C) array, synced over
     ``axis_name`` when bound.
@@ -106,12 +107,15 @@ class SyncBatchNorm(nn.Module):
                 ra_mean.value = (1 - m) * ra_mean.value + m * mean
                 ra_var.value = (1 - m) * ra_var.value + m * unbiased
 
-        y = (xc.astype(jnp.float32) - mean) * jax.lax.rsqrt(var + self.eps)
         if self.affine:
             w = self.param("weight", nn.initializers.ones, (c,), jnp.float32)
             b = self.param("bias", nn.initializers.zeros, (c,), jnp.float32)
-            y = y * w + b
-        return restore(y.astype(x.dtype))
+        with jax.named_scope("apex_syncbn"):
+            y = ((xc.astype(jnp.float32) - mean)
+                 * jax.lax.rsqrt(var + self.eps))
+            if self.affine:
+                y = y * w + b
+            return restore(y.astype(x.dtype))
 
 
 def convert_syncbn_model(module: Any, process_group: Optional[str] =

@@ -10,10 +10,12 @@ distiller that parses the written profile into a top-device-ops table
 (the prof half, `apex_tpu.pyprof.prof`).
 
 Run-time training telemetry (metric rings, span timing, retrace
-counters) is the sibling layer `apex_tpu.telemetry`:
-``telemetry.span(name)`` nests on nvtx's (thread-local) range stack,
-so telemetry spans land in XProf traces exactly like `annotate`d
-functions do.
+counters) is the sibling layer `apex_tpu.telemetry`.  The two do not
+share a mechanism: an nvtx range here is a ``jax.named_scope``, a
+trace-time name that reaches the ``op_name`` of operations traced
+inside it and writes nothing into a running profiler by itself;
+``telemetry.span(name)`` is a ``jax.profiler.TraceAnnotation``, a host
+event in the profiler's trace.
 """
 
 from apex_tpu.pyprof import nvtx, prof  # noqa: F401

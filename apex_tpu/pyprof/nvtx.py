@@ -3,17 +3,19 @@ apex/pyprof/nvtx/nvmarker.py).
 
 range_push/range_pop manage a stack of named_scope context managers;
 `range` is the decorator/context form; `profile` wraps
-jax.profiler.trace for XProf capture.  Scopes show up in TPU traces the
-way nvtx ranges show up in nsight.
+jax.profiler.trace for XProf capture.  A scope opened while a function
+is being TRACED names the operations traced inside it (their
+``op_name``, which a TPU trace carries per device op); opened in eager
+host code it names nothing in a profile — host regions are
+``telemetry.span``'s job.
 
 The push/pop stack is THREAD-LOCAL: a prefetch thread annotating its
 own work must never pop a scope the main thread pushed (the reference
 nvtx API is per-thread for the same reason).  ``range_pop`` is also
 best-effort on teardown — a scope body that raised can leave
 ``jax.named_scope``'s own context in a state where ``__exit__``
-raises, and an unwinding caller (``telemetry.span``'s finally, an
-except-branch cleanup) must still get its stack balanced rather than
-a second exception.
+raises, and an unwinding caller (an except-branch cleanup) must
+still get its stack balanced rather than a second exception.
 """
 
 from __future__ import annotations

@@ -17,9 +17,11 @@ flags as APX101 (and whose runtime twin is APX102).  Core invariant:
   writes them into the ring inside the step's own jit.
 - emitters (emitters.py): JSONL (schema'd, one record per step),
   rank-0 rate-limited console, wide CSV — all fed at flush time only.
-- :func:`span` (spans.py): wall-time spans for host-side phases
-  (checkpoint save/restore...), layered on ``pyprof.nvtx`` so they
-  also land in XProf traces.
+- :func:`span` (spans.py): host-side spans (the optimizer's step
+  call, the scaler update, checkpoint save/restore...), each a
+  ``jax.profiler.TraceAnnotation`` — an event on the host plane of a
+  running profiler trace, on the device ops' clock — and, while a sink
+  is registered, a record (name, start, end, parent, step) for it.
 - :class:`RetraceCounter` (retrace.py): counts recompiles at run time
   via ``jax.monitoring`` (plus a per-function wrapper fallback) — the
   runtime companion to the APX30x static rules.

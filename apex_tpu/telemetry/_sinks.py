@@ -1,5 +1,5 @@
 """Shared host-side sink registry — the one fan-out mechanism behind
-both :mod:`~apex_tpu.telemetry.spans` (durations) and
+both :mod:`~apex_tpu.telemetry.spans` (span records) and
 :mod:`~apex_tpu.telemetry.hostmetrics` (counters).  Each keeps its own
 registry INSTANCE (a span sink must never see counter values), but the
 registration/emission semantics live here once.
@@ -35,6 +35,11 @@ class SinkRegistry:
         with self._lock:
             if fn in self._sinks:
                 self._sinks.remove(fn)
+
+    def active(self) -> bool:
+        """Whether any sink is registered (the same unlocked,
+        GIL-atomic read as ``emit``'s fast path)."""
+        return bool(self._sinks)
 
     def emit(self, name: str, value: float) -> None:
         if not self._sinks:

@@ -27,6 +27,7 @@ def _tp_bound(axis) -> bool:
     return comm.axis_is_bound(axis)
 
 
+@jax.named_scope("apex_xentropy")
 def vocab_parallel_cross_entropy(vocab_parallel_logits, target,
                                  label_smoothing: float = 0.0,
                                  axis: str = AXIS):

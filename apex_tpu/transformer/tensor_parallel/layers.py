@@ -99,10 +99,11 @@ class ColumnParallelLinear(nn.Module):
             x = mappings.gather_from_sequence_parallel_region(x, AXIS)
         elif tp > 1:
             x = mappings.copy_to_tensor_model_parallel_region(x, AXIS)
-        dt = self.compute_dtype or x.dtype
-        y = _local_matmul(x.astype(dt), w.astype(dt), self.fp8)
-        if b is not None and not self.skip_bias_add:
-            y = y + b.astype(dt)
+        with jax.named_scope("apex_linear"):
+            dt = self.compute_dtype or x.dtype
+            y = _local_matmul(x.astype(dt), w.astype(dt), self.fp8)
+            if b is not None and not self.skip_bias_add:
+                y = y + b.astype(dt)
         if self.gather_output and tp > 1:
             assert not self.sequence_parallel_enabled
             y = mappings.gather_from_tensor_model_parallel_region(y, AXIS)
@@ -147,7 +148,8 @@ class RowParallelLinear(nn.Module):
         if not self.input_is_parallel and tp > 1:
             x = mappings.scatter_to_tensor_model_parallel_region(x, AXIS)
         dt = self.compute_dtype or x.dtype
-        y = _local_matmul(x.astype(dt), w.astype(dt), self.fp8)
+        with jax.named_scope("apex_linear"):
+            y = _local_matmul(x.astype(dt), w.astype(dt), self.fp8)
         if tp > 1:
             if self.sequence_parallel_enabled:
                 y = mappings.reduce_scatter_to_sequence_parallel_region(
@@ -164,7 +166,8 @@ class RowParallelLinear(nn.Module):
         if self.skip_bias_add:
             return y, b
         if b is not None:
-            y = y + b.astype(dt)
+            with jax.named_scope("apex_linear"):
+                y = y + b.astype(dt)
         return y
 
 

@@ -90,7 +90,11 @@ SUITES = {
     # retrace counter) + the pyprof nvtx/prof satellites + the live
     # /metrics exporter
     "run_telemetry": ["tests/test_telemetry.py",
-                      "tests/test_export.py"],
+                      "tests/test_export.py",
+                      # spans as profiler events; the hot path's own
+                      # apex/* spans and apex_* scopes
+                      "tests/test_telemetry_spans.py",
+                      "tests/test_hot_path_scopes.py"],
     # the performance observatory: trace parsing, attribution/overlap,
     # cost-model MFU, report CLI, and the perf regression gate
     "run_profiler": ["tests/test_profiler.py"],
