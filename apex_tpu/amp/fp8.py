@@ -3,7 +3,7 @@
 The reference apex stops at fp16/bf16; fp8-capable TPUs run
 e4m3/e5m2 matmuls at roughly 2x the bf16 MXU rate, and the flat AMP
 pipeline already owns everything delayed scaling needs: per-bucket
-flat buffers, sorted-segment per-tensor reduces, the loss scaler's
+flat buffers, per-tensor reduces over static segments, the loss scaler's
 growth/backoff discipline and the watchdog's rollback safety net.
 
 Design (the transformer-engine recipe, bucketized):
@@ -23,7 +23,7 @@ Design (the transformer-engine recipe, bucketized):
   in the :class:`~apex_tpu.multi_tensor_apply.packer.BucketPlan`
   layout — one ``(n_leaves, H)`` history matrix and one
   ``(n_leaves,)`` scale vector per bucket — updated by ONE flat pass
-  per bucket (``ops.multi_tensor.flat_amax_scale_update``: sorted-
+  per bucket (``ops.multi_tensor.flat_amax_scale_update``: per-
   segment amax + history roll + scale recompute + per-tensor overflow
   backoff), never a per-leaf tree_map.  As optimizer slots
   (``FusedOptimizerBase.enable_fp8``) the state is donated, offloaded,
@@ -275,8 +275,7 @@ def update_packed(amax_history: Sequence[jax.Array],
     new_hist, new_scale, flags = [], [], []
     for bi, buf in enumerate(bufs):
         h, s, f = mt.flat_amax_scale_update(
-            buf, plan.segment_ids(bi), plan.num_segments(bi),
-            amax_history[bi], scale[bi],
+            buf, plan.segment_sizes(bi), amax_history[bi], scale[bi],
             fp8_max=fmax, margin=policy.margin,
             backoff_factor=policy.backoff_factor, update=update)
         new_hist.append(h)

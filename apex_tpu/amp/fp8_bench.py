@@ -65,7 +65,7 @@ def bench_fp8_matmul(m: int = 4096, k: int = 4096, n: int = 4096,
 def bench_fp8_scale_update(layers: int = 48, hidden: int = 256,
                            amax_history_len: int = 16,
                            iters: int = 10, reps: int = 3):
-    """Fused packed fp8 scale update (ONE flat segment-reduce pass per
+    """Fused packed fp8 scale update (ONE flat per-segment amax pass per
     bucket) vs the per-leaf oracle (amax per leaf via a tree walk) on
     the same many-leaf pytree — the dispatch-amortization win the
     packed state exists for, measured exactly like the other

@@ -67,8 +67,10 @@ def primitive_counts(jaxpr) -> collections.Counter:
 def concat_out_shapes(jaxpr) -> List[Tuple[int, ...]]:
     """Output shapes of every FLOATING ``concatenate`` — the
     gradient-pack signature: a pack shows up as exactly one bucket-sized
-    concat.  Integer concatenates are not packs (the bucket plan builds
-    its segment-id map in-program as one)."""
+    concat.  Integer concatenates are not packs.  LAMB's and NovoGrad's
+    per-element factor (each tensor's scalar broadcast over its static
+    extent) is a second bucket-sized floating one; their specs count
+    it."""
     import jax.numpy as jnp       # bf16-aware, unlike numpy's issubdtype
     return [tuple(e.outvars[0].aval.shape) for e in iter_eqns(jaxpr)
             if e.primitive.name == "concatenate"

@@ -52,9 +52,15 @@ _PALLAS_PER_BUCKET = {
     "FusedAdam": 1,       # flat_adam
     "FusedSGD": 1,        # flat_sgd
     "FusedAdagrad": 1,    # flat_adagrad
-    "FusedNovoGrad": 1,   # flat_novograd (segment reduce is XLA)
+    "FusedNovoGrad": 1,   # flat_novograd (per-tensor norms: XLA reduces
+                          # over the plan's static slices)
     "FusedLAMB": 3,       # flat_l2norm prologue + two-stage flat_lamb
 }
+
+# the segmented optimizers broadcast each tensor's scalar back over its
+# static extent (ops.multi_tensor.flat_segment_broadcast): a second
+# bucket-sized floating concatenate per bucket beside the gradient pack
+_SEGMENTED = {"FusedNovoGrad", "FusedLAMB"}
 
 
 def _tiny_params():
@@ -113,7 +119,7 @@ def _build_bucketed(name, **kw):
         "no_host_transfer": True,
         "no_f64": True,
         # ONE gradient pack: a bucket-sized concatenate per bucket
-        "bucket_concats": {"count": nb,
+        "bucket_concats": {"count": nb * (2 if name in _SEGMENTED else 1),
                            "sizes": {(b.size,)
                                      for b in opt._plan.buckets}},
         # donation honored: every packed state buffer aliases an output

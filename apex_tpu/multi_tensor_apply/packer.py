@@ -14,8 +14,9 @@ on the rare host-facing paths: ``state_dict()``, ``load_state_dict()``
 and the ``params`` property.
 
 Per-tensor semantics (LAMB trust ratios, NovoGrad per-tensor second
-moments) survive packing through each bucket's ``segment_ids``: a
-sorted i32 element->leaf map the segmented kernels reduce over.
+moments) survive packing through each bucket's ``segment_sizes``: the
+leaves' element counts as Python ints, in buffer order, so the
+segmented kernels reduce and broadcast over static slices.
 """
 
 from __future__ import annotations
@@ -280,21 +281,15 @@ class BucketPlan:
             self.treedef, self._unpack_leaves(bufs, dtypes=None))
 
     # ---- segment metadata ------------------------------------------------
-    def segment_ids(self, bucket_index: int) -> jax.Array:
-        """Sorted i32 element->bucket-local-leaf map for one bucket
-        (feeds the segmented LAMB/NovoGrad kernels).  Built with jnp
-        from the static leaf sizes, so under jit it is a concatenate of
-        broadcasts INSIDE the program — a temporary the compiler can
-        place and reuse — never a bucket-sized constant baked into the
-        executable (at BERT-Large's 334 M elements that constant was
-        1.25 GB of program and minutes of compile)."""
-        b = self.buckets[bucket_index]
-        return jnp.concatenate(
-            [jnp.full((s.size,), j, jnp.int32)
-             for j, s in enumerate(b.leaves)])
-
-    def num_segments(self, bucket_index: int) -> int:
-        return len(self.buckets[bucket_index].leaves)
+    def segment_sizes(self, bucket_index: int) -> Tuple[int, ...]:
+        """One bucket's static segment boundaries: its leaves' element
+        counts in buffer order (leaves are contiguous, so leaf ``j``
+        starts where ``sizes[:j]`` end).  Python ints — the segmented
+        LAMB/NovoGrad/fp8 kernels slice with them at trace time, and no
+        bucket-sized index array exists in the program or as a
+        constant of it (at BERT-Large's 334 M elements such a constant
+        was 1.25 GB of program and minutes of compile)."""
+        return tuple(s.size for s in self.buckets[bucket_index].leaves)
 
     def describe(self) -> List[dict]:
         """Human/bench-facing plan summary."""

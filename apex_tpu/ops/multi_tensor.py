@@ -578,44 +578,62 @@ def flat_adagrad_ref(p, g, h, *, lr, eps, weight_decay=0.0, grad_scale=1.0):
 
 # ---------------------------------------------------------------------------
 # segmented reductions over a bucket (per-TENSOR norms inside one flat
-# buffer; segment ids come from the bucket plan and are SORTED because
-# leaves are concatenated in order)
+# buffer).  A bucket's segments are its leaves: contiguous, in order, and
+# their sizes are Python ints from the bucket plan
+# (``BucketPlan.segment_sizes``), so every boundary is static at trace
+# time and a segment is a ``lax.slice``
 # ---------------------------------------------------------------------------
 
-def flat_segment_sumsq(x, seg_ids, num_segments: int):
-    """Per-segment sum of squares of a flat buffer, f32 accumulation.
+def _segments(x, sizes):
+    """The flat buffer's segments as static slices, in f32."""
+    if sum(sizes) != x.shape[0]:
+        raise ValueError(f"segment sizes sum to {sum(sizes)}, the buffer "
+                         f"holds {x.shape[0]} elements")
+    offset = 0
+    for size in sizes:
+        yield _f32(jax.lax.slice(x, (offset,), (offset + size,)))
+        offset += size
 
-    One XLA sorted-segment reduce — not a per-leaf loop; the elementwise
-    heavy lifting around it stays in the flat Pallas kernels."""
-    xf = _f32(x)
-    return jax.ops.segment_sum(xf * xf, seg_ids,
-                               num_segments=num_segments,
-                               indices_are_sorted=True)
+
+def flat_segment_sumsq(x, sizes):
+    """Per-segment sum of squares of a flat buffer, f32 accumulation;
+    shape ``(len(sizes),)``.
+
+    One XLA reduce per segment over its own static extent — a tree
+    sum, one sweep of the buffer in total; the elementwise heavy
+    lifting around it stays in the flat Pallas kernels."""
+    return jnp.stack([jnp.sum(seg * seg) for seg in _segments(x, sizes)])
 
 
-def flat_segment_absmax(x, seg_ids, num_segments: int):
-    """Per-segment max(|x|) of a flat buffer, f32 accumulation.
+def flat_segment_absmax(x, sizes):
+    """Per-segment max(|x|) of a flat buffer, f32; shape
+    ``(len(sizes),)``.
 
-    One XLA sorted-segment reduce per bucket — the per-TENSOR amax the
-    fp8 delayed-scaling state needs, from the same segment metadata the
-    LAMB/NovoGrad kernels already use.  Non-finite elements propagate
-    (|nan| is nan, |inf| is inf) so the caller's overflow detection
-    sees them."""
-    return jax.ops.segment_max(jnp.abs(_f32(x)), seg_ids,
-                               num_segments=num_segments,
-                               indices_are_sorted=True)
+    The per-TENSOR amax the fp8 delayed-scaling state needs, from the
+    same static boundaries the LAMB/NovoGrad kernels use.  Non-finite
+    elements propagate (|nan| is nan, |inf| is inf) so the caller's
+    overflow detection sees them; an empty segment reads 0."""
+    return jnp.stack([jnp.max(jnp.abs(seg), initial=0.0)
+                      for seg in _segments(x, sizes)])
+
+
+def flat_segment_broadcast(values, sizes):
+    """Per-element buffer holding each segment's scalar over its static
+    extent (``values``: ``(len(sizes),)``): a concatenate of broadcasts
+    the compiler builds inside the program."""
+    return jnp.concatenate([jnp.full((size,), values[j], values.dtype)
+                            for j, size in enumerate(sizes)])
 
 
 # ---------------------------------------------------------------------------
 # fused fp8 amax + delayed-scale update   [beyond-reference: the
 # transformer-engine delayed-scaling recipe collapsed to ONE flat pass
-# per bucket — per-tensor amax via a sorted-segment reduce, history
+# per bucket — per-tensor amax over the plan's static segments, history
 # roll, scale recompute and per-tensor overflow backoff all from that
 # single sweep, never a per-leaf tree_map]
 # ---------------------------------------------------------------------------
 
-def flat_amax_scale_update(buf, seg_ids, num_segments: int,
-                           amax_history, scale, *, fp8_max,
+def flat_amax_scale_update(buf, sizes, amax_history, scale, *, fp8_max,
                            margin: float = 0.0,
                            backoff_factor: float = 0.5,
                            max_scale: float = 2.0 ** 24,
@@ -623,6 +641,7 @@ def flat_amax_scale_update(buf, seg_ids, num_segments: int,
                            update=True):
     """One bucket's fp8 delayed-scaling bookkeeping in a single flat
     pass.  ``buf``: the bucket's flat buffer (any float dtype);
+    ``sizes``: its static segment sizes (``BucketPlan.segment_sizes``);
     ``amax_history``: (num_segments, H) f32, column 0 newest;
     ``scale``: (num_segments,) f32 — the CURRENT quantization scales
     (value * scale fills the fp8 range).
@@ -644,26 +663,27 @@ def flat_amax_scale_update(buf, seg_ids, num_segments: int,
     """
     if not op_enabled("multi_tensor"):
         return flat_amax_scale_update_ref(
-            buf, seg_ids, num_segments, amax_history, scale,
-            fp8_max=fp8_max, margin=margin,
+            buf, sizes, amax_history, scale, fp8_max=fp8_max, margin=margin,
             backoff_factor=backoff_factor, max_scale=max_scale,
             min_scale=min_scale, update=update)
-    amax = flat_segment_absmax(buf, seg_ids, num_segments)
+    amax = flat_segment_absmax(buf, sizes)
     return _amax_scale_math(amax, amax_history, scale, fp8_max, margin,
                             backoff_factor, max_scale, min_scale,
                             update)
 
 
-def flat_amax_scale_update_ref(buf, seg_ids, num_segments: int,
-                               amax_history, scale, *, fp8_max,
-                               margin: float = 0.0,
+def flat_amax_scale_update_ref(buf, sizes, amax_history, scale, *,
+                               fp8_max, margin: float = 0.0,
                                backoff_factor: float = 0.5,
                                max_scale: float = 2.0 ** 24,
                                min_scale: float = 2.0 ** -24,
                                update=True):
-    """Oracle: per-segment amax via scatter-max instead of the sorted
-    segment reduce; identical update math (bit-exact by test)."""
-    amax = jnp.zeros((num_segments,), jnp.float32).at[seg_ids].max(
+    """Oracle: per-segment amax via scatter-max through an
+    element->segment id vector instead of the static slices; identical
+    update math (bit-exact by test)."""
+    seg_ids = flat_segment_broadcast(
+        jnp.arange(len(sizes), dtype=jnp.int32), sizes)
+    amax = jnp.zeros((len(sizes),), jnp.float32).at[seg_ids].max(
         jnp.abs(_f32(buf)))
     return _amax_scale_math(amax, amax_history, scale, fp8_max, margin,
                             backoff_factor, max_scale, min_scale,
@@ -728,32 +748,31 @@ def _novograd_apply_kernel(grad_averaging, reg_inside_moment,
     po_ref[...] = (p - lr * update).astype(po_ref.dtype)
 
 
-def flat_novograd(p, g, m, v_seg, seg_ids, *, lr, beta1, beta2, eps,
+def flat_novograd(p, g, m, v_seg, sizes, *, lr, beta1, beta2, eps,
                   weight_decay=0.0, first_run=False, grad_averaging=True,
                   init_zero=False, reg_inside_moment=False, grad_scale=1.0):
     """One fused NovoGrad step over a flat bucket; returns (p, m, v_seg).
 
     ``v_seg`` is the per-TENSOR second moment, one f32 scalar per bucket
-    segment (shape ``(num_segments,)``); ``seg_ids`` maps each element of
-    the flat buffer to its segment (sorted, from the bucket plan).  The
-    per-segment gradient norms are one sorted-segment reduce; the
-    normalizer reaches the elementwise Pallas kernel as a gathered
-    per-element buffer, so the heavy math is still one grid launch.
+    segment (shape ``(num_segments,)``); ``sizes`` are the bucket's static
+    segment sizes (``BucketPlan.segment_sizes``).  The per-segment
+    gradient norms are reduced over those static extents; the
+    normalizer reaches the elementwise Pallas kernel as a per-element
+    buffer broadcast over them, so the heavy math is still one grid
+    launch.
     ``first_run`` may be a Python bool or a traced bool scalar.
     """
     if not op_enabled("multi_tensor"):
         return flat_novograd_ref(
-            p, g, m, v_seg, seg_ids, lr=lr, beta1=beta1, beta2=beta2,
+            p, g, m, v_seg, sizes, lr=lr, beta1=beta1, beta2=beta2,
             eps=eps, weight_decay=weight_decay, first_run=first_run,
             grad_averaging=grad_averaging, init_zero=init_zero,
             reg_inside_moment=reg_inside_moment, grad_scale=grad_scale)
-    num_seg = v_seg.shape[0]
     inv_scale = 1.0 / jnp.asarray(grad_scale, jnp.float32)
     b2 = jnp.asarray(beta2, jnp.float32)
     first = jnp.asarray(first_run, jnp.bool_)
     with jax.named_scope("apex_optim/grad_norm"):
-        g_norm_sq = flat_segment_sumsq(_f32(g) * inv_scale, seg_ids,
-                                       num_seg)
+        g_norm_sq = flat_segment_sumsq(_f32(g) * inv_scale, sizes)
         if init_zero:
             v_new = jnp.where(first, (1 - b2) * g_norm_sq,
                               b2 * v_seg + (1 - b2) * g_norm_sq)
@@ -762,7 +781,7 @@ def flat_novograd(p, g, m, v_seg, seg_ids, *, lr, beta1, beta2, eps,
                               b2 * v_seg + (1 - b2) * g_norm_sq)
         inv_denom = 1.0 / (jnp.sqrt(v_new)
                            + jnp.asarray(eps, jnp.float32))
-        d_elem = inv_denom[seg_ids]          # one gather, not per leaf
+        d_elem = flat_segment_broadcast(inv_denom, sizes)
     with jax.named_scope("apex_optim/moments"):
         s = jnp.stack([
             jnp.asarray(lr, jnp.float32), jnp.asarray(beta1, jnp.float32),
@@ -793,18 +812,17 @@ def flat_novograd(p, g, m, v_seg, seg_ids, *, lr, beta1, beta2, eps,
 
 
 @jax.named_scope("apex_optim/moments")
-def flat_novograd_ref(p, g, m, v_seg, seg_ids, *, lr, beta1, beta2, eps,
+def flat_novograd_ref(p, g, m, v_seg, sizes, *, lr, beta1, beta2, eps,
                       weight_decay=0.0, first_run=False,
                       grad_averaging=True, init_zero=False,
                       reg_inside_moment=False, grad_scale=1.0):
-    num_seg = v_seg.shape[0]
     pf = _f32(p)
     gf = _f32(g) / jnp.asarray(grad_scale, jnp.float32)
     b1 = jnp.asarray(beta1, jnp.float32)
     b2 = jnp.asarray(beta2, jnp.float32)
     wd = jnp.asarray(weight_decay, jnp.float32)
     first = jnp.asarray(first_run, jnp.bool_)
-    g_norm_sq = flat_segment_sumsq(gf, seg_ids, num_seg)
+    g_norm_sq = flat_segment_sumsq(gf, sizes)
     if init_zero:
         v_new = jnp.where(first, (1 - b2) * g_norm_sq,
                           b2 * v_seg + (1 - b2) * g_norm_sq)
@@ -812,7 +830,7 @@ def flat_novograd_ref(p, g, m, v_seg, seg_ids, *, lr, beta1, beta2, eps,
         v_new = jnp.where(first, g_norm_sq,
                           b2 * v_seg + (1 - b2) * g_norm_sq)
     denom = jnp.sqrt(v_new) + jnp.asarray(eps, jnp.float32)
-    gn = gf / denom[seg_ids]
+    gn = gf / flat_segment_broadcast(denom, sizes)
     if reg_inside_moment:
         gn = gn + wd * pf
     coeff = (1 - b1) if grad_averaging else jnp.float32(1.0)
@@ -845,15 +863,15 @@ def _apply_update_kernel(p_ref, u_ref, f_ref, po_ref):
                    - f_ref[...] * u_ref[...]).astype(po_ref.dtype)
 
 
-def flat_lamb(p, g, m, v, seg_ids, num_segments: int, *, lr, beta1, beta2,
-              eps, weight_decay=0.0, step=1, bias_correction=True,
+def flat_lamb(p, g, m, v, sizes, *, lr, beta1, beta2, eps,
+              weight_decay=0.0, step=1, bias_correction=True,
               grad_scale=1.0, clip_coeff=1.0, use_nvlamb=False):
     """One fused LAMB step over a flat bucket; returns (p, m, v).
 
     Two grid launches per bucket (the reference's stage1+stage2 shape):
     moments + unscaled update, then the trust-ratio-scaled apply.  The
-    per-TENSOR trust ratio ||p||/||update|| is computed from bucket
-    ``seg_ids`` with one sorted-segment reduce per norm — per-tensor
+    per-TENSOR trust ratio ||p||/||update|| is reduced over the bucket's
+    static segment ``sizes`` (``BucketPlan.segment_sizes``) — per-tensor
     semantics preserved without per-tensor kernels.  ``clip_coeff`` is
     the precomputed global-grad-norm clip factor (stage-1 side input).
     """
@@ -870,8 +888,8 @@ def flat_lamb(p, g, m, v, seg_ids, num_segments: int, *, lr, beta1, beta2,
             / jnp.asarray(grad_scale, jnp.float32))
     if not op_enabled("multi_tensor"):
         return flat_lamb_ref(
-            p, g, m, v, seg_ids, num_segments, lr=lr, beta1=beta1,
-            beta2=beta2, eps=eps, weight_decay=weight_decay, step=step,
+            p, g, m, v, sizes, lr=lr, beta1=beta1, beta2=beta2, eps=eps,
+            weight_decay=weight_decay, step=step,
             bias_correction=bias_correction, grad_scale=grad_scale,
             clip_coeff=clip_coeff, use_nvlamb=use_nvlamb)
     with jax.named_scope("apex_optim/moments"):
@@ -896,8 +914,7 @@ def flat_lamb(p, g, m, v, seg_ids, num_segments: int, *, lr, beta1, beta2,
             name="apex_multi_tensor_lamb_moments",
         )(s, p2d, g2d, m2d, v2d)
         update = _from_tiles(update2d, n)
-    factor_elem = _lamb_trust_factor(p, update, seg_ids, num_segments,
-                                     lr, wd, use_nvlamb)
+    factor_elem = _lamb_trust_factor(p, update, sizes, lr, wd, use_nvlamb)
     with jax.named_scope("apex_optim/apply"):
         f2d, _ = _as_tiles(factor_elem)
         u2d, _ = _as_tiles(update)
@@ -915,11 +932,11 @@ def flat_lamb(p, g, m, v, seg_ids, num_segments: int, *, lr, beta1, beta2,
 
 
 @jax.named_scope("apex_optim/trust_ratio")
-def _lamb_trust_factor(p, update, seg_ids, num_segments, lr, wd,
-                       use_nvlamb):
-    """Per-element lr*trust buffer from per-segment norms (one gather)."""
-    p_norm = jnp.sqrt(flat_segment_sumsq(p, seg_ids, num_segments))
-    u_norm_sq = flat_segment_sumsq(update, seg_ids, num_segments)
+def _lamb_trust_factor(p, update, sizes, lr, wd, use_nvlamb):
+    """Per-element lr*trust buffer: per-segment norms over the static
+    ``sizes``, each segment's scalar broadcast back over its extent."""
+    p_norm = jnp.sqrt(flat_segment_sumsq(p, sizes))
+    u_norm_sq = flat_segment_sumsq(update, sizes)
     u_norm = jnp.sqrt(u_norm_sq)
     trust = jnp.where((p_norm > 0) & (u_norm > 0), p_norm / u_norm, 1.0)
     if not use_nvlamb:
@@ -933,13 +950,13 @@ def _lamb_trust_factor(p, update, seg_ids, num_segments, lr, wd,
     _tape.emit("optim/max_trust_ratio", jnp.max(trust), reduce="max")
     _tape.emit("optim/update_norm", jnp.sqrt(jnp.sum(u_norm_sq)),
                reduce="rss")
-    return (jnp.asarray(lr, jnp.float32) * trust)[seg_ids]
+    return flat_segment_broadcast(jnp.asarray(lr, jnp.float32) * trust,
+                                  sizes)
 
 
-def flat_lamb_ref(p, g, m, v, seg_ids, num_segments: int, *, lr, beta1,
-                  beta2, eps, weight_decay=0.0, step=1,
-                  bias_correction=True, grad_scale=1.0, clip_coeff=1.0,
-                  use_nvlamb=False):
+def flat_lamb_ref(p, g, m, v, sizes, *, lr, beta1, beta2, eps,
+                  weight_decay=0.0, step=1, bias_correction=True,
+                  grad_scale=1.0, clip_coeff=1.0, use_nvlamb=False):
     step = jnp.asarray(step, jnp.float32)
     b1 = jnp.asarray(beta1, jnp.float32)
     b2 = jnp.asarray(beta2, jnp.float32)
@@ -957,7 +974,6 @@ def flat_lamb_ref(p, g, m, v, seg_ids, num_segments: int, *, lr, beta1,
             c1r = c2r = jnp.float32(1.0)
         update = (m * c1r) / (jnp.sqrt(v * c2r)
                               + jnp.asarray(eps, jnp.float32)) + wd * pf
-    factor = _lamb_trust_factor(pf, update, seg_ids, num_segments,
-                                lr, wd, use_nvlamb)
+    factor = _lamb_trust_factor(pf, update, sizes, lr, wd, use_nvlamb)
     with jax.named_scope("apex_optim/apply"):
         return (pf - factor * update).astype(p.dtype), m, v

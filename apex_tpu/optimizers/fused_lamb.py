@@ -82,8 +82,7 @@ class FusedLAMB(FusedOptimizerBase):
         h = self._merge_hypers(hypers)
         po, mo, vo = mt.flat_lamb(
             p, g, state["exp_avg"], state["exp_avg_sq"],
-            self._plan.segment_ids(bucket_index),
-            self._plan.num_segments(bucket_index),
+            self._plan.segment_sizes(bucket_index),
             lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],
             weight_decay=h["weight_decay"], step=step,
             bias_correction=self.hypers["bias_correction"],

@@ -68,11 +68,12 @@ class FusedNovoGrad(FusedOptimizerBase):
         if self.hypers["norm_type"] != 2:
             raise ValueError("FusedNovoGrad only supports norm_type=2")
         h = self._merge_hypers(hypers)
-        # per-tensor second moments ride the bucket's segment ids: the
-        # packed exp_avg_sq is one (num leaves,) vector per bucket
+        # per-tensor second moments ride the bucket's static segment
+        # sizes: the packed exp_avg_sq is one (num leaves,) vector per
+        # bucket
         po, mo, vo = mt.flat_novograd(
             p, g, state["exp_avg"], state["exp_avg_sq"],
-            self._plan.segment_ids(bucket_index),
+            self._plan.segment_sizes(bucket_index),
             lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],
             weight_decay=h["weight_decay"], first_run=step == 1,
             grad_averaging=self.hypers["grad_averaging"],

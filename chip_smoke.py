@@ -197,7 +197,6 @@ def _kernel_case(name, kernel, ref, args, rtol, atol):
 def phase_kernels():
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from apex_tpu.ops import layer_norm as ln
     from apex_tpu.ops import multi_tensor as mt
@@ -244,9 +243,8 @@ def phase_kernels():
     # flat optimizer kernels: one BERT-Large LAMB bucket (32.5 M, 28
     # tensors), ResNet-50's 25.6 M for SGD and the AMP unscale+norm
     n, n_seg = 32_537_600, 28
-    sizes = [n // n_seg] * (n_seg - 1)
-    seg = jnp.asarray(np.repeat(np.arange(n_seg, dtype=np.int32),
-                                sizes + [n - sum(sizes)]))
+    sizes = (n // n_seg,) * (n_seg - 1)
+    sizes += (n - sum(sizes),)
     p, g, m, v = (rnd(20, (n,), f32), rnd(21, (n,), f32, 0.1),
                   rnd(22, (n,), f32, 0.01),
                   jnp.abs(rnd(23, (n,), f32, 0.01)))
@@ -254,11 +252,9 @@ def phase_kernels():
               weight_decay=0.01, step=3)
     _kernel_case(
         f"flat_lamb n={n} seg={n_seg} f32",
-        lambda p, g, m, v, s: mt.flat_lamb(p, g, m, v, s, n_seg, **kw),
-        lambda p, g, m, v, s: mt.flat_lamb_ref(p, g, m, v, s, n_seg,
-                                               **kw),
-        (p, g, m, v, seg), 1e-4, 1e-5)
-    del seg
+        lambda p, g, m, v: mt.flat_lamb(p, g, m, v, sizes, **kw),
+        lambda p, g, m, v: mt.flat_lamb_ref(p, g, m, v, sizes, **kw),
+        (p, g, m, v), 1e-4, 1e-5)
     n = 25_557_032
     p, g, m = p[:n], g[:n], m[:n]
     kw = dict(lr=0.1, momentum=0.9, weight_decay=1e-4)

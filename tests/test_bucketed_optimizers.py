@@ -256,13 +256,40 @@ class TestBucketPlan:
                         jax.tree_util.tree_leaves(back)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    def test_segment_ids_sorted_and_sized(self):
+    def test_segment_sizes_follow_the_leaves(self):
         tree = {"a": jnp.ones((3, 2)), "b": jnp.ones((5,))}
         plan = BucketPlan.from_tree(tree)
-        ids = np.asarray(plan.segment_ids(0))
-        assert ids.shape == (11,)
-        assert (np.diff(ids) >= 0).all()
-        assert plan.num_segments(0) == 2
+        sizes = plan.segment_sizes(0)
+        assert sizes == (6, 5) and all(type(s) is int for s in sizes)
+        assert sum(sizes) == plan.buckets[0].size == 11
+        # contiguous and in order: leaf j starts where sizes[:j] end
+        assert [s.offset for s in plan.buckets[0].leaves] == [0, 6]
+        assert len(sizes) == len(plan.buckets[0].leaves) == 2
+
+
+@pytest.mark.parametrize("pallas", [True, False],
+                         ids=["kernels", "xla_oracle"])
+@pytest.mark.parametrize("cls", [FusedLAMB, FusedNovoGrad],
+                         ids=lambda c: c.__name__)
+def test_segmented_step_program_has_no_scatter_or_gather(cls, pallas,
+                                                         monkeypatch):
+    """Per-tensor norms come from the plan's static segment sizes: the
+    jitted bucketed step scatters nothing through an element->segment
+    id vector, gathers nothing from one, and holds no integer array of
+    bucket size.  Both ``op_enabled("multi_tensor")`` branches."""
+    if not pallas:
+        monkeypatch.setenv("APEX_TPU_DISABLE_PALLAS", "1")
+    params = _params(jnp.bfloat16)
+    opt = cls(params, lr=1e-2, weight_decay=0.01, master_weights=True)
+    assert opt.fuse_buckets and len(opt._plan.buckets) == 1
+    n = opt._plan.buckets[0].size
+    assert len(opt._plan.segment_sizes(0)) == 5
+    text = opt._jit_step.lower(
+        *opt._step_args(_grads(params, 7), 1.0, jnp.int32(0))).as_text()
+    assert "stablehlo.reduce" in text           # the norms are there
+    for word in ("scatter", "gather", f"tensor<{n}xi32>",
+                 f"tensor<{n}xui32>", f"tensor<{n}xi64>"):
+        assert word not in text, word
 
 
 def test_functional_step_layout_detection():

@@ -243,27 +243,71 @@ def test_flat_adagrad_matches_ref(dtype):
     np.testing.assert_allclose(ho, hr, rtol=1e-5, atol=1e-6)
 
 
-def _segmented_buffers(n_leaves=4, key=6):
-    sizes = [257, 128, 1000, 5]
-    n = sum(sizes)
-    seg = jnp.asarray(np.repeat(np.arange(n_leaves, dtype=np.int32),
-                                sizes))
+SEGMENT_SIZES = (257, 128, 1000, 5)
+
+
+def _segmented_buffers(key=6):
+    n = sum(SEGMENT_SIZES)
     ks = jax.random.split(jax.random.key(key), 4)
     p = jax.random.normal(ks[0], (n,))
     g = jax.random.normal(ks[1], (n,))
     m = jax.random.normal(ks[2], (n,)) * 0.1
     v = jnp.abs(jax.random.normal(ks[3], (n,))) * 0.1
-    return p, g, m, v, seg, n_leaves
+    return p, g, m, v, SEGMENT_SIZES
+
+
+@pytest.mark.parametrize("sizes,dtype,poison", [
+    ((257, 128, 1000, 5), jnp.float32, None),       # no multiple of 128
+    ((3000, 1, 1024, 1, 7), jnp.float32, None),     # 1-element leaves
+    ((4097,), jnp.float32, None),                   # single-leaf bucket
+    ((1024, 2048, 1024), jnp.float32, None),        # tile-aligned
+    ((257, 128, 1000, 5), jnp.bfloat16, None),      # f32 accumulation
+    ((300, 1, 77), jnp.float32, np.nan),            # nan stays in its leaf
+    ((300, 1, 77), jnp.float32, np.inf),
+], ids=["ragged", "one_element", "single_leaf", "aligned", "bf16", "nan",
+        "inf"])
+def test_flat_segment_reductions_match_per_leaf(sizes, dtype, poison):
+    """The static-boundary reductions against plain per-leaf ``jnp.sum``
+    / ``jnp.max`` on slices of the same buffer, and the broadcast back
+    against ``np.repeat``."""
+    n = sum(sizes)
+    x = (jax.random.normal(jax.random.key(11), (n,)) * 3.0).astype(dtype)
+    if poison is not None:
+        x = x.at[sizes[0]].set(poison)          # leaf 1's only element
+    sumsq = jax.jit(lambda a: mt.flat_segment_sumsq(a, sizes))(x)
+    absmax = jax.jit(lambda a: mt.flat_segment_absmax(a, sizes))(x)
+    assert sumsq.shape == absmax.shape == (len(sizes),)
+    assert sumsq.dtype == absmax.dtype == jnp.float32
+    xf = np.asarray(x, np.float32)
+    bounds = np.cumsum((0,) + tuple(sizes))
+    leaves = [xf[a:b] for a, b in zip(bounds[:-1], bounds[1:])]
+    np.testing.assert_allclose(
+        sumsq, [np.sum(np.square(l, dtype=np.float64)) for l in leaves],
+        rtol=1e-5)
+    np.testing.assert_array_equal(absmax,
+                                  [np.max(np.abs(l)) for l in leaves])
+    if poison is not None:
+        finite = np.isfinite(np.stack([sumsq, absmax]))
+        assert not finite[:, 1].any() and finite[:, (0, 2)].all()
+    back = mt.flat_segment_broadcast(jnp.arange(len(sizes), dtype=jnp.float32),
+                                     sizes)
+    np.testing.assert_array_equal(
+        back, np.repeat(np.arange(len(sizes), dtype=np.float32), sizes))
+
+
+def test_flat_segment_sizes_must_cover_the_buffer():
+    with pytest.raises(ValueError, match="segment sizes sum to 10"):
+        mt.flat_segment_sumsq(jnp.ones((11,)), (4, 6))
 
 
 @pytest.mark.parametrize("use_nvlamb", [False, True])
 def test_flat_lamb_matches_ref(use_nvlamb):
-    p, g, m, v, seg, ns = _segmented_buffers()
+    p, g, m, v, sizes = _segmented_buffers()
     kw = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6,
               weight_decay=0.01, step=3, clip_coeff=0.7,
               use_nvlamb=use_nvlamb)
-    po, mo, vo = mt.flat_lamb(p, g, m, v, seg, ns, **kw)
-    pr, mr, vr = mt.flat_lamb_ref(p, g, m, v, seg, ns, **kw)
+    po, mo, vo = mt.flat_lamb(p, g, m, v, sizes, **kw)
+    pr, mr, vr = mt.flat_lamb_ref(p, g, m, v, sizes, **kw)
     np.testing.assert_allclose(po, pr, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(mo, mr, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(vo, vr, rtol=1e-5, atol=1e-6)
@@ -273,11 +317,10 @@ def test_flat_lamb_trust_ratio_is_per_segment():
     """The segmented kernel must reproduce the per-leaf trust ratios —
     not one bucket-global ratio."""
     from apex_tpu.optimizers import _functional as F
-    p, g, m, v, seg, ns = _segmented_buffers()
-    sizes = [257, 128, 1000, 5]
+    p, g, m, v, sizes = _segmented_buffers()
     kw = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6,
               weight_decay=0.01, step=3)
-    po, _, _ = mt.flat_lamb(p, g, m, v, seg, ns, **kw)
+    po, _, _ = mt.flat_lamb(p, g, m, v, sizes, **kw)
     o = 0
     for sz in sizes:
         sl = slice(o, o + sz)
@@ -289,13 +332,13 @@ def test_flat_lamb_trust_ratio_is_per_segment():
 @pytest.mark.parametrize("first_run", [True, False])
 def test_flat_novograd_matches_per_leaf(first_run):
     from apex_tpu.optimizers import _functional as F
-    p, g, m, _, seg, ns = _segmented_buffers(key=8)
-    sizes = [257, 128, 1000, 5]
-    vseg = jnp.abs(jax.random.normal(jax.random.key(9), (ns,))) * 0.2
+    p, g, m, _, sizes = _segmented_buffers(key=8)
+    vseg = jnp.abs(jax.random.normal(jax.random.key(9),
+                                     (len(sizes),))) * 0.2
     kw = dict(lr=1e-3, beta1=0.95, beta2=0.98, eps=1e-8,
               weight_decay=0.01, first_run=first_run)
-    po, mo, vo = mt.flat_novograd(p, g, m, vseg, seg, **kw)
-    pr, mr, vr = mt.flat_novograd_ref(p, g, m, vseg, seg, **kw)
+    po, mo, vo = mt.flat_novograd(p, g, m, vseg, sizes, **kw)
+    pr, mr, vr = mt.flat_novograd_ref(p, g, m, vseg, sizes, **kw)
     np.testing.assert_allclose(po, pr, rtol=1e-5, atol=1e-6)
     o = 0
     for i, sz in enumerate(sizes):

@@ -116,21 +116,34 @@ def test_flash_attention_fwd_bwd(one_chip, shape, causal):
 # the flat optimizer / AMP kernels at real bucket sizes
 # ---------------------------------------------------------------------------
 
-N_LAMB_BUCKET = 32_537_600      # one 128 MiB-capped BERT-Large bucket
+# one 128 MiB-capped BERT-Large bucket: two encoder layers and a third
+# of the next, 28 leaves (mlp_in, its bias, mlp_out, bias, two
+# LayerNorms, attn_proj, attn_qkv's bias, attn_qkv, ...)
+_BERT_LAYER = (3145728, 4096, 4194304, 1024, 4194304, 1024, 1024, 1024,
+               1024, 1024, 1048576, 3072)
+LAMB_BUCKET_SIZES = _BERT_LAYER * 2 + _BERT_LAYER[:4]
+N_LAMB_BUCKET = sum(LAMB_BUCKET_SIZES)
 N_RESNET50 = 25_557_032
 
 
-def test_flat_lamb_one_bert_large_bucket(one_chip):
+def _lamb(sizes):
     from apex_tpu.ops import multi_tensor as mt
-    n = N_LAMB_BUCKET
+    return lambda p, g, m, v: mt.flat_lamb(
+        p, g, m, v, sizes, lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6,
+        weight_decay=0.01, step=3)
 
-    def step(p, g, m, v, seg):
-        return mt.flat_lamb(p, g, m, v, seg, 28, lr=1e-3, beta1=0.9,
-                            beta2=0.999, eps=1e-6, weight_decay=0.01,
-                            step=3)
-    text = _compile(step, one_chip, *(((n,), F32),) * 4, ((n,), I32))
+
+def test_flat_lamb_one_bert_large_bucket(one_chip):
+    n = N_LAMB_BUCKET
+    assert n == 32_537_600 and len(LAMB_BUCKET_SIZES) == 28
+    text = _compile(_lamb(LAMB_BUCKET_SIZES), one_chip,
+                    *(((n,), F32),) * 4)
     _assert_kernels(text, "apex_multi_tensor_lamb_moments",
                     "apex_multi_tensor_lamb_apply")
+    # per-tensor norms are reduces over static slices: nothing is
+    # scattered through or gathered from an element->segment id vector
+    assert "scatter" not in text and "gather" not in text
+    assert f"s32[{n}]" not in text
 
 
 def test_flat_sgd_resnet50_size(one_chip):
@@ -150,21 +163,18 @@ def test_flat_unscale_norm_resnet50_size(one_chip):
     _assert_kernels(text, "apex_multi_tensor_unscale_norm")
 
 
-def test_segment_ids_are_built_in_the_program(one_chip):
-    """BucketPlan.segment_ids under jit must be a concatenate of
-    broadcasts, never a bucket-sized literal: as a baked-in constant
-    BERT-Large's one-bucket LAMB step was 1.25 GB of program."""
+def test_segment_boundaries_are_not_baked_into_the_program(one_chip):
+    """The plan's static segment sizes must stay slice bounds: the
+    per-element factor is a concatenate of broadcasts built inside the
+    program, never a bucket-sized literal (as a baked-in constant
+    BERT-Large's one-bucket LAMB step was 1.25 GB of program)."""
     from apex_tpu.multi_tensor_apply.packer import BucketPlan
-    from apex_tpu.ops import multi_tensor as mt
     leaves = [jnp.zeros((1024, 1024), F32)] * 8 + [jnp.zeros((1024,), F32)]
     plan = BucketPlan.from_tree(leaves)
     n = plan.buckets[0].size
-
-    def sumsq(x):
-        return mt.flat_segment_sumsq(x, plan.segment_ids(0),
-                                     plan.num_segments(0))
-    compiled = jax.jit(sumsq).lower(
-        jax.ShapeDtypeStruct((n,), F32, sharding=one_chip)).compile()
+    x = jax.ShapeDtypeStruct((n,), F32, sharding=one_chip)
+    compiled = jax.jit(_lamb(plan.segment_sizes(0))).lower(
+        x, x, x, x).compile()
     code = compiled.memory_analysis().generated_code_size_in_bytes
     assert code < n, f"{code} bytes of program for a {n}-element bucket"
 

@@ -184,12 +184,12 @@ def test_lower_multi_tensor_family():
         nesterov=False, first_run=False), p, p, p)
     lower_tpu(lambda *a: mt.flat_adagrad(
         *a, lr=1e-2, eps=1e-10, weight_decay=0.01), p, p, p)
-    # segmented family: per-tensor norms via bucket segment ids
-    seg = jnp.zeros((n,), jnp.int32)
+    # segmented family: per-tensor norms over static segment sizes
+    sizes = (n - 100, 100)
     lower_tpu(lambda p_, g_, m_, v_: mt.flat_lamb(
-        p_, g_, m_, v_, seg, 1, lr=1e-3, beta1=0.9, beta2=0.999,
+        p_, g_, m_, v_, sizes, lr=1e-3, beta1=0.9, beta2=0.999,
         eps=1e-6, weight_decay=0.01, step=3), p, p, p, p)
-    vseg = jnp.zeros((1,), jnp.float32)
+    vseg = jnp.zeros((2,), jnp.float32)
     lower_tpu(lambda p_, g_, m_: mt.flat_novograd(
-        p_, g_, m_, vseg, seg, lr=1e-3, beta1=0.95, beta2=0.98,
+        p_, g_, m_, vseg, sizes, lr=1e-3, beta1=0.95, beta2=0.98,
         eps=1e-8, weight_decay=0.01, first_run=False), p, p, p)

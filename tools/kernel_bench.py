@@ -545,18 +545,15 @@ def main():
         lambda *a: mt.flat_adam(*a, **kw),
         lambda *a: mt.flat_adam_ref(*a, **kw), p, g, m, v))
     # segmented LAMB over the same buffer, carved into 256 "tensors"
-    import numpy as np
     n_seg = 256
-    seg = jnp.asarray(np.repeat(np.arange(n_seg, dtype=np.int32),
-                                n // n_seg))
+    sizes = (n // n_seg,) * n_seg
     kwl = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6,
                weight_decay=0.01, step=3, clip_coeff=1.0)
     rows.append(bench_pair(
         "flat_lamb", f"n={n}/seg{n_seg}", "f32",
-        lambda p_, g_, m_, v_: mt.flat_lamb(p_, g_, m_, v_, seg, n_seg,
-                                            **kwl),
-        lambda p_, g_, m_, v_: mt.flat_lamb_ref(p_, g_, m_, v_, seg,
-                                                n_seg, **kwl),
+        lambda p_, g_, m_, v_: mt.flat_lamb(p_, g_, m_, v_, sizes, **kwl),
+        lambda p_, g_, m_, v_: mt.flat_lamb_ref(p_, g_, m_, v_, sizes,
+                                                **kwl),
         p, g, m, v))
 
     # per-leaf vs bucketed fused-optimizer step on a many-leaf pytree —
