@@ -11,10 +11,13 @@ from apex_tpu.models.resnet import (BasicBlock, Bottleneck, ResNet,
 from apex_tpu.models.gpt import GPTLayer, GPTModel, GPTStage
 from apex_tpu.models.bert import (BertLayer, BertModel, bert_base,
                                   bert_large)
+from apex_tpu.models.looped import (LoopedDecoder, LoopedDecoderLayer,
+                                    LoopedPass)
 
 __all__ = [
     "BasicBlock", "Bottleneck", "ResNet",
     "resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
     "GPTLayer", "GPTModel", "GPTStage",
     "BertLayer", "BertModel", "bert_base", "bert_large",
+    "LoopedDecoder", "LoopedDecoderLayer", "LoopedPass",
 ]

@@ -58,7 +58,8 @@ SUITES = {
                        # incident-id correlation + the merged fleet
                        # timeline (telemetry timeline CLI)
                        "tests/test_incident_timeline.py"],
-    "run_models": ["tests/test_models.py"],
+    "run_models": ["tests/test_models.py",
+                   "tests/test_looped_decoder.py"],
     "run_examples": ["tests/test_examples_smoke.py"],
     "run_data": ["tests/test_data.py"],
     "run_offload": ["tests/test_offload.py"],

@@ -614,6 +614,14 @@ def test_gpt_block_tiny(capsys):
     assert "step time" in capsys.readouterr().out
 
 
+@pytest.mark.slow
+def test_gpt_looped_tiny(capsys):
+    _run("examples/gpt/train_looped.py",
+         ["--cpu", "--steps", "4", "--layers", "1", "--passes", "2"])
+    out = capsys.readouterr().out
+    assert "looped decoder L1 x2" in out and "step time" in out
+
+
 def test_train_tp_converges(capsys):
     _run("examples/simple/train_tp.py", [])
     assert "OK: loss" in capsys.readouterr().out
