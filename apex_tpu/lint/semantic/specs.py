@@ -4,11 +4,13 @@ Every spec here traces a REAL public entry point with tiny abstract
 inputs and pins the structural facts earlier PRs proved ad hoc:
 
 * the five fused optimizers, per-leaf AND bucketed — zero host
-  transfer primitives, the exact flat-kernel count per bucket, the
-  single bucket-sized gradient pack, donation reflected as
-  input-output aliasing in the lowered HLO, no f64;
-* the flat AMP pipeline step — 2 Pallas calls per bucket (unscale+norm
-  fused with the optimizer kernel chain), never a per-leaf finite
+  transfer primitives, no kernel in the update (the bucketed step is
+  ``jnp`` that XLA fuses with the overflow skip and the model-dtype
+  copy, PERF.md section 6, PR 28), the single bucket-sized gradient
+  pack, donation reflected as input-output aliasing in the lowered
+  HLO, no f64;
+* the flat AMP pipeline step — 1 Pallas call per bucket (unscale+norm
+  fused, ahead of the optimizer's XLA sweeps), never a per-leaf finite
   check;
 * ``amp.scaled_value_and_grad`` (per-leaf oracle surface) — no host
   traffic, no f64;
@@ -48,14 +50,8 @@ import functools
 
 from apex_tpu.lint.semantic.registry import register_spec
 
-_PALLAS_PER_BUCKET = {
-    "FusedAdam": 1,       # flat_adam
-    "FusedSGD": 1,        # flat_sgd
-    "FusedAdagrad": 1,    # flat_adagrad
-    "FusedNovoGrad": 1,   # flat_novograd (per-tensor norms: XLA reduces
-                          # over the plan's static slices)
-    "FusedLAMB": 3,       # flat_l2norm prologue + two-stage flat_lamb
-}
+_BUCKETED_OPTIMIZERS = ("FusedAdam", "FusedSGD", "FusedAdagrad",
+                        "FusedNovoGrad", "FusedLAMB")
 
 # the segmented optimizers broadcast each tensor's scalar back over its
 # static extent (ops.multi_tensor.flat_segment_broadcast): a second
@@ -92,15 +88,23 @@ def _traced_hypers(opt):
             if isinstance(v, float) and not isinstance(v, bool)}
 
 
-def _optimizer(name, **kw):
+def _optimizer(name, masters=False, **kw):
+    """``masters``: bfloat16 parameters, so the optimizer keeps float32
+    masters and the step writes the model-dtype copy as well."""
+    import jax
+    import jax.numpy as jnp
     from apex_tpu import optimizers
-    return getattr(optimizers, name)(_tiny_params(), lr=1e-3, **kw)
+    params = _tiny_params()
+    if masters:
+        params = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16), params)
+    return getattr(optimizers, name)(params, lr=1e-3, **kw)
 
 
 def _step_args(opt):
     import jax
     import jax.numpy as jnp
-    grads = jax.tree_util.tree_map(jnp.ones_like, _tiny_params())
+    grads = jax.tree_util.tree_map(jnp.ones_like, opt.params)
     work = opt._param_bufs if opt._plan is not None else opt.params
     masters = opt._master_bufs if opt._plan is not None else None
     return (work, masters, opt.opt_state, grads, jnp.int32(1),
@@ -109,7 +113,6 @@ def _step_args(opt):
 
 def _build_bucketed(name, **kw):
     import jax
-    from apex_tpu.ops._dispatch import op_enabled
     opt = _optimizer(name, **kw)
     assert opt._plan is not None, f"{name}: packer declined tiny tree"
     args = _step_args(opt)
@@ -125,10 +128,10 @@ def _build_bucketed(name, **kw):
         # donation honored: every packed state buffer aliases an output
         "donated_aliases": n_state,
         "no_orphan_collectives": True,
+        # the update is jnp: XLA fuses it with the skip and the cast
+        "pallas_calls": 0,
+        "is_finite_max": 0,           # found_inf arrives as a flag
     }
-    if op_enabled("multi_tensor"):
-        expect["pallas_calls"] = _PALLAS_PER_BUCKET[name] * nb
-        expect["is_finite_max"] = 0   # kernels carry the finite flag
     return {"fn": opt._full_step_impl, "args": args,
             "jit_kwargs": {"donate_argnums": (2,)}, "expect": expect}
 
@@ -154,13 +157,14 @@ def _build_per_leaf(name, **kw):
 
 _OPT_KW = {"FusedSGD": {"momentum": 0.9}}
 
-for _name in sorted(_PALLAS_PER_BUCKET):
+for _name in sorted(_BUCKETED_OPTIMIZERS):
     _anchor = ("apex_tpu/optimizers/"
                f"{_name.replace('Fused', 'fused_').lower()}.py")
     register_spec(
         f"optim.{_name}.bucketed", anchor=_anchor,
-        description=f"bucketed {_name} step: flat kernels per bucket, "
-                    "one grad pack, donated state, zero host traffic")(
+        description=f"bucketed {_name} step: fused XLA sweeps per "
+                    "bucket, one grad pack, donated state, zero host "
+                    "traffic")(
         functools.partial(_build_bucketed, _name,
                           **_OPT_KW.get(_name, {})))
     register_spec(
@@ -175,7 +179,8 @@ for _name in sorted(_PALLAS_PER_BUCKET):
     "amp.flat_pipeline_step",
     anchor="apex_tpu/amp/flat_pipeline.py",
     description="flat AMP train step: one grad pack per bucket, "
-                "unscale+norm fused (2 pallas/bucket with FusedAdam), "
+                "unscale+norm fused (1 pallas/bucket; FusedAdam's update "
+                "is XLA), "
                 "no per-leaf finite checks, zero host traffic")
 def _build_flat_pipeline_step():
     import jax
@@ -215,10 +220,10 @@ def _build_flat_pipeline_step():
         "no_orphan_collectives": True,
     }
     if op_enabled("multi_tensor"):
-        # exactly unscale_norm + adam per bucket: clipping folds into
-        # the optimizer kernel's grad scaling, nothing else touches
-        # the gradients
-        expect["pallas_calls"] = 2 * nb
+        # exactly unscale_norm per bucket: clipping folds into the
+        # optimizer update's grad scaling (XLA sweeps, no kernel),
+        # nothing else touches the gradients
+        expect["pallas_calls"] = nb
         expect["is_finite_max"] = 0
     return {"fn": flat_step, "args": args, "expect": expect}
 

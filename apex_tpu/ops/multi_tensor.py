@@ -14,6 +14,17 @@ SURVEY.md §3.2).
 
 Every kernel has a pure-jnp oracle (suffix ``_ref``) used for testing and
 as the XLA fallback when Pallas is disabled.
+
+The optimizer updates' ``_ref`` functions are more than oracles: they are
+what the bucketed optimizer step runs (``optimizers/_base.py``).  Measured
+on the chip (PERF.md section 6, PR 28), XLA fuses an update with the
+overflow skip, the model-dtype copy of the masters and LAMB's per-tensor
+broadcast into one sweep per phase, where a kernel is opaque to it and
+pays a pad, a slice, a select and a cast around itself.  They take the
+step's ``keep`` flag and the bucket's ``model_dtype`` for that.  The
+update kernels (``flat_adam``, ``flat_sgd``, ``flat_adagrad``,
+``flat_novograd``, ``flat_lamb``) are kept with their tests; no step
+calls them (ROADMAP, Design).
 """
 
 from __future__ import annotations
@@ -74,6 +85,20 @@ def _smem_spec():
 
 def _f32(x):
     return x.astype(jnp.float32)
+
+
+def _keep_and_cast(keep, model_dtype, new, old):
+    """The update oracles' epilogue (``new``/``old``: tuples, parameters
+    first).  ``keep`` (traced bool, the step's ``found_inf == 0``):
+    every result is ``where(keep, new, old)``, a select XLA fuses into
+    the update's own sweep — a skipped step returns its inputs to the
+    bit.  ``model_dtype``: the new parameters in that dtype as one more
+    result of the same sweep."""
+    if keep is not None:
+        new = tuple(jnp.where(keep, n, o) for n, o in zip(new, old))
+    if model_dtype is not None:
+        new = new + (new[0].astype(model_dtype),)
+    return new
 
 
 def _all_finite(x):
@@ -417,7 +442,12 @@ def flat_adam(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, step,
 
 
 def flat_adam_ref(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, step,
-                  adam_w_mode=True, bias_correction=True, grad_scale=1.0):
+                  adam_w_mode=True, bias_correction=True, grad_scale=1.0,
+                  keep=None, model_dtype=None):
+    """``flat_adam`` in ``jnp``; with ``keep`` / ``model_dtype``
+    (``_keep_and_cast``) the bucketed step's whole Adam sweep:
+    (p, m, v) or (p, m, v, p_model)."""
+    m_old, v_old = m, v
     step = jnp.asarray(step, jnp.float32)
     b1 = jnp.asarray(beta1, jnp.float32)
     b2 = jnp.asarray(beta2, jnp.float32)
@@ -436,7 +466,9 @@ def flat_adam_ref(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, step,
     update = (m * c1r) / (jnp.sqrt(v * c2r) + jnp.asarray(eps, jnp.float32))
     if adam_w_mode:
         update = update + wd * pf
-    return (pf - jnp.asarray(lr, jnp.float32) * update).astype(p.dtype), m, v
+    p_new = (pf - jnp.asarray(lr, jnp.float32) * update).astype(p.dtype)
+    return _keep_and_cast(keep, model_dtype, (p_new, m, v),
+                          (p, m_old, v_old))
 
 
 # ---------------------------------------------------------------------------
@@ -503,7 +535,9 @@ def flat_sgd(p, g, momentum_buf, *, lr, momentum=0.0, dampening=0.0,
 
 def flat_sgd_ref(p, g, momentum_buf, *, lr, momentum=0.0, dampening=0.0,
                  weight_decay=0.0, nesterov=False, first_run=False,
-                 grad_scale=1.0):
+                 grad_scale=1.0, keep=None, model_dtype=None):
+    """``flat_sgd`` in ``jnp``; ``keep`` / ``model_dtype`` as in
+    ``flat_adam_ref``: (p, momentum_buf[, p_model])."""
     pf = _f32(p)
     gf = _f32(g) / jnp.asarray(grad_scale, jnp.float32)
     gf = gf + jnp.asarray(weight_decay, jnp.float32) * pf
@@ -518,7 +552,9 @@ def flat_sgd_ref(p, g, momentum_buf, *, lr, momentum=0.0, dampening=0.0,
     else:
         buf = momentum_buf
         step_dir = gf
-    return (pf - jnp.asarray(lr, jnp.float32) * step_dir).astype(p.dtype), buf
+    p_new = (pf - jnp.asarray(lr, jnp.float32) * step_dir).astype(p.dtype)
+    return _keep_and_cast(keep, model_dtype, (p_new, buf),
+                          (p, momentum_buf))
 
 
 # ---------------------------------------------------------------------------
@@ -567,13 +603,18 @@ def flat_adagrad(p, g, h, *, lr, eps, weight_decay=0.0, grad_scale=1.0):
     return _from_tiles(po, n), _from_tiles(ho, n)
 
 
-def flat_adagrad_ref(p, g, h, *, lr, eps, weight_decay=0.0, grad_scale=1.0):
+def flat_adagrad_ref(p, g, h, *, lr, eps, weight_decay=0.0, grad_scale=1.0,
+                     keep=None, model_dtype=None):
+    """``flat_adagrad`` in ``jnp``; ``keep`` / ``model_dtype`` as in
+    ``flat_adam_ref``: (p, h[, p_model])."""
+    h_old = h
     pf = _f32(p)
     gf = _f32(g) / jnp.asarray(grad_scale, jnp.float32)
     gf = gf + jnp.asarray(weight_decay, jnp.float32) * pf
     h = h + gf * gf
-    return (pf - jnp.asarray(lr, jnp.float32) * gf /
-            (jnp.sqrt(h) + jnp.asarray(eps, jnp.float32))).astype(p.dtype), h
+    p_new = (pf - jnp.asarray(lr, jnp.float32) * gf /
+             (jnp.sqrt(h) + jnp.asarray(eps, jnp.float32))).astype(p.dtype)
+    return _keep_and_cast(keep, model_dtype, (p_new, h), (p, h_old))
 
 
 # ---------------------------------------------------------------------------
@@ -811,33 +852,39 @@ def flat_novograd(p, g, m, v_seg, sizes, *, lr, beta1, beta2, eps,
         return _from_tiles(po, n), _from_tiles(mo, n), v_new
 
 
-@jax.named_scope("apex_optim/moments")
 def flat_novograd_ref(p, g, m, v_seg, sizes, *, lr, beta1, beta2, eps,
                       weight_decay=0.0, first_run=False,
                       grad_averaging=True, init_zero=False,
-                      reg_inside_moment=False, grad_scale=1.0):
+                      reg_inside_moment=False, grad_scale=1.0,
+                      keep=None, model_dtype=None):
+    """``flat_novograd`` in ``jnp``; ``keep`` / ``model_dtype`` as in
+    ``flat_adam_ref``: (p, m, v_seg[, p_model])."""
+    m_old = m
     pf = _f32(p)
     gf = _f32(g) / jnp.asarray(grad_scale, jnp.float32)
     b1 = jnp.asarray(beta1, jnp.float32)
     b2 = jnp.asarray(beta2, jnp.float32)
     wd = jnp.asarray(weight_decay, jnp.float32)
     first = jnp.asarray(first_run, jnp.bool_)
-    g_norm_sq = flat_segment_sumsq(gf, sizes)
-    if init_zero:
-        v_new = jnp.where(first, (1 - b2) * g_norm_sq,
-                          b2 * v_seg + (1 - b2) * g_norm_sq)
-    else:
-        v_new = jnp.where(first, g_norm_sq,
-                          b2 * v_seg + (1 - b2) * g_norm_sq)
-    denom = jnp.sqrt(v_new) + jnp.asarray(eps, jnp.float32)
-    gn = gf / flat_segment_broadcast(denom, sizes)
-    if reg_inside_moment:
-        gn = gn + wd * pf
-    coeff = (1 - b1) if grad_averaging else jnp.float32(1.0)
-    m = jnp.where(first, gn, b1 * m + coeff * gn)
-    update = m if reg_inside_moment else m + wd * pf
-    return ((pf - jnp.asarray(lr, jnp.float32) * update).astype(p.dtype),
-            m, v_new)
+    with jax.named_scope("apex_optim/grad_norm"):
+        g_norm_sq = flat_segment_sumsq(gf, sizes)
+        if init_zero:
+            v_new = jnp.where(first, (1 - b2) * g_norm_sq,
+                              b2 * v_seg + (1 - b2) * g_norm_sq)
+        else:
+            v_new = jnp.where(first, g_norm_sq,
+                              b2 * v_seg + (1 - b2) * g_norm_sq)
+        denom = jnp.sqrt(v_new) + jnp.asarray(eps, jnp.float32)
+    with jax.named_scope("apex_optim/moments"):
+        gn = gf / flat_segment_broadcast(denom, sizes)
+        if reg_inside_moment:
+            gn = gn + wd * pf
+        coeff = (1 - b1) if grad_averaging else jnp.float32(1.0)
+        m = jnp.where(first, gn, b1 * m + coeff * gn)
+        update = m if reg_inside_moment else m + wd * pf
+        p_new = (pf - jnp.asarray(lr, jnp.float32) * update).astype(p.dtype)
+        return _keep_and_cast(keep, model_dtype, (p_new, m, v_new),
+                              (p, m_old, v_seg))
 
 
 # ---------------------------------------------------------------------------
@@ -956,7 +1003,13 @@ def _lamb_trust_factor(p, update, sizes, lr, wd, use_nvlamb):
 
 def flat_lamb_ref(p, g, m, v, sizes, *, lr, beta1, beta2, eps,
                   weight_decay=0.0, step=1, bias_correction=True,
-                  grad_scale=1.0, clip_coeff=1.0, use_nvlamb=False):
+                  grad_scale=1.0, clip_coeff=1.0, use_nvlamb=False,
+                  keep=None, model_dtype=None):
+    """``flat_lamb`` in ``jnp``; ``keep`` / ``model_dtype`` as in
+    ``flat_adam_ref``: (p, m, v[, p_model]).  The skip is a select, not
+    the trust factor multiplied by zero: after an overflow ``update``
+    holds inf/nan."""
+    m_old, v_old = m, v
     step = jnp.asarray(step, jnp.float32)
     b1 = jnp.asarray(beta1, jnp.float32)
     b2 = jnp.asarray(beta2, jnp.float32)
@@ -976,4 +1029,6 @@ def flat_lamb_ref(p, g, m, v, sizes, *, lr, beta1, beta2, eps,
                               + jnp.asarray(eps, jnp.float32)) + wd * pf
     factor = _lamb_trust_factor(pf, update, sizes, lr, wd, use_nvlamb)
     with jax.named_scope("apex_optim/apply"):
-        return (pf - factor * update).astype(p.dtype), m, v
+        p_new = (pf - factor * update).astype(p.dtype)
+        return _keep_and_cast(keep, model_dtype, (p_new, m, v),
+                              (p, m_old, v_old))

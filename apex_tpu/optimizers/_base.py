@@ -11,9 +11,12 @@ user's jitted train step via the ``functional_step`` attribute.
 Bucketed flat path (default, ``fuse_buckets=True``): at construction a
 one-time :class:`~apex_tpu.multi_tensor_apply.packer.BucketPlan`
 concatenates dtype-homogeneous leaves into flat HBM buffers, and the
-jitted step runs ONE flat Pallas kernel per bucket
-(apex_tpu.ops.multi_tensor) — the TPU realization of the reference's
-``multi_tensor_apply`` + ``amp_C`` design.  Params, masters and
+jitted step runs ONE sweep per bucket and phase of the update
+(apex_tpu.ops.multi_tensor's ``flat_*_ref`` math, which XLA fuses with
+the overflow skip and the model-dtype copy of the masters: every
+buffer is read once and written once per phase) — the TPU realization
+of the reference's ``multi_tensor_apply`` + ``amp_C`` design.  Params,
+masters and
 optimizer state stay PACKED between steps; the per-leaf pytree view is
 rebuilt lazily (one compiled unpack program) only for ``state_dict()``,
 ``load_state_dict()`` and the ``params``/``masters`` properties, and the
@@ -116,19 +119,29 @@ def _select(keep, new_tree, old_tree):
     return tree_map(lambda a, b: jnp.where(keep, a, b), new_tree, old_tree)
 
 
+def _keep_flag(found_inf):
+    """``found_inf == 0`` as the step's traced ``keep`` scalar (None
+    without a flag: then no select is traced at all), reporting the
+    skip.  The telemetry emission lands only when the body is traced
+    inside an instrumented jit (functional_step, or a train step
+    embedding ``_full_step_impl``); the stateful ``step()`` facade's
+    internal jit cannot report into an outer ring — the tape correctly
+    drops its tracers (telemetry._tape docstring)."""
+    if found_inf is None:
+        return None
+    _tape.emit("optim/skipped", jnp.asarray(found_inf) > 0,
+               reduce="max")
+    return jnp.asarray(found_inf) == 0
+
+
 @jax.named_scope("apex_optim/skip_select")
 def _skip_on_overflow(found_inf, new_work, old_work, new_state,
                       old_state):
-    """The branch-free found_inf skip, shared by every step body:
-    keep the old values when the flag is set, and report the skip.
-    The telemetry emission lands only when the body is traced inside
-    an instrumented jit (functional_step, or a train step embedding
-    ``_full_step_impl``); the stateful ``step()`` facade's internal
-    jit cannot report into an outer ring — the tape correctly drops
-    its tracers (telemetry._tape docstring)."""
-    keep = jnp.asarray(found_inf) == 0
-    _tape.emit("optim/skipped", jnp.asarray(found_inf) > 0,
-               reduce="max")
+    """The branch-free found_inf skip of the per-leaf step bodies (all
+    ``jnp``: XLA fuses the selects into the update): keep the old
+    values when the flag is set, and report the skip.  The bucketed
+    path hands ``_keep_flag`` to its sweeps instead."""
+    keep = _keep_flag(found_inf)
     return (_select(keep, new_work, old_work),
             _select(keep, new_state, old_state))
 
@@ -136,10 +149,10 @@ def _skip_on_overflow(found_inf, new_work, old_work, new_state,
 def _fold_clip(grad_scale, clip_coef):
     """Fold a global-norm clip coefficient into the gradient scale.
 
-    Every flat_* kernel (and the per-leaf math) multiplies grads by
+    Every flat_* update (and the per-leaf math) multiplies grads by
     ``1/grad_scale``; an effective scale of ``grad_scale/clip_coef``
     therefore multiplies by ``clip_coef/grad_scale`` — clipping rides
-    the scaling the kernels already do, with no extra gradient pass or
+    the scaling the updates already do, with no extra gradient pass or
     copy.  LAMB's global-grad-norm prologue composes correctly: it sees
     the norm of the gradients AS CLIPPED, which is what its own
     max_grad_norm logic should be judging."""
@@ -245,21 +258,19 @@ class FusedOptimizerBase:
             from apex_tpu.ops._dispatch import on_tpu
             self.opt_state = place_on_host(self.opt_state)
             self._fused_offload = on_tpu()
-            if self._fused_offload:
-                # no donation: the state crosses memory kinds
-                # (pinned_host in, device math, pinned_host out) and
-                # donating across spaces is not aliasable anyway
-                self._jit_step = jax.jit(  # apexlint: disable=APX401
-                    self._full_step_offload,
-                    out_shardings=(None, None,
-                                   tree_map(_host_sharding,
-                                            self.opt_state)))
-            else:
-                self._jit_step = jax.jit(self._full_step_impl,
-                                         donate_argnums=(2,))
-        else:
-            self._jit_step = jax.jit(self._full_step_impl,
-                                     donate_argnums=(2,))
+        self._jit_step = self._build_jit_step()
+
+    def _build_jit_step(self):
+        """The step program for the current plan and state layout."""
+        if self._fused_offload:
+            # no donation: the state crosses memory kinds
+            # (pinned_host in, device math, pinned_host out) and
+            # donating across spaces is not aliasable anyway
+            return jax.jit(  # apexlint: disable=APX401
+                self._full_step_offload,
+                out_shardings=(None, None,
+                               tree_map(_host_sharding, self.opt_state)))
+        return jax.jit(self._full_step_impl, donate_argnums=(2,))
 
     # ---- packed views ----------------------------------------------------
     @property
@@ -323,11 +334,16 @@ class FusedOptimizerBase:
         raise NotImplementedError
 
     def _flat_bucket_step(self, bucket_index: int, p, g, state, step,
-                          grad_scale, hypers, extra):
-        """One bucket's flat-kernel update: ``p``/``g`` are flat buffers,
+                          grad_scale, hypers, extra, keep=None,
+                          model_dtype=None):
+        """One bucket's flat update: ``p``/``g`` are flat buffers,
         ``state`` maps field name -> this bucket's buffer.  Returns
         (new_p, new_state).  ``extra`` is whatever ``_flat_prologue``
-        returned (e.g. LAMB's global-norm clip coefficient)."""
+        returned (e.g. LAMB's global-norm clip coefficient).  ``keep``
+        (``_keep_flag``) is the overflow skip, taken inside the
+        update's own sweep; ``model_dtype`` asks the sweep that writes
+        ``new_p`` for its copy in that dtype as well, and the result is
+        then (new_p, new_state, new_model_p)."""
         raise NotImplementedError
 
     def _flat_prologue(self, work_bufs, grad_bufs, step, grad_scale,
@@ -336,7 +352,12 @@ class FusedOptimizerBase:
         return None
 
     def _flat_step_math(self, work_bufs, grad_bufs, opt_state, step,
-                        grad_scale, hypers):
+                        grad_scale, hypers, keep=None, cast_model=False):
+        """Every bucket's update, each buffer read once and written
+        once per phase: the overflow skip (``keep``) and, with
+        ``cast_model``, the model-dtype copy of a bucket that has
+        masters ride the update's sweep.  -> (new work buffers, new
+        state, new model-dtype buffers or None)."""
         # fp8 delayed-scaling slots are carried state, not optimizer
         # math: split them out of the per-bucket loop and update them
         # from the POST-step work buffers below (delayed scaling: the
@@ -350,19 +371,28 @@ class FusedOptimizerBase:
             extra = self._flat_prologue(work_bufs, grad_bufs, step,
                                         grad_scale, hypers)
         new_bufs: List[Any] = []
+        new_model: List[Any] = []
         new_state: Dict[str, List[Any]] = {k: [] for k in core}
         for bi, (p, g) in enumerate(zip(work_bufs, grad_bufs)):
             bucket_state = {k: v[bi] for k, v in core.items()}
-            np_, ns = self._flat_bucket_step(
-                bi, p, g, bucket_state, step, grad_scale, hypers, extra)
+            bucket = self._plan.buckets[bi]
+            has_masters = cast_model and bucket.model_dtype != bucket.dtype
+            np_, ns, *nm = self._flat_bucket_step(
+                bi, p, g, bucket_state, step, grad_scale, hypers, extra,
+                keep, bucket.model_dtype if has_masters else None)
             new_bufs.append(np_)
+            new_model.extend(nm or [np_])
             for k in new_state:
                 new_state[k].append(ns[k])
         if fp8_state:
             with jax.named_scope("apex_optim/fp8_slots"):
-                new_state.update(self._fp8_slot_update(
-                    new_bufs, fp8_state, step))
-        return new_bufs, new_state
+                new_fp8 = self._fp8_slot_update(new_bufs, fp8_state, step)
+            if keep is not None:
+                # (num_leaves,)-sized: a select of their own
+                with jax.named_scope("apex_optim/skip_select"):
+                    new_fp8 = _select(keep, new_fp8, fp8_state)
+            new_state.update(new_fp8)
+        return new_bufs, new_state, new_model if cast_model else None
 
     def _fp8_slot_update(self, new_work_bufs, fp8_state, step):
         """The packed fp8 weight-scale slots' delayed-scaling
@@ -449,23 +479,20 @@ class FusedOptimizerBase:
                         step, grad_scale, hypers, found_inf=None):
         """Bucketed step body: grads pack (one concatenate per bucket)
         — or arrive ALREADY packed from the flat AMP pipeline, in which
-        case zero pack work happens here — then ONE flat kernel chain
-        per bucket; params/masters/state go in and come out packed."""
+        case zero pack work happens here — then ONE chain of sweeps
+        per bucket; params/masters/state go in and come out packed.
+        The overflow skip and the model-dtype copy of the masters are
+        part of that chain (``_flat_step_math``), not passes after it."""
         work_bufs = master_bufs if master_bufs is not None else param_bufs
         if self._plan.is_packed(grads):
             grad_bufs = list(grads)
         else:
             with jax.named_scope("apex_optim/pack_grads"):
                 grad_bufs = self._plan.pack(grads)
-        new_work, new_state = self._flat_step_math(
-            work_bufs, grad_bufs, opt_state, step, grad_scale, hypers)
-        if found_inf is not None:
-            new_work, new_state = _skip_on_overflow(
-                found_inf, new_work, work_bufs, new_state, opt_state)
+        new_work, new_state, new_params = self._flat_step_math(
+            work_bufs, grad_bufs, opt_state, step, grad_scale, hypers,
+            _keep_flag(found_inf), cast_model=master_bufs is not None)
         if master_bufs is not None:
-            with jax.named_scope("apex_optim/cast_model"):
-                new_params = [w.astype(b.model_dtype) for w, b in
-                              zip(new_work, self._plan.buckets)]
             return new_params, new_work, new_state
         return new_work, None, new_state
 
@@ -510,7 +537,7 @@ class FusedOptimizerBase:
         ``params``/``grads`` are pytrees; ``opt_state`` may be either a
         per-leaf state pytree (per-leaf math runs) or this optimizer's
         PACKED state (e.g. ``opt.opt_state`` of a bucketed optimizer) —
-        then the flat bucket kernels run, the new state comes back
+        then the flat bucket sweeps run, the new state comes back
         packed, and the new params come back as a pytree (what a train
         step's model apply needs anyway; the repack/unpack concatenates
         and slices fuse into the caller's jit).  With packed state,
@@ -520,7 +547,7 @@ class FusedOptimizerBase:
         apply unless overridden explicitly (``step()`` parity).
 
         ``clip_coef``: optional traced global-norm clip coefficient
-        (e.g. ``FlatGrads.clip_coef``); folded into the kernels' grad
+        (e.g. ``FlatGrads.clip_coef``); folded into the update's grad
         scaling, so clipping never materializes a gradient copy.
 
         ``found_inf``: optional on-device overflow flag; when nonzero,
@@ -547,11 +574,9 @@ class FusedOptimizerBase:
                 work_bufs = self._plan.pack_work(params)
                 grad_bufs = (list(grads) if self._plan.is_packed(grads)
                              else self._plan.pack(grads))
-            new_bufs, new_state = self._flat_step_math(
-                work_bufs, grad_bufs, opt_state, step, gs, hypers)
-            if found_inf is not None:
-                new_bufs, new_state = _skip_on_overflow(
-                    found_inf, new_bufs, work_bufs, new_state, opt_state)
+            new_bufs, new_state, _ = self._flat_step_math(
+                work_bufs, grad_bufs, opt_state, step, gs, hypers,
+                _keep_flag(found_inf))
             return self._plan.unpack(new_bufs), new_state
         new_params, new_state = self._step_math(
             params, grads, opt_state, step, gs, hypers)
@@ -577,7 +602,7 @@ class FusedOptimizerBase:
         a host sync.
 
         ``clip_coef``: optional traced global-norm clip coefficient in
-        (0, 1]; folded into the kernels' grad scaling (see
+        (0, 1]; folded into the update's grad scaling (see
         ``_fold_clip``) so clipping costs zero extra gradient passes."""
         with span("apex/optim/step"):
             return self._step(grads, grad_scale, found_inf, clip_coef)
@@ -607,9 +632,8 @@ class FusedOptimizerBase:
             if eager_offload:   # CPU fallback: explicit round trip
                 args = args[:2] + (place_on_device(args[2]),) + args[3:]
             # bucketed path only: its buffers are whole by construction
-            # (the packer declines sharded leaves), and it is the one
-            # that runs Mosaic kernels; per-leaf math partitions under
-            # plain jit
+            # (the packer declines sharded leaves); per-leaf math
+            # partitions under plain jit
             mesh = _replica_mesh(grads) if self._plan is not None else None
             step_fn = (self._jit_step if mesh is None
                        else self._replicated_step(mesh))
@@ -637,9 +661,10 @@ class FusedOptimizerBase:
         """The step program for gradients that arrive replicated over
         ``mesh`` — the data-parallel layout: every device holds the
         full params and runs the same update after the reduction.
-        Mosaic kernels cannot be partitioned automatically, so the same
-        body runs under ``shard_map`` with every operand replicated
-        (a plain multi-device jit refuses the flat kernels outright)."""
+        The same body runs under ``shard_map`` with every operand
+        replicated, so no partitioner decides a bucket's layout (and a
+        Mosaic kernel in a step body, which a plain multi-device jit
+        refuses outright, would still run)."""
         fn = self._mesh_steps.get(mesh)
         if fn is None:
             spec = jax.sharding.PartitionSpec()
@@ -736,16 +761,7 @@ class FusedOptimizerBase:
         if self.offload_state:
             self.opt_state = place_on_host(self.opt_state)
         # fresh jit: the step body closes over the plan
-        if self._fused_offload:
-            # no donation: the state crosses memory kinds (__init__)
-            self._jit_step = jax.jit(  # apexlint: disable=APX401
-                self._full_step_offload,
-                out_shardings=(None, None,
-                               tree_map(_host_sharding,
-                                        self.opt_state)))
-        else:
-            self._jit_step = jax.jit(self._full_step_impl,
-                                     donate_argnums=(2,))
+        self._jit_step = self._build_jit_step()
         return True
 
     # ---- bucket-native checkpoint capture --------------------------------

@@ -177,7 +177,10 @@ def bench_flat_accumulate(layers: int = 48, hidden: int = 256,
     once at the backward) and ``flat_accumulate`` does one fused
     read-modify-write per dtype bucket with the found_inf latch from
     the same HBM sweep.  The per-leaf side gets its latch the per-leaf
-    way (``check_finite``), so both sides answer the same question."""
+    way (``check_finite``), so both sides answer the same question.
+    Beside the two timings, ``accum_*_ops`` count the equations each
+    side traces to per accumulation: what the layout decides, whatever
+    the machine's load does to a clock."""
     import jax
     import jax.numpy as jnp
 
@@ -208,6 +211,10 @@ def bench_flat_accumulate(layers: int = 48, hidden: int = 256,
         "accum_elements": sum(int(l.size) for l in
                               jax.tree_util.tree_leaves(params)),
     }
+    out["accum_per_leaf_ops"] = len(jax.make_jaxpr(per_leaf)(
+        acc_tree, grads, jnp.int32(0)).eqns)
+    out["accum_flat_ops"] = len(jax.make_jaxpr(flat)(
+        acc_flat, packed).eqns)
     # two programs, two compiles — not a hot-loop retrace
     # apexlint: disable-next=APX302
     ms_pl = timeit(jax.jit(per_leaf), acc_tree, grads, jnp.int32(0),

@@ -2,8 +2,8 @@
 cf. csrc/multi_tensor_adagrad.cu.
 
 Flat AMP pipeline: ``step()`` takes already-packed per-bucket gradient
-buffers and a traced ``clip_coef`` folded into ``flat_adagrad``'s
-in-kernel ``inv_scale`` (optimizers/_base._fold_clip)."""
+buffers and a traced ``clip_coef`` folded into ``flat_adagrad_ref``'s
+own unscaling (optimizers/_base._fold_clip)."""
 
 from __future__ import annotations
 
@@ -36,10 +36,11 @@ class FusedAdagrad(FusedOptimizerBase):
         return new_p, {"sum": new_s}
 
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
-                          hypers, extra):
+                          hypers, extra, keep=None, model_dtype=None):
         h = self._merge_hypers(hypers)
         with jax.named_scope("apex_optim/moments"):
-            po, ho = mt.flat_adagrad(
+            po, ho, *pm = mt.flat_adagrad_ref(
                 p, g, state["sum"], lr=h["lr"], eps=h["eps"],
-                weight_decay=h["weight_decay"], grad_scale=grad_scale)
-        return po, {"sum": ho}
+                weight_decay=h["weight_decay"], grad_scale=grad_scale,
+                keep=keep, model_dtype=model_dtype)
+        return po, {"sum": ho}, *pm

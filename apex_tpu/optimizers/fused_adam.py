@@ -8,9 +8,9 @@ ignored (every step is a compiled graph on TPU).
 
 Flat AMP pipeline: ``step()`` accepts the bucket plan's per-bucket flat
 gradient buffers (or an ``amp.FlatGrads`` bundle) plus a traced
-``clip_coef`` — the clip folds into ``flat_adam``'s in-kernel
-``inv_scale`` multiply, so a clipped step reads the gradients exactly
-once (see optimizers/_base._fold_clip).
+``clip_coef`` — the clip folds into the update's own unscaling of the
+gradients (``flat_adam_ref``), so a clipped step reads the gradients
+exactly once (see optimizers/_base._fold_clip).
 """
 
 from __future__ import annotations
@@ -58,14 +58,14 @@ class FusedAdam(FusedOptimizerBase):
         return new_p, {"exp_avg": new_m, "exp_avg_sq": new_v}
 
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
-                          hypers, extra):
+                          hypers, extra, keep=None, model_dtype=None):
         h = self._merge_hypers(hypers)
         with jax.named_scope("apex_optim/moments"):
-            po, mo, vo = mt.flat_adam(
+            po, mo, vo, *pm = mt.flat_adam_ref(
                 p, g, state["exp_avg"], state["exp_avg_sq"],
                 lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],
                 weight_decay=h["weight_decay"], step=step,
                 adam_w_mode=self.hypers["adam_w_mode"],
                 bias_correction=self.hypers["bias_correction"],
-                grad_scale=grad_scale)
-        return po, {"exp_avg": mo, "exp_avg_sq": vo}
+                grad_scale=grad_scale, keep=keep, model_dtype=model_dtype)
+        return po, {"exp_avg": mo, "exp_avg_sq": vo}, *pm

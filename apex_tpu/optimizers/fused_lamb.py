@@ -71,24 +71,25 @@ class FusedLAMB(FusedOptimizerBase):
         buckets (the reference's multi_tensor_l2norm prologue): one
         fused reduction per bucket, rss-combined."""
         h = self._merge_hypers(hypers)
-        gnorm = jnp.sqrt(sum(mt.flat_l2norm(g) ** 2 for g in grad_bufs))
+        gnorm = jnp.sqrt(sum(mt.flat_l2norm_ref(g) ** 2 for g in grad_bufs))
         gnorm = gnorm / grad_scale
         maxn = h["max_grad_norm"]
         return jnp.where((maxn > 0) & (gnorm > maxn),
                          maxn / gnorm, jnp.float32(1.0))
 
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
-                          hypers, extra):
+                          hypers, extra, keep=None, model_dtype=None):
         h = self._merge_hypers(hypers)
-        po, mo, vo = mt.flat_lamb(
+        po, mo, vo, *pm = mt.flat_lamb_ref(
             p, g, state["exp_avg"], state["exp_avg_sq"],
             self._plan.segment_sizes(bucket_index),
             lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],
             weight_decay=h["weight_decay"], step=step,
             bias_correction=self.hypers["bias_correction"],
             grad_scale=grad_scale, clip_coeff=extra,
-            use_nvlamb=self.hypers["use_nvlamb"])
-        return po, {"exp_avg": mo, "exp_avg_sq": vo}
+            use_nvlamb=self.hypers["use_nvlamb"], keep=keep,
+            model_dtype=model_dtype)
+        return po, {"exp_avg": mo, "exp_avg_sq": vo}, *pm
 
 
 class FusedMixedPrecisionLamb(FusedLAMB):

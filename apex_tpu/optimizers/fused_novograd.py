@@ -64,14 +64,14 @@ class FusedNovoGrad(FusedOptimizerBase):
         return new_p, {"exp_avg": new_m, "exp_avg_sq": new_v}
 
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
-                          hypers, extra):
+                          hypers, extra, keep=None, model_dtype=None):
         if self.hypers["norm_type"] != 2:
             raise ValueError("FusedNovoGrad only supports norm_type=2")
         h = self._merge_hypers(hypers)
         # per-tensor second moments ride the bucket's static segment
         # sizes: the packed exp_avg_sq is one (num leaves,) vector per
         # bucket
-        po, mo, vo = mt.flat_novograd(
+        po, mo, vo, *pm = mt.flat_novograd_ref(
             p, g, state["exp_avg"], state["exp_avg_sq"],
             self._plan.segment_sizes(bucket_index),
             lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],
@@ -79,5 +79,5 @@ class FusedNovoGrad(FusedOptimizerBase):
             grad_averaging=self.hypers["grad_averaging"],
             init_zero=self.hypers["init_zero"],
             reg_inside_moment=self.hypers["reg_inside_moment"],
-            grad_scale=grad_scale)
-        return po, {"exp_avg": mo, "exp_avg_sq": vo}
+            grad_scale=grad_scale, keep=keep, model_dtype=model_dtype)
+        return po, {"exp_avg": mo, "exp_avg_sq": vo}, *pm

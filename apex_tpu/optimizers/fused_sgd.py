@@ -4,8 +4,8 @@ torch.optim.SGD semantics (momentum / dampening / nesterov / weight
 decay) as one fused pytree update; cf. csrc/multi_tensor_sgd_kernel.cu.
 
 Flat AMP pipeline: ``step()`` takes already-packed per-bucket gradient
-buffers and a traced ``clip_coef`` folded into ``flat_sgd``'s in-kernel
-``inv_scale`` (optimizers/_base._fold_clip) — no per-leaf clip pass.
+buffers and a traced ``clip_coef`` folded into ``flat_sgd_ref``'s own
+unscaling (optimizers/_base._fold_clip) — no per-leaf clip pass.
 """
 
 from __future__ import annotations
@@ -52,14 +52,15 @@ class FusedSGD(FusedOptimizerBase):
         return new_p, {"momentum_buffer": new_b}
 
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
-                          hypers, extra):
+                          hypers, extra, keep=None, model_dtype=None):
         h = self._merge_hypers(hypers)
         with jax.named_scope("apex_optim/moments"):
-            po, bo = mt.flat_sgd(
+            po, bo, *pm = mt.flat_sgd_ref(
                 p, g, state["momentum_buffer"], lr=h["lr"],
                 momentum=self.hypers["momentum"],
                 dampening=self.hypers["dampening"],
                 weight_decay=h["weight_decay"],
                 nesterov=self.hypers["nesterov"],
-                first_run=step == 1, grad_scale=grad_scale)
-        return po, {"momentum_buffer": bo}
+                first_run=step == 1, grad_scale=grad_scale, keep=keep,
+                model_dtype=model_dtype)
+        return po, {"momentum_buffer": bo}, *pm

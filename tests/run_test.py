@@ -33,6 +33,7 @@ SUITES = {
     "run_optimizers": ["tests/test_multi_tensor.py",
                        "tests/test_optimizers.py",
                        "tests/test_bucketed_optimizers.py",
+                       "tests/test_flat_step_one_sweep.py",
                        "tests/test_distributed_optimizers.py"],
     "run_fused_layer_norm": ["tests/test_fused_layer_norm.py"],
     "run_fused_softmax": ["tests/test_fused_softmax_rope.py"],

@@ -348,7 +348,7 @@ def test_bucketed_allreduce_matches_perleaf():
 
 def test_op_count_one_pack_zero_perleaf_amp_ops():
     """The jitted flat AMP train step contains exactly ONE gradient
-    pack per bucket, 2 pallas_calls per bucket and ZERO per-leaf
+    pack per bucket, 1 pallas_call per bucket and ZERO per-leaf
     unscale/clip/finite-check ops — asserted by the registered
     `amp.flat_pipeline_step` invariant spec; the per-leaf oracle step
     contains one finite check per leaf (local contrast)."""
@@ -362,8 +362,8 @@ def test_op_count_one_pack_zero_perleaf_amp_ops():
     assert {"bucket_concats", "no_host_transfer",
             "is_finite_max", "no_f64"} <= checked, checked
     if op_enabled("multi_tensor"):
-        # exactly 2 pallas_calls per bucket (unscale_norm + adam):
-        # clip folds into the optimizer kernel's grad scaling
+        # exactly 1 pallas_call per bucket (unscale_norm; the Adam
+        # update is XLA sweeps): clip folds into its grad scaling
         assert "pallas_calls" in checked, checked
 
     # contrast: the per-leaf oracle walks every leaf

@@ -146,6 +146,89 @@ def test_flat_lamb_one_bert_large_bucket(one_chip):
     assert f"s32[{n}]" not in text
 
 
+def _bucket_sized_ops(text, n):
+    """Instruction name -> count, over the entry computation's
+    instructions that produce ``n`` elements or more (parameters,
+    bitcasts and tuples aside)."""
+    import collections
+    import re
+    ops = collections.Counter()
+    for line in text.split("ENTRY")[1].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w\-]+?)(?:\.\d+)? = "
+                     r"\(?[a-z0-9]+\[([\d,]*)\]", line)
+        if not m or not m.group(2):
+            continue
+        size = 1
+        for d in m.group(2).split(","):
+            size *= int(d)
+        if size >= n and not re.search(
+                r" (parameter|bitcast|tuple|get-tuple-element)\(", line):
+            ops[m.group(1)] += 1
+    return ops
+
+
+def test_lamb_bucket_step_is_one_sweep_per_phase(one_chip):
+    """What the bucketed LAMB step runs on one BERT-Large bucket, in
+    the benchmark's shape (bf16 gradients, a traced ``keep``, the bf16
+    copy of the masters, donated moments): the compiler fuses the skip,
+    the cast and the per-tensor broadcast into the two phases' sweeps.
+    No kernel stands in its way, nothing bucket-sized is copied, and
+    the temporaries are the ``update`` buffer alone."""
+    from apex_tpu.ops import multi_tensor as mt
+    n = N_LAMB_BUCKET
+
+    def step(m, v, p, g, found_inf):
+        p, m, v, p_model = mt.flat_lamb_ref(
+            p, g, m, v, LAMB_BUCKET_SIZES, lr=1e-3, beta1=0.9, beta2=0.999,
+            eps=1e-6, weight_decay=0.01, step=3, keep=found_inf == 0,
+            model_dtype=BF16)
+        return m, v, p, p_model
+
+    args = [jax.ShapeDtypeStruct((n,), d, sharding=one_chip)
+            for d in (F32, F32, F32, BF16)]
+    args.append(jax.ShapeDtypeStruct((), I32, sharding=one_chip))
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    ops = _bucket_sized_ops(text, n)
+    assert not [k for k in ops if k.startswith("copy")], ops
+    # the trust factor is written into one buffer a tensor at a time
+    # (in-place dynamic-update-slices, one sweep in all); beside them:
+    # moments+update and the apply, each with the skip (and the cast)
+    # inside, not a pass each as in the old step
+    sweeps = {k: c for k, c in ops.items()
+              if k.endswith("fusion") and "dynamic-update-slice" not in k}
+    assert sum(sweeps.values()) == 2, ops
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == 8 * n      # m and v in place
+    assert stats.temp_size_in_bytes < 5 * n        # ``update`` alone
+
+
+def test_adam_bucket_step_is_one_sweep(one_chip):
+    """A 128 MiB bucket of the looped decoder's plan under the bucketed
+    Adam step: one fusion reads p, g, m, v and writes p, m, v and the
+    bf16 parameters; no temporaries, no copies."""
+    from apex_tpu.ops import multi_tensor as mt
+    n = 32 * 2 ** 20
+
+    def step(m, v, p, g, found_inf):
+        p, m, v, p_model = mt.flat_adam_ref(
+            p, g, m, v, lr=3e-4, beta1=0.9, beta2=0.95, eps=1e-8,
+            weight_decay=0.1, step=3, grad_scale=1024.0,
+            keep=found_inf == 0, model_dtype=BF16)
+        return m, v, p, p_model
+
+    args = [jax.ShapeDtypeStruct((n,), d, sharding=one_chip)
+            for d in (F32, F32, F32, BF16)]
+    args.append(jax.ShapeDtypeStruct((), I32, sharding=one_chip))
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    ops = _bucket_sized_ops(compiled.as_text(), n)
+    assert sum(ops.values()) == 1 and not ops.get("copy"), ops
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == 8 * n
+    assert stats.temp_size_in_bytes < 2 ** 20
+
+
 def test_flat_sgd_resnet50_size(one_chip):
     from apex_tpu.ops import multi_tensor as mt
     n = N_RESNET50
@@ -236,7 +319,7 @@ def test_ddp_flat_reduce_over_four_described_chips(topo):
 
 
 def test_fused_sgd_step_replicated_over_four_described_chips(topo):
-    """The data-parallel optimizer step: FusedSGD's flat kernel on
+    """The data-parallel optimizer step: FusedSGD's flat update on
     gradients REPLICATED over a 4-chip mesh.  A plain multi-device jit
     refuses it ("Mosaic kernels cannot be automatically partitioned" —
     what the first four-chip run of PR 21 died of, and what interpret
@@ -257,7 +340,9 @@ def test_fused_sgd_step_replicated_over_four_described_chips(topo):
                                        sharding=replicated),
         opt._step_args(params, found_inf=jnp.int32(0)))
     text = opt._replicated_step(mesh).lower(*args).compile().as_text()
-    _assert_kernels(text, "apex_multi_tensor_sgd")
+    # since PR 28 the update is XLA sweeps: nothing here needs a kernel
+    # partitioned, and the program still has to compile replicated
+    assert "tpu_custom_call" not in text and "fusion" in text
 
 
 # ---------------------------------------------------------------------------
