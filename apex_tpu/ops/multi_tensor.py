@@ -1,4 +1,4 @@
-"""Fused "foreach" kernels over flat parameter buffers.
+"""Fused "foreach" math over flat parameter buffers.
 
 TPU-native replacement for the reference's ``amp_C`` extension
 (upstream-expected csrc/amp_C_frontend.cpp + multi_tensor_*.cu kernels,
@@ -6,30 +6,29 @@ SURVEY.md §2.4): scale with non-finite detection, axpby, L2 norm, and the
 optimizer step math (Adam/SGD/...).  The reference chunks a list of CUDA
 tensors into one grid launch to amortize launch overhead; the TPU design
 concatenates pytree leaves into one flat HBM buffer (see
-apex_tpu.multi_tensor_apply) and runs ONE pallas_call whose grid walks
-(rows, 128)-shaped VMEM tiles.  All math accumulates in f32 regardless of
-storage dtype; non-finite detection is an on-device i32 flag (never a host
-sync — the reference's host-side overflow read is a known sync point,
-SURVEY.md §3.2).
+apex_tpu.multi_tensor_apply) and sweeps it once per phase.  All math
+accumulates in f32 regardless of storage dtype; non-finite detection is
+an on-device i32 flag (never a host sync — the reference's host-side
+overflow read is a known sync point, SURVEY.md §3.2).
 
-Every kernel has a pure-jnp oracle (suffix ``_ref``) used for testing and
-as the XLA fallback when Pallas is disabled.
+Pallas kernels (ONE pallas_call whose grid walks (rows, 128)-shaped
+VMEM tiles), each with a pure-jnp oracle suffixed ``_ref`` that tests
+compare against and that runs when Pallas is disabled: ``flat_scale``,
+``flat_axpby``, ``flat_accumulate``, ``flat_unscale_norm``,
+``flat_l2norm``.  ``flat_amax_scale_update`` is ``jnp`` over the static
+segments behind the same switch, with a scatter-max ``_ref`` oracle.
 
-The optimizer updates' ``_ref`` functions are more than oracles: they are
-what the bucketed optimizer step runs (``optimizers/_base.py``).  Measured
-on the chip (PERF.md section 6, PR 28), XLA fuses an update with the
-overflow skip, the model-dtype copy of the masters and LAMB's per-tensor
-broadcast into one sweep per phase, where a kernel is opaque to it and
-pays a pad, a slice, a select and a cast around itself.  They take the
-step's ``keep`` flag and the bucket's ``model_dtype`` for that.  The
-update kernels (``flat_adam``, ``flat_sgd``, ``flat_adagrad``,
-``flat_novograd``, ``flat_lamb``) are kept with their tests; no step
-calls them (ROADMAP, Design).
+XLA math (``jnp``, no kernel): the optimizer updates ``flat_adam``,
+``flat_sgd``, ``flat_adagrad``, ``flat_novograd``, ``flat_lamb`` and
+the segment helpers.  XLA fuses an update with the overflow skip
+(``keep``), the model-dtype copy of the masters (``model_dtype``) and
+LAMB's per-tensor broadcast into one sweep per phase, where a kernel is
+opaque to it and pays a pad, a slice, a select and a cast around itself
+(measured on the chip: PERF.md section 6, PR 28).
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
@@ -88,8 +87,8 @@ def _f32(x):
 
 
 def _keep_and_cast(keep, model_dtype, new, old):
-    """The update oracles' epilogue (``new``/``old``: tuples, parameters
-    first).  ``keep`` (traced bool, the step's ``found_inf == 0``):
+    """The optimizer updates' epilogue (``new``/``old``: tuples,
+    parameters first).  ``keep`` (traced bool, the step's ``found_inf == 0``):
     every result is ``where(keep, new, old)``, a select XLA fuses into
     the update's own sweep — a skipped step returns its inputs to the
     bit.  ``model_dtype``: the new parameters in that dtype as one more
@@ -373,80 +372,16 @@ def flat_l2norm_ref(x):
 # Adam / AdamW step   [reference: multi_tensor_adam.cu]
 # ---------------------------------------------------------------------------
 
-def _adam_kernel(adam_w_mode, s_ref, p_ref, g_ref, m_ref, v_ref,
-                 po_ref, mo_ref, vo_ref):
-    lr, b1, b2, eps, wd, c1r, c2r, inv_scale = (
-        s_ref[0], s_ref[1], s_ref[2], s_ref[3],
-        s_ref[4], s_ref[5], s_ref[6], s_ref[7],
-    )
-    p = _f32(p_ref[...])
-    g = _f32(g_ref[...]) * inv_scale
-    if not adam_w_mode:  # classic Adam: L2 term folded into the gradient
-        g = g + wd * p
-    m = b1 * m_ref[...] + (1.0 - b1) * g
-    v = b2 * v_ref[...] + (1.0 - b2) * g * g
-    update = (m * c1r) / (jnp.sqrt(v * c2r) + eps)
-    if adam_w_mode:  # decoupled weight decay
-        update = update + wd * p
-    po_ref[...] = (p - lr * update).astype(po_ref.dtype)
-    mo_ref[...] = m
-    vo_ref[...] = v
-
-
 def flat_adam(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, step,
               adam_w_mode: bool = True, bias_correction: bool = True,
-              grad_scale=1.0):
-    """One fused Adam/AdamW step over flat buffers.
+              grad_scale=1.0, keep=None, model_dtype=None):
+    """One Adam/AdamW step over flat buffers.
 
     p may be bf16 or f32; m/v must be f32.  ``step`` is the 1-based step
-    count (traced scalar ok).  Returns (p, m, v).
+    count (traced scalar ok).  With ``keep`` / ``model_dtype``
+    (``_keep_and_cast``) the bucketed step's whole Adam sweep.  Returns
+    (p, m, v) or (p, m, v, p_model).
     """
-    if not op_enabled("multi_tensor"):
-        return flat_adam_ref(
-            p, g, m, v, lr=lr, beta1=beta1, beta2=beta2, eps=eps,
-            weight_decay=weight_decay, step=step, adam_w_mode=adam_w_mode,
-            bias_correction=bias_correction, grad_scale=grad_scale)
-    step = jnp.asarray(step, jnp.float32)
-    if bias_correction:
-        c1r = 1.0 / (1.0 - jnp.asarray(beta1, jnp.float32) ** step)
-        c2r = 1.0 / (1.0 - jnp.asarray(beta2, jnp.float32) ** step)
-    else:
-        c1r = jnp.float32(1.0)
-        c2r = jnp.float32(1.0)
-    s = jnp.stack([
-        jnp.asarray(lr, jnp.float32), jnp.asarray(beta1, jnp.float32),
-        jnp.asarray(beta2, jnp.float32), jnp.asarray(eps, jnp.float32),
-        jnp.asarray(weight_decay, jnp.float32), c1r, c2r,
-        1.0 / jnp.asarray(grad_scale, jnp.float32),
-    ])
-    p2d, n = _as_tiles(p)
-    g2d, _ = _as_tiles(g)
-    m2d, _ = _as_tiles(m)
-    v2d, _ = _as_tiles(v)
-    kernel = functools.partial(_adam_kernel, adam_w_mode)
-    po, mo, vo = pl.pallas_call(
-        kernel,
-        grid=(_grid(p2d.shape[0]),),
-        in_specs=[_smem_spec()] + [_vec_spec()] * 4,
-        out_specs=[_vec_spec()] * 3,
-        out_shape=[
-            jax.ShapeDtypeStruct(p2d.shape, p.dtype),
-            jax.ShapeDtypeStruct(m2d.shape, jnp.float32),
-            jax.ShapeDtypeStruct(v2d.shape, jnp.float32),
-        ],
-        input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=interpret_mode(),
-        name="apex_multi_tensor_adam",
-    )(s, p2d, g2d, m2d, v2d)
-    return _from_tiles(po, n), _from_tiles(mo, n), _from_tiles(vo, n)
-
-
-def flat_adam_ref(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, step,
-                  adam_w_mode=True, bias_correction=True, grad_scale=1.0,
-                  keep=None, model_dtype=None):
-    """``flat_adam`` in ``jnp``; with ``keep`` / ``model_dtype``
-    (``_keep_and_cast``) the bucketed step's whole Adam sweep:
-    (p, m, v) or (p, m, v, p_model)."""
     m_old, v_old = m, v
     step = jnp.asarray(step, jnp.float32)
     b1 = jnp.asarray(beta1, jnp.float32)
@@ -475,69 +410,13 @@ def flat_adam_ref(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, step,
 # SGD (momentum/nesterov/wd) step   [reference: multi_tensor_sgd_kernel.cu]
 # ---------------------------------------------------------------------------
 
-def _sgd_kernel(nesterov, use_momentum,
-                s_ref, p_ref, g_ref, b_ref, po_ref, bo_ref):
-    lr, momentum, dampening, wd, inv_scale, first = (
-        s_ref[0], s_ref[1], s_ref[2], s_ref[3], s_ref[4], s_ref[5])
-    p = _f32(p_ref[...])
-    g = _f32(g_ref[...]) * inv_scale + wd * p
-    if use_momentum:
-        # first_run may be traced (step == 1 inside a jitted facade
-        # step): select instead of Python-branching
-        buf = jnp.where(first > 0, g,
-                        momentum * b_ref[...] + (1.0 - dampening) * g)
-        step_dir = (g + momentum * buf) if nesterov else buf
-        bo_ref[...] = buf
-    else:
-        step_dir = g
-        bo_ref[...] = b_ref[...]
-    po_ref[...] = (p - lr * step_dir).astype(po_ref.dtype)
-
-
 def flat_sgd(p, g, momentum_buf, *, lr, momentum=0.0, dampening=0.0,
              weight_decay=0.0, nesterov=False, first_run=False,
-             grad_scale=1.0):
-    """One fused SGD step over flat buffers; returns (p, momentum_buf).
+             grad_scale=1.0, keep=None, model_dtype=None):
+    """One SGD step over flat buffers; ``keep`` / ``model_dtype`` as in
+    ``flat_adam``.  Returns (p, momentum_buf[, p_model]).
 
     ``first_run`` may be a Python bool or a traced bool scalar."""
-    if not op_enabled("multi_tensor"):
-        return flat_sgd_ref(
-            p, g, momentum_buf, lr=lr, momentum=momentum, dampening=dampening,
-            weight_decay=weight_decay, nesterov=nesterov, first_run=first_run,
-            grad_scale=grad_scale)
-    s = jnp.stack([
-        jnp.asarray(lr, jnp.float32), jnp.asarray(momentum, jnp.float32),
-        jnp.asarray(dampening, jnp.float32),
-        jnp.asarray(weight_decay, jnp.float32),
-        1.0 / jnp.asarray(grad_scale, jnp.float32),
-        jnp.asarray(first_run, jnp.float32),
-    ])
-    p2d, n = _as_tiles(p)
-    g2d, _ = _as_tiles(g)
-    b2d, _ = _as_tiles(momentum_buf)
-    kernel = functools.partial(
-        _sgd_kernel, bool(nesterov), momentum != 0.0)
-    po, bo = pl.pallas_call(
-        kernel,
-        grid=(_grid(p2d.shape[0]),),
-        in_specs=[_smem_spec()] + [_vec_spec()] * 3,
-        out_specs=[_vec_spec()] * 2,
-        out_shape=[
-            jax.ShapeDtypeStruct(p2d.shape, p.dtype),
-            jax.ShapeDtypeStruct(b2d.shape, jnp.float32),
-        ],
-        input_output_aliases={1: 0, 3: 1},
-        interpret=interpret_mode(),
-        name="apex_multi_tensor_sgd",
-    )(s, p2d, g2d, b2d)
-    return _from_tiles(po, n), _from_tiles(bo, n)
-
-
-def flat_sgd_ref(p, g, momentum_buf, *, lr, momentum=0.0, dampening=0.0,
-                 weight_decay=0.0, nesterov=False, first_run=False,
-                 grad_scale=1.0, keep=None, model_dtype=None):
-    """``flat_sgd`` in ``jnp``; ``keep`` / ``model_dtype`` as in
-    ``flat_adam_ref``: (p, momentum_buf[, p_model])."""
     pf = _f32(p)
     gf = _f32(g) / jnp.asarray(grad_scale, jnp.float32)
     gf = gf + jnp.asarray(weight_decay, jnp.float32) * pf
@@ -561,52 +440,13 @@ def flat_sgd_ref(p, g, momentum_buf, *, lr, momentum=0.0, dampening=0.0,
 # Adagrad step   [reference: multi_tensor_adagrad.cu]
 # ---------------------------------------------------------------------------
 
-def _adagrad_kernel(s_ref, p_ref, g_ref, h_ref, po_ref, ho_ref):
-    lr, eps, wd, inv_scale = s_ref[0], s_ref[1], s_ref[2], s_ref[3]
-    p = _f32(p_ref[...])
-    g = _f32(g_ref[...]) * inv_scale + wd * p
-    h = h_ref[...] + g * g
-    ho_ref[...] = h
-    po_ref[...] = (p - lr * g / (jnp.sqrt(h) + eps)).astype(po_ref.dtype)
-
-
-def flat_adagrad(p, g, h, *, lr, eps, weight_decay=0.0, grad_scale=1.0):
-    """One fused Adagrad step over flat buffers; returns (p, h).
+def flat_adagrad(p, g, h, *, lr, eps, weight_decay=0.0, grad_scale=1.0,
+                 keep=None, model_dtype=None):
+    """One Adagrad step over flat buffers; ``keep`` / ``model_dtype`` as
+    in ``flat_adam``.  Returns (p, h[, p_model]).
 
     h is the running sum of squared (decayed) gradients, f32.
     """
-    if not op_enabled("multi_tensor"):
-        return flat_adagrad_ref(p, g, h, lr=lr, eps=eps,
-                                weight_decay=weight_decay,
-                                grad_scale=grad_scale)
-    s = jnp.stack([
-        jnp.asarray(lr, jnp.float32), jnp.asarray(eps, jnp.float32),
-        jnp.asarray(weight_decay, jnp.float32),
-        1.0 / jnp.asarray(grad_scale, jnp.float32),
-    ])
-    p2d, n = _as_tiles(p)
-    g2d, _ = _as_tiles(g)
-    h2d, _ = _as_tiles(h)
-    po, ho = pl.pallas_call(
-        _adagrad_kernel,
-        grid=(_grid(p2d.shape[0]),),
-        in_specs=[_smem_spec()] + [_vec_spec()] * 3,
-        out_specs=[_vec_spec()] * 2,
-        out_shape=[
-            jax.ShapeDtypeStruct(p2d.shape, p.dtype),
-            jax.ShapeDtypeStruct(h2d.shape, jnp.float32),
-        ],
-        input_output_aliases={1: 0, 3: 1},
-        interpret=interpret_mode(),
-        name="apex_multi_tensor_adagrad",
-    )(s, p2d, g2d, h2d)
-    return _from_tiles(po, n), _from_tiles(ho, n)
-
-
-def flat_adagrad_ref(p, g, h, *, lr, eps, weight_decay=0.0, grad_scale=1.0,
-                     keep=None, model_dtype=None):
-    """``flat_adagrad`` in ``jnp``; ``keep`` / ``model_dtype`` as in
-    ``flat_adam_ref``: (p, h[, p_model])."""
     h_old = h
     pf = _f32(p)
     gf = _f32(g) / jnp.asarray(grad_scale, jnp.float32)
@@ -641,8 +481,7 @@ def flat_segment_sumsq(x, sizes):
     shape ``(len(sizes),)``.
 
     One XLA reduce per segment over its own static extent — a tree
-    sum, one sweep of the buffer in total; the elementwise heavy
-    lifting around it stays in the flat Pallas kernels."""
+    sum, one sweep of the buffer in total."""
     return jnp.stack([jnp.sum(seg * seg) for seg in _segments(x, sizes)])
 
 
@@ -651,7 +490,7 @@ def flat_segment_absmax(x, sizes):
     ``(len(sizes),)``.
 
     The per-TENSOR amax the fp8 delayed-scaling state needs, from the
-    same static boundaries the LAMB/NovoGrad kernels use.  Non-finite
+    same static boundaries the LAMB/NovoGrad updates use.  Non-finite
     elements propagate (|nan| is nan, |inf| is inf) so the caller's
     overflow detection sees them; an empty segment reads 0."""
     return jnp.stack([jnp.max(jnp.abs(seg), initial=0.0)
@@ -773,92 +612,20 @@ def _amax_scale_math(amax, amax_history, scale, fp8_max, margin,
 # NovoGrad step (segmented)   [reference: multi_tensor_novograd.cu]
 # ---------------------------------------------------------------------------
 
-def _novograd_apply_kernel(grad_averaging, reg_inside_moment,
-                           s_ref, p_ref, g_ref, m_ref, d_ref,
-                           po_ref, mo_ref):
-    lr, b1, wd, inv_scale, first = (
-        s_ref[0], s_ref[1], s_ref[2], s_ref[3], s_ref[4])
-    p = _f32(p_ref[...])
-    gn = _f32(g_ref[...]) * inv_scale * d_ref[...]
-    if reg_inside_moment:
-        gn = gn + wd * p
-    coeff = (1.0 - b1) if grad_averaging else 1.0
-    m = jnp.where(first > 0, gn, b1 * m_ref[...] + coeff * gn)
-    mo_ref[...] = m
-    update = m if reg_inside_moment else m + wd * p
-    po_ref[...] = (p - lr * update).astype(po_ref.dtype)
-
-
 def flat_novograd(p, g, m, v_seg, sizes, *, lr, beta1, beta2, eps,
                   weight_decay=0.0, first_run=False, grad_averaging=True,
-                  init_zero=False, reg_inside_moment=False, grad_scale=1.0):
-    """One fused NovoGrad step over a flat bucket; returns (p, m, v_seg).
+                  init_zero=False, reg_inside_moment=False, grad_scale=1.0,
+                  keep=None, model_dtype=None):
+    """One NovoGrad step over a flat bucket; ``keep`` / ``model_dtype``
+    as in ``flat_adam``.  Returns (p, m, v_seg[, p_model]).
 
     ``v_seg`` is the per-TENSOR second moment, one f32 scalar per bucket
-    segment (shape ``(num_segments,)``); ``sizes`` are the bucket's static
+    segment (shape ``(len(sizes),)``); ``sizes`` are the bucket's static
     segment sizes (``BucketPlan.segment_sizes``).  The per-segment
-    gradient norms are reduced over those static extents; the
-    normalizer reaches the elementwise Pallas kernel as a per-element
-    buffer broadcast over them, so the heavy math is still one grid
-    launch.
+    gradient norms are reduced over those static extents and the
+    normalizer is broadcast back over them inside the elementwise sweep.
     ``first_run`` may be a Python bool or a traced bool scalar.
     """
-    if not op_enabled("multi_tensor"):
-        return flat_novograd_ref(
-            p, g, m, v_seg, sizes, lr=lr, beta1=beta1, beta2=beta2,
-            eps=eps, weight_decay=weight_decay, first_run=first_run,
-            grad_averaging=grad_averaging, init_zero=init_zero,
-            reg_inside_moment=reg_inside_moment, grad_scale=grad_scale)
-    inv_scale = 1.0 / jnp.asarray(grad_scale, jnp.float32)
-    b2 = jnp.asarray(beta2, jnp.float32)
-    first = jnp.asarray(first_run, jnp.bool_)
-    with jax.named_scope("apex_optim/grad_norm"):
-        g_norm_sq = flat_segment_sumsq(_f32(g) * inv_scale, sizes)
-        if init_zero:
-            v_new = jnp.where(first, (1 - b2) * g_norm_sq,
-                              b2 * v_seg + (1 - b2) * g_norm_sq)
-        else:
-            v_new = jnp.where(first, g_norm_sq,
-                              b2 * v_seg + (1 - b2) * g_norm_sq)
-        inv_denom = 1.0 / (jnp.sqrt(v_new)
-                           + jnp.asarray(eps, jnp.float32))
-        d_elem = flat_segment_broadcast(inv_denom, sizes)
-    with jax.named_scope("apex_optim/moments"):
-        s = jnp.stack([
-            jnp.asarray(lr, jnp.float32), jnp.asarray(beta1, jnp.float32),
-            jnp.asarray(weight_decay, jnp.float32), inv_scale,
-            jnp.asarray(first, jnp.float32),
-        ])
-        p2d, n = _as_tiles(p)
-        g2d, _ = _as_tiles(g)
-        m2d, _ = _as_tiles(m)
-        d2d, _ = _as_tiles(d_elem)
-        kernel = functools.partial(_novograd_apply_kernel,
-                                   bool(grad_averaging),
-                                   bool(reg_inside_moment))
-        po, mo = pl.pallas_call(
-            kernel,
-            grid=(_grid(p2d.shape[0]),),
-            in_specs=[_smem_spec()] + [_vec_spec()] * 4,
-            out_specs=[_vec_spec()] * 2,
-            out_shape=[
-                jax.ShapeDtypeStruct(p2d.shape, p.dtype),
-                jax.ShapeDtypeStruct(m2d.shape, jnp.float32),
-            ],
-            input_output_aliases={1: 0, 3: 1},
-            interpret=interpret_mode(),
-            name="apex_multi_tensor_novograd",
-        )(s, p2d, g2d, m2d, d2d)
-        return _from_tiles(po, n), _from_tiles(mo, n), v_new
-
-
-def flat_novograd_ref(p, g, m, v_seg, sizes, *, lr, beta1, beta2, eps,
-                      weight_decay=0.0, first_run=False,
-                      grad_averaging=True, init_zero=False,
-                      reg_inside_moment=False, grad_scale=1.0,
-                      keep=None, model_dtype=None):
-    """``flat_novograd`` in ``jnp``; ``keep`` / ``model_dtype`` as in
-    ``flat_adam_ref``: (p, m, v_seg[, p_model])."""
     m_old = m
     pf = _f32(p)
     gf = _f32(g) / jnp.asarray(grad_scale, jnp.float32)
@@ -891,93 +658,6 @@ def flat_novograd_ref(p, g, m, v_seg, sizes, *, lr, beta1, beta2, eps,
 # LAMB step (segmented)   [reference: multi_tensor_lamb.cu stage1+stage2]
 # ---------------------------------------------------------------------------
 
-def _lamb_moment_kernel(s_ref, p_ref, g_ref, m_ref, v_ref,
-                        mo_ref, vo_ref, uo_ref):
-    b1, b2, eps, wd, c1r, c2r, gmul = (
-        s_ref[0], s_ref[1], s_ref[2], s_ref[3],
-        s_ref[4], s_ref[5], s_ref[6])
-    p = _f32(p_ref[...])
-    g = _f32(g_ref[...]) * gmul
-    m = b1 * m_ref[...] + (1.0 - b1) * g
-    v = b2 * v_ref[...] + (1.0 - b2) * g * g
-    mo_ref[...] = m
-    vo_ref[...] = v
-    uo_ref[...] = (m * c1r) / (jnp.sqrt(v * c2r) + eps) + wd * p
-
-
-def _apply_update_kernel(p_ref, u_ref, f_ref, po_ref):
-    po_ref[...] = (_f32(p_ref[...])
-                   - f_ref[...] * u_ref[...]).astype(po_ref.dtype)
-
-
-def flat_lamb(p, g, m, v, sizes, *, lr, beta1, beta2, eps,
-              weight_decay=0.0, step=1, bias_correction=True,
-              grad_scale=1.0, clip_coeff=1.0, use_nvlamb=False):
-    """One fused LAMB step over a flat bucket; returns (p, m, v).
-
-    Two grid launches per bucket (the reference's stage1+stage2 shape):
-    moments + unscaled update, then the trust-ratio-scaled apply.  The
-    per-TENSOR trust ratio ||p||/||update|| is reduced over the bucket's
-    static segment ``sizes`` (``BucketPlan.segment_sizes``) — per-tensor
-    semantics preserved without per-tensor kernels.  ``clip_coeff`` is
-    the precomputed global-grad-norm clip factor (stage-1 side input).
-    """
-    step = jnp.asarray(step, jnp.float32)
-    b1 = jnp.asarray(beta1, jnp.float32)
-    b2 = jnp.asarray(beta2, jnp.float32)
-    wd = jnp.asarray(weight_decay, jnp.float32)
-    if bias_correction:
-        c1r = 1.0 / (1.0 - b1 ** step)
-        c2r = 1.0 / (1.0 - b2 ** step)
-    else:
-        c1r = c2r = jnp.float32(1.0)
-    gmul = (jnp.asarray(clip_coeff, jnp.float32)
-            / jnp.asarray(grad_scale, jnp.float32))
-    if not op_enabled("multi_tensor"):
-        return flat_lamb_ref(
-            p, g, m, v, sizes, lr=lr, beta1=beta1, beta2=beta2, eps=eps,
-            weight_decay=weight_decay, step=step,
-            bias_correction=bias_correction, grad_scale=grad_scale,
-            clip_coeff=clip_coeff, use_nvlamb=use_nvlamb)
-    with jax.named_scope("apex_optim/moments"):
-        s = jnp.stack([b1, b2, jnp.asarray(eps, jnp.float32), wd,
-                       c1r, c2r, gmul])
-        p2d, n = _as_tiles(p)
-        g2d, _ = _as_tiles(g)
-        m2d, _ = _as_tiles(m)
-        v2d, _ = _as_tiles(v)
-        mo, vo, update2d = pl.pallas_call(
-            _lamb_moment_kernel,
-            grid=(_grid(p2d.shape[0]),),
-            in_specs=[_smem_spec()] + [_vec_spec()] * 4,
-            out_specs=[_vec_spec()] * 3,
-            out_shape=[
-                jax.ShapeDtypeStruct(m2d.shape, jnp.float32),
-                jax.ShapeDtypeStruct(v2d.shape, jnp.float32),
-                jax.ShapeDtypeStruct(p2d.shape, jnp.float32),
-            ],
-            input_output_aliases={3: 0, 4: 1},
-            interpret=interpret_mode(),
-            name="apex_multi_tensor_lamb_moments",
-        )(s, p2d, g2d, m2d, v2d)
-        update = _from_tiles(update2d, n)
-    factor_elem = _lamb_trust_factor(p, update, sizes, lr, wd, use_nvlamb)
-    with jax.named_scope("apex_optim/apply"):
-        f2d, _ = _as_tiles(factor_elem)
-        u2d, _ = _as_tiles(update)
-        po = pl.pallas_call(
-            _apply_update_kernel,
-            grid=(_grid(p2d.shape[0]),),
-            in_specs=[_vec_spec()] * 3,
-            out_specs=_vec_spec(),
-            out_shape=jax.ShapeDtypeStruct(p2d.shape, p.dtype),
-            input_output_aliases={0: 0},
-            interpret=interpret_mode(),
-            name="apex_multi_tensor_lamb_apply",
-        )(p2d, u2d, f2d)
-        return _from_tiles(po, n), _from_tiles(mo, n), _from_tiles(vo, n)
-
-
 @jax.named_scope("apex_optim/trust_ratio")
 def _lamb_trust_factor(p, update, sizes, lr, wd, use_nvlamb):
     """Per-element lr*trust buffer: per-segment norms over the static
@@ -990,9 +670,8 @@ def _lamb_trust_factor(p, update, sizes, lr, wd, use_nvlamb):
         # standard LAMB exempts decay-free tensors from layer adaptation;
         # NVLAMB applies the trust ratio to every layer
         trust = jnp.where(wd == 0.0, jnp.float32(1.0), trust)
-    # telemetry from the reductions that already exist (both the kernel
-    # and ref paths come through here) — per-bucket emissions combine
-    # across buckets: max for the trust ratio, root-sum-square for the
+    # telemetry from the reductions that already exist — per-bucket
+    # emissions combine across buckets: max for the trust ratio, root-sum-square for the
     # update norm.  No extra HBM sweep: u_norm_sq is (num_segments,).
     _tape.emit("optim/max_trust_ratio", jnp.max(trust), reduce="max")
     _tape.emit("optim/update_norm", jnp.sqrt(jnp.sum(u_norm_sq)),
@@ -1001,14 +680,21 @@ def _lamb_trust_factor(p, update, sizes, lr, wd, use_nvlamb):
                                   sizes)
 
 
-def flat_lamb_ref(p, g, m, v, sizes, *, lr, beta1, beta2, eps,
-                  weight_decay=0.0, step=1, bias_correction=True,
-                  grad_scale=1.0, clip_coeff=1.0, use_nvlamb=False,
-                  keep=None, model_dtype=None):
-    """``flat_lamb`` in ``jnp``; ``keep`` / ``model_dtype`` as in
-    ``flat_adam_ref``: (p, m, v[, p_model]).  The skip is a select, not
-    the trust factor multiplied by zero: after an overflow ``update``
-    holds inf/nan."""
+def flat_lamb(p, g, m, v, sizes, *, lr, beta1, beta2, eps,
+              weight_decay=0.0, step=1, bias_correction=True,
+              grad_scale=1.0, clip_coeff=1.0, use_nvlamb=False,
+              keep=None, model_dtype=None):
+    """One LAMB step over a flat bucket; ``keep`` / ``model_dtype`` as in
+    ``flat_adam``.  Returns (p, m, v[, p_model]).
+
+    Two phases (the reference's stage1+stage2 shape): moments + unscaled
+    update, then the trust-ratio-scaled apply.  The per-TENSOR trust
+    ratio ||p||/||update|| is reduced over the bucket's static segment
+    ``sizes`` (``BucketPlan.segment_sizes``).  ``clip_coeff`` is the
+    precomputed global-grad-norm clip factor (stage-1 side input).  The
+    skip is a select, not the trust factor multiplied by zero: after an
+    overflow ``update`` holds inf/nan.
+    """
     m_old, v_old = m, v
     step = jnp.asarray(step, jnp.float32)
     b1 = jnp.asarray(beta1, jnp.float32)

@@ -12,16 +12,16 @@ Bucketed flat path (default, ``fuse_buckets=True``): at construction a
 one-time :class:`~apex_tpu.multi_tensor_apply.packer.BucketPlan`
 concatenates dtype-homogeneous leaves into flat HBM buffers, and the
 jitted step runs ONE sweep per bucket and phase of the update
-(apex_tpu.ops.multi_tensor's ``flat_*_ref`` math, which XLA fuses with
-the overflow skip and the model-dtype copy of the masters: every
-buffer is read once and written once per phase) — the TPU realization
-of the reference's ``multi_tensor_apply`` + ``amp_C`` design.  Params,
-masters and
-optimizer state stay PACKED between steps; the per-leaf pytree view is
-rebuilt lazily (one compiled unpack program) only for ``state_dict()``,
-``load_state_dict()`` and the ``params``/``masters`` properties, and the
-checkpoint layout is unchanged — old per-leaf checkpoints load into
-bucketed optimizers and vice versa.  ``fuse_buckets=False`` (or any
+(apex_tpu.ops.multi_tensor's ``flat_adam`` / ``flat_sgd`` / ... ``jnp``
+math, which XLA fuses with the overflow skip and the model-dtype copy
+of the masters: every buffer is read once and written once per phase)
+— the TPU realization of the reference's ``multi_tensor_apply`` +
+``amp_C`` design.  Params, masters and optimizer state stay PACKED
+between steps; the per-leaf pytree view is rebuilt lazily (one compiled
+unpack program) only for ``state_dict()``, ``load_state_dict()`` and the
+``params``/``masters`` properties, and the checkpoint layout is
+unchanged — old per-leaf checkpoints load into bucketed optimizers and
+vice versa.  ``fuse_buckets=False`` (or any
 tree the packer declines: non-float leaves, multi-device shardings)
 falls back to the traced per-leaf update.
 

@@ -1,13 +1,15 @@
 """Per-leaf optimizer math shared by all fused optimizer facades.
 
 Reference kernels: csrc/multi_tensor_{adam,sgd,lamb,novograd,adagrad}.cu
-(SURVEY.md §2.4).  TPU-first note: the reference's "multi tensor" design
-amortizes CUDA launch overhead by fusing thousands of small tensors into
-one launch.  Under XLA a whole-pytree update traced in ONE jit already
-compiles to a handful of fused elementwise loops, so the canonical path
-here is per-leaf jnp math (bandwidth-bound, fully fused); the Pallas
-flat-buffer kernels in apex_tpu.ops.multi_tensor remain available via
-``fused=True`` on the facades for extreme leaf counts.
+(SURVEY.md §2.4).  The bucketed step runs the same updates over flat
+buffers (apex_tpu.ops.multi_tensor's ``flat_adam`` etc.); this file is
+the update one leaf at a time, kept for two reasons.  It is the only
+path for a tree the packer declines (sharded or non-float leaves,
+``fuse_buckets=False``): each facade's ``_step_math`` maps these over
+the pytree inside ONE jit, which XLA compiles to a handful of fused
+elementwise loops.  And it is the independent oracle the tests compare
+the flat path against (tests/test_bucketed_optimizers.py,
+tests/test_multi_tensor.py).
 
 All math accumulates in f32 regardless of storage dtype; master-weight
 handling keeps f32 params alongside bf16 model params (reference O2).

@@ -2,7 +2,7 @@
 cf. csrc/multi_tensor_adagrad.cu.
 
 Flat AMP pipeline: ``step()`` takes already-packed per-bucket gradient
-buffers and a traced ``clip_coef`` folded into ``flat_adagrad_ref``'s
+buffers and a traced ``clip_coef`` folded into ``flat_adagrad``'s
 own unscaling (optimizers/_base._fold_clip)."""
 
 from __future__ import annotations
@@ -39,7 +39,7 @@ class FusedAdagrad(FusedOptimizerBase):
                           hypers, extra, keep=None, model_dtype=None):
         h = self._merge_hypers(hypers)
         with jax.named_scope("apex_optim/moments"):
-            po, ho, *pm = mt.flat_adagrad_ref(
+            po, ho, *pm = mt.flat_adagrad(
                 p, g, state["sum"], lr=h["lr"], eps=h["eps"],
                 weight_decay=h["weight_decay"], grad_scale=grad_scale,
                 keep=keep, model_dtype=model_dtype)

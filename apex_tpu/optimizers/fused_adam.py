@@ -9,7 +9,7 @@ ignored (every step is a compiled graph on TPU).
 Flat AMP pipeline: ``step()`` accepts the bucket plan's per-bucket flat
 gradient buffers (or an ``amp.FlatGrads`` bundle) plus a traced
 ``clip_coef`` — the clip folds into the update's own unscaling of the
-gradients (``flat_adam_ref``), so a clipped step reads the gradients
+gradients (``flat_adam``), so a clipped step reads the gradients
 exactly once (see optimizers/_base._fold_clip).
 """
 
@@ -61,7 +61,7 @@ class FusedAdam(FusedOptimizerBase):
                           hypers, extra, keep=None, model_dtype=None):
         h = self._merge_hypers(hypers)
         with jax.named_scope("apex_optim/moments"):
-            po, mo, vo, *pm = mt.flat_adam_ref(
+            po, mo, vo, *pm = mt.flat_adam(
                 p, g, state["exp_avg"], state["exp_avg_sq"],
                 lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],
                 weight_decay=h["weight_decay"], step=step,

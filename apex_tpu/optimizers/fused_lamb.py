@@ -80,7 +80,7 @@ class FusedLAMB(FusedOptimizerBase):
     def _flat_bucket_step(self, bucket_index, p, g, state, step, grad_scale,
                           hypers, extra, keep=None, model_dtype=None):
         h = self._merge_hypers(hypers)
-        po, mo, vo, *pm = mt.flat_lamb_ref(
+        po, mo, vo, *pm = mt.flat_lamb(
             p, g, state["exp_avg"], state["exp_avg_sq"],
             self._plan.segment_sizes(bucket_index),
             lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],

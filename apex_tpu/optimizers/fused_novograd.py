@@ -71,7 +71,7 @@ class FusedNovoGrad(FusedOptimizerBase):
         # per-tensor second moments ride the bucket's static segment
         # sizes: the packed exp_avg_sq is one (num leaves,) vector per
         # bucket
-        po, mo, vo, *pm = mt.flat_novograd_ref(
+        po, mo, vo, *pm = mt.flat_novograd(
             p, g, state["exp_avg"], state["exp_avg_sq"],
             self._plan.segment_sizes(bucket_index),
             lr=h["lr"], beta1=h["beta1"], beta2=h["beta2"], eps=h["eps"],

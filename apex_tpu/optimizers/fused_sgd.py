@@ -4,7 +4,7 @@ torch.optim.SGD semantics (momentum / dampening / nesterov / weight
 decay) as one fused pytree update; cf. csrc/multi_tensor_sgd_kernel.cu.
 
 Flat AMP pipeline: ``step()`` takes already-packed per-bucket gradient
-buffers and a traced ``clip_coef`` folded into ``flat_sgd_ref``'s own
+buffers and a traced ``clip_coef`` folded into ``flat_sgd``'s own
 unscaling (optimizers/_base._fold_clip) — no per-leaf clip pass.
 """
 
@@ -55,7 +55,7 @@ class FusedSGD(FusedOptimizerBase):
                           hypers, extra, keep=None, model_dtype=None):
         h = self._merge_hypers(hypers)
         with jax.named_scope("apex_optim/moments"):
-            po, bo, *pm = mt.flat_sgd_ref(
+            po, bo, *pm = mt.flat_sgd(
                 p, g, state["momentum_buffer"], lr=h["lr"],
                 momentum=self.hypers["momentum"],
                 dampening=self.hypers["dampening"],

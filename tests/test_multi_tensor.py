@@ -1,4 +1,5 @@
-"""Pallas multi-tensor kernels vs jnp oracles.
+"""Pallas multi-tensor kernels vs jnp oracles, and the flat optimizer
+updates (``jnp``, no kernel) vs the per-leaf math and torch.
 
 Mirrors the reference's dominant test pattern (SURVEY.md §4): fused kernel
 vs stock oracle, allclose under per-dtype tolerances, over a small
@@ -13,6 +14,7 @@ import pytest
 from apex_tpu.ops import multi_tensor as mt
 from apex_tpu.multi_tensor_apply import (flatten, unflatten,
                                          multi_tensor_applier)
+from apex_tpu.optimizers import _functional as F
 
 SIZES = [1, 100, 128, 1024, 5000]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -21,6 +23,16 @@ DTYPES = [jnp.float32, jnp.bfloat16]
 def tol(dtype):
     return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
         else dict(rtol=1e-6, atol=1e-6)
+
+
+def per_leaf(leaf_step, sizes, bufs, **kw):
+    """The independent oracle of a flat update: ``leaf_step`` (one of
+    ``optimizers/_functional``'s) over each segment of the buffers on
+    its own, the results laid end to end again."""
+    bounds = np.cumsum((0,) + tuple(sizes))
+    outs = [leaf_step(*(b[lo:hi] for b in bufs), **kw)
+            for lo, hi in zip(bounds[:-1], bounds[1:])]
+    return tuple(jnp.concatenate(o) for o in zip(*outs))
 
 
 @pytest.mark.parametrize("n", SIZES)
@@ -64,8 +76,9 @@ def test_flat_l2norm(n):
 
 @pytest.mark.parametrize("adam_w", [True, False])
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_flat_adam_matches_ref(adam_w, dtype):
-    n = 3000
+def test_flat_adam_matches_per_leaf(adam_w, dtype):
+    sizes = (257, 128, 1000, 5, 1610)
+    n = sum(sizes)
     keys = jax.random.split(jax.random.key(3), 4)
     p = jax.random.normal(keys[0], (n,), jnp.float32).astype(dtype)
     g = jax.random.normal(keys[1], (n,), jnp.float32).astype(dtype)
@@ -74,7 +87,7 @@ def test_flat_adam_matches_ref(adam_w, dtype):
     kw = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8,
               weight_decay=0.01, step=1, adam_w_mode=adam_w)
     po, mo, vo = mt.flat_adam(p, g, m, v, **kw)
-    pr, mr, vr = mt.flat_adam_ref(p, g, m, v, **kw)
+    pr, mr, vr = per_leaf(F.adam_step, sizes, (p, g, m, v), **kw)
     np.testing.assert_allclose(np.asarray(po, np.float32),
                                np.asarray(pr, np.float32), **tol(dtype))
     np.testing.assert_allclose(mo, mr, rtol=1e-5, atol=1e-6)
@@ -217,7 +230,8 @@ class TestDispatchPrefs:
             {"kernel": "flash_attention", "speedup": 2.0, "backend": "tpu"},
             {"kernel": "int8_matmul_weight_only", "speedup": 1.9,
              "backend": "tpu"},               # not a dispatch family
-            {"kernel": "flat_adam", "speedup": None, "backend": "tpu"},
+            {"kernel": "flat_unscale_norm", "speedup": None,
+             "backend": "tpu"},
         ]
         p = tmp_path / "prefs.json"
         prefs = kb.write_prefs(rows, str(p))
@@ -229,15 +243,16 @@ class TestDispatchPrefs:
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_flat_adagrad_matches_ref(dtype):
-    n = 2000
+def test_flat_adagrad_matches_per_leaf(dtype):
+    sizes = (257, 128, 1000, 5, 610)
+    n = sum(sizes)
     keys = jax.random.split(jax.random.key(4), 2)
     p = jax.random.normal(keys[0], (n,), jnp.float32).astype(dtype)
     g = jax.random.normal(keys[1], (n,), jnp.float32).astype(dtype)
     h = jnp.abs(jax.random.normal(jax.random.key(5), (n,))) * 0.1
     kw = dict(lr=1e-2, eps=1e-10, weight_decay=0.01)
     po, ho = mt.flat_adagrad(p, g, h, **kw)
-    pr, hr = mt.flat_adagrad_ref(p, g, h, **kw)
+    pr, hr = per_leaf(F.adagrad_step, sizes, (p, g, h), **kw)
     np.testing.assert_allclose(np.asarray(po, np.float32),
                                np.asarray(pr, np.float32), **tol(dtype))
     np.testing.assert_allclose(ho, hr, rtol=1e-5, atol=1e-6)
@@ -301,57 +316,51 @@ def test_flat_segment_sizes_must_cover_the_buffer():
 
 
 @pytest.mark.parametrize("use_nvlamb", [False, True])
-def test_flat_lamb_matches_ref(use_nvlamb):
+def test_flat_lamb_matches_per_leaf(use_nvlamb):
     p, g, m, v, sizes = _segmented_buffers()
     kw = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6,
               weight_decay=0.01, step=3, clip_coeff=0.7,
               use_nvlamb=use_nvlamb)
     po, mo, vo = mt.flat_lamb(p, g, m, v, sizes, **kw)
-    pr, mr, vr = mt.flat_lamb_ref(p, g, m, v, sizes, **kw)
+    pr, mr, vr = per_leaf(F.lamb_step, sizes, (p, g, m, v), **kw)
     np.testing.assert_allclose(po, pr, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(mo, mr, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(vo, vr, rtol=1e-5, atol=1e-6)
 
 
 def test_flat_lamb_trust_ratio_is_per_segment():
-    """The segmented kernel must reproduce the per-leaf trust ratios —
+    """The segmented update must reproduce the per-leaf trust ratios —
     not one bucket-global ratio."""
-    from apex_tpu.optimizers import _functional as F
     p, g, m, v, sizes = _segmented_buffers()
     kw = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6,
               weight_decay=0.01, step=3)
     po, _, _ = mt.flat_lamb(p, g, m, v, sizes, **kw)
-    o = 0
-    for sz in sizes:
-        sl = slice(o, o + sz)
-        pe, _, _ = F.lamb_step(p[sl], g[sl], m[sl], v[sl], **kw)
-        np.testing.assert_allclose(po[sl], pe, rtol=1e-5, atol=1e-6)
-        o += sz
+    pe, _, _ = per_leaf(F.lamb_step, sizes, (p, g, m, v), **kw)
+    np.testing.assert_allclose(po, pe, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("first_run", [True, False])
 def test_flat_novograd_matches_per_leaf(first_run):
-    from apex_tpu.optimizers import _functional as F
     p, g, m, _, sizes = _segmented_buffers(key=8)
     vseg = jnp.abs(jax.random.normal(jax.random.key(9),
                                      (len(sizes),))) * 0.2
     kw = dict(lr=1e-3, beta1=0.95, beta2=0.98, eps=1e-8,
               weight_decay=0.01, first_run=first_run)
     po, mo, vo = mt.flat_novograd(p, g, m, vseg, sizes, **kw)
-    pr, mr, vr = mt.flat_novograd_ref(p, g, m, vseg, sizes, **kw)
-    np.testing.assert_allclose(po, pr, rtol=1e-5, atol=1e-6)
     o = 0
     for i, sz in enumerate(sizes):
         sl = slice(o, o + sz)
         pe, me, ve = F.novograd_step(p[sl], g[sl], m[sl], vseg[i], **kw)
         np.testing.assert_allclose(po[sl], pe, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(mo[sl], me, rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(vo[i], ve, rtol=1e-5, atol=1e-6)
         o += sz
 
 
 def test_flat_sgd_traced_first_run():
     """first_run may be a traced bool (step == 1 inside a jitted
-    optimizer step) on both the kernel and the ref path."""
+    optimizer step): the select must pick what the per-leaf math does
+    with a Python bool."""
     n = 300
     p = jax.random.normal(jax.random.key(0), (n,))
     g = jax.random.normal(jax.random.key(1), (n,))
@@ -364,7 +373,7 @@ def test_flat_sgd_traced_first_run():
 
     for count, want_first in ((1, True), (2, False)):
         po, bo = step(p, g, buf, jnp.int32(count))
-        pr, br = mt.flat_sgd_ref(p, g, buf, first_run=want_first, **kw)
+        pr, br = F.sgd_step(p, g, buf, first_run=want_first, **kw)
         np.testing.assert_allclose(po, pr, rtol=1e-6, atol=1e-7)
         np.testing.assert_allclose(bo, br, rtol=1e-6, atol=1e-7)
 

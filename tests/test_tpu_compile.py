@@ -113,7 +113,8 @@ def test_flash_attention_fwd_bwd(one_chip, shape, causal):
 
 
 # ---------------------------------------------------------------------------
-# the flat optimizer / AMP kernels at real bucket sizes
+# the flat optimizer updates (XLA sweeps) and the AMP kernel at real
+# bucket sizes
 # ---------------------------------------------------------------------------
 
 # one 128 MiB-capped BERT-Large bucket: two encoder layers and a third
@@ -138,8 +139,7 @@ def test_flat_lamb_one_bert_large_bucket(one_chip):
     assert n == 32_537_600 and len(LAMB_BUCKET_SIZES) == 28
     text = _compile(_lamb(LAMB_BUCKET_SIZES), one_chip,
                     *(((n,), F32),) * 4)
-    _assert_kernels(text, "apex_multi_tensor_lamb_moments",
-                    "apex_multi_tensor_lamb_apply")
+    assert "tpu_custom_call" not in text
     # per-tensor norms are reduces over static slices: nothing is
     # scattered through or gathered from an element->segment id vector
     assert "scatter" not in text and "gather" not in text
@@ -178,7 +178,7 @@ def test_lamb_bucket_step_is_one_sweep_per_phase(one_chip):
     n = N_LAMB_BUCKET
 
     def step(m, v, p, g, found_inf):
-        p, m, v, p_model = mt.flat_lamb_ref(
+        p, m, v, p_model = mt.flat_lamb(
             p, g, m, v, LAMB_BUCKET_SIZES, lr=1e-3, beta1=0.9, beta2=0.999,
             eps=1e-6, weight_decay=0.01, step=3, keep=found_inf == 0,
             model_dtype=BF16)
@@ -212,7 +212,7 @@ def test_adam_bucket_step_is_one_sweep(one_chip):
     n = 32 * 2 ** 20
 
     def step(m, v, p, g, found_inf):
-        p, m, v, p_model = mt.flat_adam_ref(
+        p, m, v, p_model = mt.flat_adam(
             p, g, m, v, lr=3e-4, beta1=0.9, beta2=0.95, eps=1e-8,
             weight_decay=0.1, step=3, grad_scale=1024.0,
             keep=found_inf == 0, model_dtype=BF16)
@@ -229,14 +229,32 @@ def test_adam_bucket_step_is_one_sweep(one_chip):
     assert stats.temp_size_in_bytes < 2 ** 20
 
 
-def test_flat_sgd_resnet50_size(one_chip):
+def test_sgd_bucket_step_is_one_sweep(one_chip):
+    """ResNet-50's parameters as one bucket under the bucketed SGD
+    step: one fusion reads p, g and the momentum buffer and writes p,
+    the buffer and the bf16 parameters; no temporaries, no copies."""
     from apex_tpu.ops import multi_tensor as mt
     n = N_RESNET50
-    text = _compile(
-        lambda p, g, m: mt.flat_sgd(p, g, m, lr=0.1, momentum=0.9,
-                                    weight_decay=1e-4),
-        one_chip, *(((n,), F32),) * 3)
-    _assert_kernels(text, "apex_multi_tensor_sgd")
+
+    def step(buf, p, g, count, found_inf):
+        p, buf, p_model = mt.flat_sgd(
+            p, g, buf, lr=0.1, momentum=0.9, weight_decay=1e-4,
+            first_run=count == 1, grad_scale=128.0, keep=found_inf == 0,
+            model_dtype=BF16)
+        return buf, p, p_model
+
+    args = [jax.ShapeDtypeStruct((n,), d, sharding=one_chip)
+            for d in (F32, F32, BF16)]
+    args += [jax.ShapeDtypeStruct((), I32, sharding=one_chip)] * 2
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    ops = _bucket_sized_ops(text, n)
+    assert sum(ops.values()) == 1 and not ops.get("copy"), ops
+    stats = compiled.memory_analysis()
+    # the momentum buffer in place (its allocation padded to a tile)
+    assert 4 * n <= stats.alias_size_in_bytes < 4 * n + 2 ** 13
+    assert stats.temp_size_in_bytes < 2 ** 20
 
 
 def test_flat_unscale_norm_resnet50_size(one_chip):

@@ -314,28 +314,6 @@ def test_flat_scale_inf_flag():
     assert int(flag) == 1
 
 
-def test_flat_adam_sgd():
-    from apex_tpu.ops import multi_tensor as mt
-    n = 1 << 18
-    ks = jax.random.split(jax.random.key(0), 4)
-    p = jax.random.normal(ks[0], (n,), jnp.float32)
-    g = jax.random.normal(ks[1], (n,), jnp.float32) * 0.1
-    m = jax.random.normal(ks[2], (n,), jnp.float32) * 0.01
-    v = jnp.abs(jax.random.normal(ks[3], (n,), jnp.float32)) * 0.01
-    kw = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8,
-              weight_decay=0.01, step=7, adam_w_mode=True)
-    out = jax.jit(lambda *a: mt.flat_adam(*a, **kw))(p, g, m, v)
-    ref = mt.flat_adam_ref(p, g, m, v, **kw)
-    for a, b_ in zip(out, ref):
-        _close(a, b_, jnp.float32, rtol=1e-5, atol=1e-6)
-    kw = dict(lr=0.1, momentum=0.9, dampening=0.0, weight_decay=1e-4,
-              nesterov=False, first_run=False)
-    out = jax.jit(lambda *a: mt.flat_sgd(*a, **kw))(p, g, m)
-    ref = mt.flat_sgd_ref(p, g, m, **kw)
-    for a, b_ in zip(out, ref):
-        _close(a, b_, jnp.float32, rtol=1e-5, atol=1e-6)
-
-
 # ---------------------------------------------------------------------------
 # welford / xentropy
 # ---------------------------------------------------------------------------

@@ -475,16 +475,12 @@ def _routing_cases(cfg):
     key = jax.random.key(0)
 
     n = cfg["mt_n"]
-    p = jax.random.normal(key, (n,), jnp.float32)
     g = jax.random.normal(jax.random.key(2), (n,), jnp.float32) * 0.01
-    m = jnp.zeros((n,), jnp.float32)
-    v = jnp.zeros((n,), jnp.float32)
-    kw = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8,
-              weight_decay=0.01, step=3, adam_w_mode=True)
-    cases.append(("multi_tensor", f"flat_adam/n={n}", "f32",
-                  functools.partial(mt.flat_adam, **kw),
-                  functools.partial(mt.flat_adam_ref, **kw),
-                  (p, g, m, v)))
+    inv = jnp.float32(1.0 / 65536.0)
+    cases.append(("multi_tensor", f"flat_unscale_norm/n={n}", "f32",
+                  lambda g_: mt.flat_unscale_norm(g_, inv),
+                  lambda g_: mt.flat_unscale_norm_ref(g_, inv),
+                  (g,)))
 
     r, c = cfg["welford_shape"]
     xw = jax.random.normal(key, (r, c), jnp.bfloat16)

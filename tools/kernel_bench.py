@@ -98,8 +98,6 @@ _OP_FAMILY = {
     "fused_layer_norm": "layer_norm",
     "scaled_upper_triang_masked_softmax": "softmax",
     "softmax_cross_entropy": "xentropy",
-    "flat_adam": "multi_tensor",
-    "flat_lamb": "multi_tensor",
     "flat_unscale_norm": "multi_tensor",
     "flat_accumulate": "multi_tensor",
     "welford_mean_var": "welford",
@@ -526,10 +524,7 @@ def main():
 
     # multi-tensor substrate
     n = 1 << 24
-    p = jax.random.normal(key, (n,), jnp.float32)
     g = jax.random.normal(jax.random.key(2), (n,), jnp.float32) * 0.01
-    m = jnp.zeros((n,), jnp.float32)
-    v = jnp.zeros((n,), jnp.float32)
     # fused amp gradient epilogue: unscale + non-finite + Σg² in ONE
     # HBM read, vs the same three answers computed the per-leaf way
     # (scale pass + isfinite pass + l2norm pass over the same buffer)
@@ -538,26 +533,9 @@ def main():
         "flat_unscale_norm", f"n={n}", "f32",
         lambda g_: mt.flat_unscale_norm(g_, inv),
         lambda g_: mt.flat_unscale_norm_ref(g_, inv), g))
-    kw = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8,
-              weight_decay=0.01, step=3, adam_w_mode=True)
-    rows.append(bench_pair(
-        "flat_adam", f"n={n}", "f32",
-        lambda *a: mt.flat_adam(*a, **kw),
-        lambda *a: mt.flat_adam_ref(*a, **kw), p, g, m, v))
-    # segmented LAMB over the same buffer, carved into 256 "tensors"
-    n_seg = 256
-    sizes = (n // n_seg,) * n_seg
-    kwl = dict(lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-6,
-               weight_decay=0.01, step=3, clip_coeff=1.0)
-    rows.append(bench_pair(
-        "flat_lamb", f"n={n}/seg{n_seg}", "f32",
-        lambda p_, g_, m_, v_: mt.flat_lamb(p_, g_, m_, v_, sizes, **kwl),
-        lambda p_, g_, m_, v_: mt.flat_lamb_ref(p_, g_, m_, v_, sizes,
-                                                **kwl),
-        p, g, m, v))
 
     # per-leaf vs bucketed fused-optimizer step on a many-leaf pytree —
-    # the end-to-end number the flat kernels exist for (recorded in the
+    # the end-to-end number the flat buffers exist for (recorded in the
     # bench round via bench.py extras too)
     from apex_tpu.optimizers.bucketing_bench import \
         bench_amp_pipeline, bench_optimizer_bucketing
