@@ -13,8 +13,10 @@ grid) recomputing probabilities from the forward's saved logsumexp —
 no probability tensor is ever stored, matching the memory behavior the
 reference gets from its fused in-place bwd kernels.
 
-Variant flags: ``causal`` prunes the iteration space (fully-masked
-blocks are skipped and their DMAs clamped away); ``segment_ids``
+Variant flags: ``causal`` prunes the iteration space (the grid's
+sequential axis enumerates only the blocks on or under the diagonal —
+``causal_block_plan`` — so a fully-masked block costs neither a DMA nor
+a grid step); ``segment_ids``
 (q-ids, kv-ids) masks cross-segment pairs, which is how contrib.fmha's
 packed variable-length batches route through this one kernel.
 
@@ -30,9 +32,10 @@ Shapes: (B, H, S, D) throughout ("bhsd").
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -217,11 +220,119 @@ def _mask_for_block(j, kk, bq, bk, sq, sk, sqp, skp, causal,
 
 
 # ---------------------------------------------------------------------------
+# the causal grid: which score blocks are visited, in which order
+# ---------------------------------------------------------------------------
+
+class CausalBlockPlan(NamedTuple):
+    """The (q-block j, kv-block kk) score blocks a causal call visits,
+    each as (j, kk, interior), in the two orders its kernels walk them.
+    ``q_major`` (forward, dq): a q block's kv blocks side by side, first
+    to diagonal.  ``kv_major`` (dkv): a kv block's q blocks side by
+    side, diagonal to last; a kv block no row reaches (sk > sq) keeps
+    one fully-masked visit of the last q block, so that its dk/dv are
+    written (zeros).  ``interior``: the block lies wholly under the
+    diagonal and holds no padding, so its mask masks nothing — the
+    kernels still run their one masked body there (a mask-free body
+    gained nothing on the chip: PERF.md section 6, PR 30).  The counts
+    are of ``q_major`` against the nq x nk rectangle: ``not_visited``
+    blocks cost no grid step."""
+    nq: int
+    nk: int
+    q_major: Tuple[Tuple[int, int, bool], ...]
+    kv_major: Tuple[Tuple[int, int, bool], ...]
+    interior: int
+    diagonal: int
+    not_visited: int
+
+
+@functools.lru_cache(maxsize=None)
+def causal_block_plan(sq: int, sk: int, bq: int, bk: int
+                      ) -> CausalBlockPlan:
+    """The blocks a causal call of true lengths (sq, sk) tiled (bq, bk)
+    visits.  Pure and static — the geometry alone decides, never a
+    setting: the one place the kernels' grids, tools/kernel_bench.py
+    and the tests read."""
+    nq, nk = -(-sq // bq), -(-sk // bk)
+
+    def pair(j, kk):
+        interior = ((kk + 1) * bk - 1 <= j * bq      # under the diagonal
+                    and (kk + 1) * bk <= sk and (j + 1) * bq <= sq)
+        return j, kk, interior
+
+    q_major = tuple(
+        pair(j, kk) for j in range(nq)
+        for kk in range(min(nk - 1, ((j + 1) * bq - 1) // bk) + 1))
+    kv_major = tuple(
+        pair(j, kk) for kk in range(nk)
+        for j in range(max(0, min(nq - 1, (kk * bk) // bq)), nq))
+    interior = sum(p[2] for p in q_major)
+    return CausalBlockPlan(nq, nk, q_major, kv_major, interior,
+                           len(q_major) - interior,
+                           nq * nk - len(q_major))
+
+
+def _launch(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+            args, steps=()):
+    """One flash kernel launch.  Rectangular: ``grid`` = (rows, outer,
+    inner), the inner axis sequential.  Causal: ``steps`` = two int32
+    vectors with one entry per visited block (``_causal_steps``),
+    scalar-prefetched; the two block axes flatten into ONE sequential
+    axis over just those blocks — grid (rows, len(steps[0])) — and the
+    index maps and the body look their block up in the vectors, so a
+    block above the diagonal costs no grid step at all."""
+    if steps:
+        grid = (grid[0], steps[0].shape[0])
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(steps),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",)),
+        interpret=interpret_mode(),
+        name=name,
+    )(*steps, *args)
+
+
+def _causal_steps(pairs):
+    """(outer, inner) block indices, one pair per grid step -> the two
+    int32 vectors ``_launch`` prefetches."""
+    return tuple(jnp.asarray(col, jnp.int32) for col in zip(*pairs))
+
+
+def _on_steps(index_map):
+    """An index map written over a rectangular grid's (row, outer,
+    inner) -> the same map on the flattened causal grid, where a step
+    looks its (outer, inner) up in the two prefetched vectors."""
+    return lambda i, t, outer, inner: index_map(i, outer[t], inner[t])
+
+
+def _grid_position(flat, refs):
+    """(row, outer, inner) of this grid step, and the refs that are
+    left: the program ids on a rectangle; on the flattened causal grid
+    the step's entry in the two prefetched vectors, which lead
+    ``refs``."""
+    i = pl.program_id(0)
+    if not flat:
+        return i, pl.program_id(1), pl.program_id(2), refs
+    t = pl.program_id(1)
+    return i, refs[0][t], refs[1][t], refs[2:]
+
+
+# ---------------------------------------------------------------------------
 # forward kernel: grid (B*H, NQ, NK), KV innermost, flash-2 online softmax
+# (causal: grid (B*H, visited blocks), a q block's kv blocks side by side)
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(scale, causal, seg, need_lse, rate, sq, sk, sqp, skp,
                 bq, bk, nk, *refs):
+    # causal: this step's block comes from the plan's q-major list
+    i, j, kk, refs = _grid_position(causal, refs)
     q_ref, k_ref, v_ref = refs[:3]
     refs = refs[3:]
     if rate > 0.0:
@@ -233,9 +344,6 @@ def _fwd_kernel(scale, causal, seg, need_lse, rate, sq, sk, sqp, skp,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
         lse_ref = None
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    kk = pl.program_id(2)
 
     @pl.when(kk == 0)
     def _init():
@@ -247,7 +355,6 @@ def _fwd_kernel(scale, causal, seg, need_lse, rate, sq, sk, sqp, skp,
     kk_last = jnp.minimum(nk - 1, ((j + 1) * bq - 1) // bk) if causal \
         else nk - 1
 
-    @pl.when(kk <= kk_last)
     def _body():
         # native-dtype operands on the MXU (bf16 runs at full rate),
         # f32 accumulation via preferred_element_type
@@ -276,6 +383,11 @@ def _fwd_kernel(scale, causal, seg, need_lse, rate, sq, sk, sqp, skp,
             p = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
         pv = _dot(p.astype(v_ref.dtype), v_ref[0], ((1,), (0,)))
         acc_scr[...] = acc_scr[...] * alpha + pv
+
+    if causal:      # every step of the flattened grid has work
+        _body()
+    else:
+        pl.when(kk <= kk_last)(_body)
 
     @pl.when(kk == kk_last)
     def _finish():
@@ -413,18 +525,18 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
     k3 = _pad_head(_pad_seq(k, skp), dp).reshape(b * hk, skp, dp)
     v3 = _pad_head(_pad_seq(v, skp), dp).reshape(b * hk, skp, dp)
 
-    if causal:
-        # clamp the KV index for blocks above the diagonal: the skipped
-        # iterations re-reference the diagonal block, so no DMA is issued
-        def _kv_idx(i, j, kk, bq=bq, bk=bk, nk=nk):
-            return (_kv_row(i, h, hk), jnp.minimum(kk, jnp.minimum(
-                nk - 1, ((j + 1) * bq - 1) // bk)), 0)
-    else:
-        _kv_idx = lambda i, j, kk: (_kv_row(i, h, hk), kk, 0)
+    # the index maps are written over (i, j, kk); on the flattened
+    # causal grid (nk > 1) ``at`` looks (j, kk) up in the plan's vectors
+    steps, at = (), (lambda f: f)
+    if causal and nk > 1:
+        steps = _causal_steps(
+            p[:2] for p in causal_block_plan(sq, sk, bq, bk).q_major)
+        at = _on_steps
+    _kv_idx = lambda i, j, kk: (_kv_row(i, h, hk), kk, 0)
     in_specs = [
-        pl.BlockSpec((1, bq, dp), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bk, dp), _kv_idx),
-        pl.BlockSpec((1, bk, dp), _kv_idx),
+        pl.BlockSpec((1, bq, dp), at(lambda i, j, kk: (i, j, 0))),
+        pl.BlockSpec((1, bk, dp), at(_kv_idx)),
+        pl.BlockSpec((1, bk, dp), at(_kv_idx)),
     ]
     args = [q3, k3, v3]
     if rate > 0.0:
@@ -434,18 +546,20 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
     if seg:
         qs, ks = _seg_inputs(segment_ids, b, sqp, skp)
         in_specs += [
-            pl.BlockSpec((1, bq, _LANES), lambda i, j, kk: (i // h, j, 0)),
+            pl.BlockSpec((1, bq, _LANES),
+                         at(lambda i, j, kk: (i // h, j, 0))),
             pl.BlockSpec((1, 8, bk),
-                         lambda i, j, kk: (i // h, 0,
-                                           _kv_idx(i, j, kk)[1])),
+                         at(lambda i, j, kk: (i // h, 0,
+                                              _kv_idx(i, j, kk)[1]))),
         ]
         args += [qs, ks]
 
-    out_specs = [pl.BlockSpec((1, bq, dp), lambda i, j, kk: (i, j, 0))]
+    out_specs = [pl.BlockSpec((1, bq, dp),
+                              at(lambda i, j, kk: (i, j, 0)))]
     out_shape = [jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype)]
     if need_lse:
         out_specs.append(
-            pl.BlockSpec((1, bq, _LANES), lambda i, j, kk: (i, j, 0)))
+            pl.BlockSpec((1, bq, _LANES), at(lambda i, j, kk: (i, j, 0))))
         out_shape.append(
             jax.ShapeDtypeStruct((b * h, sqp, _LANES), jnp.float32))
     if nk == 1:
@@ -462,18 +576,8 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, dp), jnp.float32),
         ]
-    outs = pl.pallas_call(
-        kernel,
-        grid=(b * h, nq, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret_mode(),
-        name="apex_flash_attention_fwd",
-    )(*args)
+    outs = _launch(kernel, "apex_flash_attention_fwd", (b * h, nq, nk),
+                   in_specs, out_specs, out_shape, scratch, args, steps)
     o = outs[0].reshape(b, h, sqp, dp)[:, :, :sq, :d]
     return o, (outs[1] if need_lse else None)
 
@@ -497,6 +601,8 @@ def _recompute_p(scale, causal, seg, sq, sk, sqp, skp, bq, bk, j, kk,
 
 def _dq_kernel(scale, causal, seg, rate, sq, sk, sqp, skp, bq, bk, nk,
                *refs):
+    # causal: this step's block comes from the plan's q-major list
+    i, j, kk, refs = _grid_position(causal, refs)
     if rate > 0.0:
         seed_ref, refs = refs[0], refs[1:]
     if seg:
@@ -505,9 +611,6 @@ def _dq_kernel(scale, causal, seg, rate, sq, sk, sqp, skp, bq, bk, nk,
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_scr = refs
         qs_ref = ks_ref = None
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    kk = pl.program_id(2)
 
     @pl.when(kk == 0)
     def _init():
@@ -516,7 +619,6 @@ def _dq_kernel(scale, causal, seg, rate, sq, sk, sqp, skp, bq, bk, nk,
     kk_last = jnp.minimum(nk - 1, ((j + 1) * bq - 1) // bk) if causal \
         else nk - 1
 
-    @pl.when(kk <= kk_last)
     def _body():
         p = _recompute_p(scale, causal, seg, sq, sk, sqp, skp, bq, bk,
                          j, kk, q_ref, k_ref, qs_ref, ks_ref, lse_ref)
@@ -531,6 +633,11 @@ def _dq_kernel(scale, causal, seg, rate, sq, sk, sqp, skp, bq, bk, nk,
         dq_scr[...] += _dot(ds.astype(k_ref.dtype), k_ref[0],
                             ((1,), (0,)))
 
+    if causal:      # every step of the flattened grid has work
+        _body()
+    else:
+        pl.when(kk <= kk_last)(_body)
+
     @pl.when(kk == kk_last)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
@@ -542,7 +649,10 @@ def _dkv_kernel(scale, causal, seg, rate, h, hk, sq, sk, sqp, skp, bq,
     q-head GROUP sharing this kv head times the q blocks (t = qh*NQ+j,
     grouped-query attention): every q head's contribution lands in the
     same scratch accumulator, race-free because the axis is
-    'arbitrary' (sequential).  g == 1 recovers plain MHA exactly."""
+    'arbitrary' (sequential).  g == 1 recovers plain MHA exactly.
+    Causal: the plan's kv-major list gives each step its (kk, t); a kv
+    block's steps stay side by side, q heads outermost among them."""
+    i, kk, t, refs = _grid_position(causal, refs)
     if rate > 0.0:
         seed_ref, refs = refs[0], refs[1:]
     if seg:
@@ -552,9 +662,6 @@ def _dkv_kernel(scale, causal, seg, rate, h, hk, sq, sk, sqp, skp, bq,
         q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, \
             dk_ref, dv_ref, dk_scr, dv_scr = refs
         qs_ref = ks_ref = None
-    i = pl.program_id(0)
-    kk = pl.program_id(1)
-    t = pl.program_id(2)
     j = t % nq if g > 1 else t
 
     # causal: first Q block whose rows reach this KV block (same for
@@ -567,7 +674,6 @@ def _dkv_kernel(scale, causal, seg, rate, h, hk, sq, sk, sqp, skp, bq,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(j >= j_first)
     def _body():
         p = _recompute_p(scale, causal, seg, sq, sk, sqp, skp, bq, bk,
                          j, kk, q_ref, k_ref, qs_ref, ks_ref, lse_ref)
@@ -590,6 +696,11 @@ def _dkv_kernel(scale, causal, seg, rate, h, hk, sq, sk, sqp, skp, bq,
         ds = p * (dp - di_ref[0, :, :1]) * scale
         dk_scr[...] += _dot(ds.astype(q_ref.dtype), q_ref[0],
                             ((0,), (0,)))
+
+    if causal:      # every step of the flattened grid has work
+        _body()
+    else:
+        pl.when(j >= j_first)(_body)
 
     @pl.when(t == g * nq - 1)
     def _finish():
@@ -627,95 +738,88 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, segment_ids,
 
     # dkv grid rows run over KV heads (b*hk); its sequential axis t
     # covers the q-head group x q blocks.  These maps recover the flat
-    # q row and the (causal-clamped) q block from (i, kk, t).
+    # q row and the q block from (i, kk, t).
     def _q_row_kv(i, t):
         return (i // hk) * h + (i % hk) * g + t // nq
 
+    # index maps are written over the rectangular grids' positions —
+    # (i, j, kk) for dq, (i, kk, t) for dkv; on the flattened causal
+    # grids ``at`` looks the block up in the plan's vectors (q-major
+    # for dq; kv-major for dkv, the q-head group unrolled into t as on
+    # the rectangle)
+    dq_steps, dkv_steps, at = (), (), (lambda f: f)
     if causal:
-        def _kv_idx(i, j, kk, bq=bq, bk=bk, nk=nk):
-            return (_kv_row(i, h, hk), jnp.minimum(kk, jnp.minimum(
-                nk - 1, ((j + 1) * bq - 1) // bk)), 0)
-
-        def _q_idx_kv(i, kk, t, bq=bq, bk=bk, nq=nq):
-            return (_q_row_kv(i, t), jnp.maximum(t % nq, jnp.minimum(
-                nq - 1, (kk * bk) // bq)), 0)
-    else:
-        _kv_idx = lambda i, j, kk: (_kv_row(i, h, hk), kk, 0)
-        _q_idx_kv = lambda i, kk, t: (_q_row_kv(i, t), t % nq, 0)
+        plan = causal_block_plan(sq, sk, bq, bk)
+        dq_steps = _causal_steps(p[:2] for p in plan.q_major)
+        kt = []
+        for kk, grp in itertools.groupby(plan.kv_major,
+                                         key=lambda p: p[1]):
+            js = [p[0] for p in grp]
+            kt += [(kk, qh * nq + j) for qh in range(g) for j in js]
+        dkv_steps = _causal_steps(kt)
+        at = _on_steps
+    _kv_idx = lambda i, j, kk: (_kv_row(i, h, hk), kk, 0)
+    _q_idx_kv = lambda i, kk, t: (_q_row_kv(i, t), t % nq, 0)
     base_specs = [
-        pl.BlockSpec((1, bq, dp), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bk, dp), _kv_idx),
-        pl.BlockSpec((1, bk, dp), _kv_idx),
-        pl.BlockSpec((1, bq, dp), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bq, _LANES), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bq, _LANES), lambda i, j, kk: (i, j, 0)),
+        pl.BlockSpec((1, bq, dp), at(lambda i, j, kk: (i, j, 0))),
+        pl.BlockSpec((1, bk, dp), at(_kv_idx)),
+        pl.BlockSpec((1, bk, dp), at(_kv_idx)),
+        pl.BlockSpec((1, bq, dp), at(lambda i, j, kk: (i, j, 0))),
+        pl.BlockSpec((1, bq, _LANES), at(lambda i, j, kk: (i, j, 0))),
+        pl.BlockSpec((1, bq, _LANES), at(lambda i, j, kk: (i, j, 0))),
     ]
     args = [q3, k3, v3, do3, lse, di]
     seg_specs = []
     if seg:
         qs, ks = _seg_inputs(segment_ids, b, sqp, skp)
         seg_specs = [
-            pl.BlockSpec((1, bq, _LANES), lambda i, j, kk: (i // h, j, 0)),
+            pl.BlockSpec((1, bq, _LANES),
+                         at(lambda i, j, kk: (i // h, j, 0))),
             pl.BlockSpec((1, 8, bk),
-                         lambda i, j, kk: (i // h, 0,
-                                           _kv_idx(i, j, kk)[1])),
+                         at(lambda i, j, kk: (i // h, 0,
+                                              _kv_idx(i, j, kk)[1]))),
         ]
         args += [qs, ks]
 
-    dq = pl.pallas_call(
+    dq = _launch(
         functools.partial(_dq_kernel, scale, causal, seg, rate, sq, sk,
                           sqp, skp, bq, bk, nk),
-        grid=(b * h, nq, nk),
-        in_specs=seed_specs + base_specs + seg_specs,
-        out_specs=[pl.BlockSpec((1, bq, dp), lambda i, j, kk: (i, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((bq, dp), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret_mode(),
-        name="apex_flash_attention_dq",
-    )(*(seed_args + args))[0]
+        "apex_flash_attention_dq", (b * h, nq, nk),
+        seed_specs + base_specs + seg_specs,
+        [pl.BlockSpec((1, bq, dp), at(lambda i, j, kk: (i, j, 0)))],
+        [jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype)],
+        [pltpu.VMEM((bq, dp), jnp.float32)],
+        seed_args + args, dq_steps)[0]
 
-    # dk/dv grid: (BH, NK, NQ) — q innermost; index maps swap j/kk roles;
-    # for causal, Q-side blocks below the first contributing one are
-    # clamped so skipped iterations issue no DMA
+    # dk/dv grid: (BH, NK, NQ) — q innermost; index maps swap j/kk roles
     kv_specs = [
-        pl.BlockSpec((1, bq, dp), _q_idx_kv),
-        pl.BlockSpec((1, bk, dp), lambda i, kk, j: (i, kk, 0)),
-        pl.BlockSpec((1, bk, dp), lambda i, kk, j: (i, kk, 0)),
-        pl.BlockSpec((1, bq, dp), _q_idx_kv),
-        pl.BlockSpec((1, bq, _LANES), _q_idx_kv),
-        pl.BlockSpec((1, bq, _LANES), _q_idx_kv),
+        pl.BlockSpec((1, bq, dp), at(_q_idx_kv)),
+        pl.BlockSpec((1, bk, dp), at(lambda i, kk, j: (i, kk, 0))),
+        pl.BlockSpec((1, bk, dp), at(lambda i, kk, j: (i, kk, 0))),
+        pl.BlockSpec((1, bq, dp), at(_q_idx_kv)),
+        pl.BlockSpec((1, bq, _LANES), at(_q_idx_kv)),
+        pl.BlockSpec((1, bq, _LANES), at(_q_idx_kv)),
     ]
     if seg:
         kv_specs += [
             pl.BlockSpec((1, bq, _LANES),
-                         lambda i, kk, t: (i // hk,
-                                           _q_idx_kv(i, kk, t)[1], 0)),
-            pl.BlockSpec((1, 8, bk), lambda i, kk, t: (i // hk, 0, kk)),
+                         at(lambda i, kk, t: (
+                             i // hk, _q_idx_kv(i, kk, t)[1], 0))),
+            pl.BlockSpec((1, 8, bk),
+                         at(lambda i, kk, t: (i // hk, 0, kk))),
         ]
-    dk, dv = pl.pallas_call(
+    dk, dv = _launch(
         functools.partial(_dkv_kernel, scale, causal, seg, rate, h, hk,
                           sq, sk, sqp, skp, bq, bk, nq, g),
-        grid=(b * hk, nk, g * nq),
-        in_specs=seed_specs + kv_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, dp), lambda i, kk, t: (i, kk, 0)),
-            pl.BlockSpec((1, bk, dp), lambda i, kk, t: (i, kk, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hk, skp, dp), k.dtype),
-            jax.ShapeDtypeStruct((b * hk, skp, dp), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, dp), jnp.float32),
-            pltpu.VMEM((bk, dp), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret_mode(),
-        name="apex_flash_attention_dkv",
-    )(*(seed_args + args))
+        "apex_flash_attention_dkv", (b * hk, nk, g * nq),
+        seed_specs + kv_specs,
+        [pl.BlockSpec((1, bk, dp), at(lambda i, kk, t: (i, kk, 0))),
+         pl.BlockSpec((1, bk, dp), at(lambda i, kk, t: (i, kk, 0)))],
+        [jax.ShapeDtypeStruct((b * hk, skp, dp), k.dtype),
+         jax.ShapeDtypeStruct((b * hk, skp, dp), v.dtype)],
+        [pltpu.VMEM((bk, dp), jnp.float32),
+         pltpu.VMEM((bk, dp), jnp.float32)],
+        seed_args + args, dkv_steps)
 
     dq = dq.reshape(b, h, sqp, dp)[:, :, :sq, :d]
     dk = dk.reshape(b, hk, skp, dp)[:, :, :sk, :d]

@@ -164,10 +164,14 @@ def test_flash_attention_segment_ids_grads():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_multiblock_tiling(causal):
+def test_flash_attention_multiblock_tiling(causal, monkeypatch):
     """Sequences spanning multiple 128-blocks and a non-divisible
-    length (footprint of the K-tiled online-softmax rework)."""
+    length (footprint of the K-tiled online-softmax rework).  The cap
+    is what makes it multi-block: at the default one, s=320 would run
+    as ONE 384-row block."""
+    monkeypatch.setenv("APEX_TPU_ATTN_BLOCK_CAP", "128")
     q, k, v = qkv(jax.random.key(5), b=1, h=1, s=320, d=64)
+    assert attn._geom(q, k)[6:10] == (128, 128, 384, 384)   # 3 x 3
     o = attn.flash_attention(q, k, v, causal)
     want = attn.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(o), np.asarray(want),
@@ -180,6 +184,168 @@ def test_flash_attention_multiblock_tiling(causal):
     for a, b_ in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-4, atol=5e-5)
+
+
+# causal geometries that between them hold every block class (interior,
+# diagonal, not visited; a padded last block on either side; a kv block
+# no q row reaches): id -> (cap or None for the default 512, b, h, hk,
+# sq, sk, d, dtype, segments, dropout rate)
+_CAUSAL_GEOMETRIES = {
+    "3x3": ("128", 1, 2, 2, 384, 384, 64, jnp.float32, False, 0.0),
+    "4x4_bf16_d128": ("128", 1, 2, 2, 512, 512, 128, jnp.bfloat16,
+                      False, 0.0),
+    # bq 384 / bk 512: 1 x 2 blocks, the second kv block above every row
+    "bq_ne_bk": ("512", 1, 2, 2, 384, 1024, 64, jnp.float32, False, 0.0),
+    # bq 512 / bk 128 (1152 has no 512-divisor): 3 x 9 blocks
+    "bq512_bk128": (None, 1, 1, 1, 1536, 1152, 64, jnp.float32, False,
+                    0.0),
+    # bq 512 / bk 384: 2 x 1 blocks, the forward's one-kv-block body
+    "sq_gt_sk": ("512", 1, 2, 2, 1024, 384, 64, jnp.float32, False, 0.0),
+    "ragged": ("128", 2, 2, 2, 320, 320, 64, jnp.float32, False, 0.0),
+    "ragged_sq_ne_sk_bf16": ("128", 1, 2, 2, 300, 450, 64, jnp.bfloat16,
+                             False, 0.0),
+    "segments": ("128", 2, 2, 2, 384, 384, 64, jnp.float32, True, 0.0),
+    "gqa": ("128", 1, 4, 2, 384, 384, 64, jnp.float32, False, 0.0),
+    "mqa_ragged": ("128", 1, 4, 1, 320, 320, 64, jnp.float32, False, 0.0),
+    "dropout": ("128", 1, 2, 2, 384, 384, 64, jnp.float32, False, 0.2),
+    "gqa_dropout_segments_bf16": ("128", 2, 4, 2, 320, 320, 64,
+                                  jnp.bfloat16, True, 0.1),
+    # larger blocks: 256 (3 x 3; 2 x 4; ragged) and the default 512
+    "3x3_at_256": ("256", 1, 2, 2, 768, 768, 64, jnp.float32, False, 0.0),
+    "2x2_at_512_bf16_d128": ("512", 1, 1, 1, 1024, 1024, 128,
+                             jnp.bfloat16, False, 0.0),
+    "sq_lt_sk_at_256": ("256", 1, 2, 2, 512, 1024, 64, jnp.float32,
+                        False, 0.0),
+    "ragged_gqa_dropout_segments_at_256": ("256", 2, 4, 2, 700, 700, 64,
+                                           jnp.float32, True, 0.1),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(_CAUSAL_GEOMETRIES))
+def test_causal_block_classes_match_ref(geometry, monkeypatch):
+    """Causal forward and all three gradients against the oracle where
+    the grid holds every class of block — interior, diagonal, never
+    visited — with padding, segments, grouped heads and dropout (whose
+    mask hashes the TRUE block position, not the flattened grid's
+    step)."""
+    from apex_tpu.ops import _dispatch
+
+    cap, b, h, hk, sq, sk, d, dtype, seg, rate = \
+        _CAUSAL_GEOMETRIES[geometry]
+    monkeypatch.setattr(_dispatch, "_ATTN_CAPS", {})
+    if cap is None:
+        monkeypatch.delenv("APEX_TPU_ATTN_BLOCK_CAP", raising=False)
+    else:
+        monkeypatch.setenv("APEX_TPU_ATTN_BLOCK_CAP", cap)
+    ks = jax.random.split(jax.random.key(30), 3)
+    q = jax.random.normal(ks[0], (b, h, sq, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (b, hk, sk, d)).astype(dtype)
+    v = jax.random.normal(ks[2], (b, hk, sk, d)).astype(dtype)
+    bq, bk = attn._geom(q, k)[6:8]
+    plan = attn.causal_block_plan(sq, sk, bq, bk)
+    assert plan.nq * plan.nk > 1 and plan.diagonal > 0
+    if geometry not in ("bq_ne_bk", "sq_gt_sk"):
+        assert plan.interior > 0 and plan.not_visited > 0
+
+    kw, ref_kw = dict(causal=True), dict(causal=True)
+    if rate:
+        kw.update(dropout_rate=rate, dropout_seed=jnp.int32(77))
+        ref_kw.update(kw)
+    if seg:     # three uneven segments, boundaries inside blocks
+        ids = jnp.asarray(np.repeat(
+            [1, 2, 3], [sq // 4, sq // 2, sq - sq // 4 - sq // 2])[None],
+            jnp.int32) * jnp.ones((b, 1), jnp.int32)
+        same = ids[:, None, :, None] == ids[:, None, None, :]
+        kw.update(segment_ids=(ids, ids))
+        ref_kw.update(mask=jnp.where(same, 0.0, attn._NEG))
+
+    def loss(f, kwargs):
+        return lambda *a: jnp.sum(
+            f(*a, **kwargs).astype(jnp.float32) ** 2)
+
+    got = (attn.flash_attention(q, k, v, **kw),) + jax.grad(
+        loss(attn.flash_attention, kw), argnums=(0, 1, 2))(q, k, v)
+    want = (attn.attention_ref(q, k, v, **ref_kw),) + jax.grad(
+        loss(attn.attention_ref, ref_kw), argnums=(0, 1, 2))(q, k, v)
+    assert got[2].shape == (b, hk, sk, d) == got[3].shape
+    for n, (g, w) in enumerate(zip(got, want)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if dtype == jnp.bfloat16:
+            # the file's bf16 tolerance, in units of the oracle's range
+            unit = max(float(np.abs(w).max()), 1.0)
+            tol = dict(rtol=2e-2, atol=2e-2 * unit)
+        else:
+            tol = dict(rtol=2e-5, atol=2e-5) if n == 0 else \
+                dict(rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(g, w, **tol)
+
+
+def test_causal_block_plan_hand_worked():
+    """The plan is the one place the causal grids, kernel_bench and the
+    tests read: hand-worked counts, both orders, padding, coverage."""
+    plan = attn.causal_block_plan(4096, 4096, 512, 512)
+    assert (plan.nq, plan.nk) == (8, 8)
+    assert (plan.interior, plan.diagonal, plan.not_visited) == (28, 8, 28)
+    assert plan.q_major[:4] == ((0, 0, False), (1, 0, True),
+                                (1, 1, False), (2, 0, True))
+    assert plan.q_major[-1] == (7, 7, False) and len(plan.q_major) == 36
+    assert plan.kv_major[:9] == tuple(
+        (j, 0, j > 0) for j in range(8)) + ((1, 1, False),)
+    assert plan.kv_major[-1] == (7, 7, False)
+    assert sorted(plan.kv_major) == sorted(plan.q_major)
+
+    # bq 384 / bk 512 over sq 384 / sk 1024: one q block; kv block 1
+    # lies above every row: never visited forward, one masked visit in
+    # the kv-major order (its dk/dv must be written)
+    plan = attn.causal_block_plan(384, 1024, 384, 512)
+    assert (plan.nq, plan.nk) == (1, 2)
+    assert plan.q_major == ((0, 0, False),)
+    assert plan.kv_major == ((0, 0, False), (0, 1, False))
+    assert (plan.interior, plan.diagonal, plan.not_visited) == (0, 1, 1)
+
+    # bq 256 / bk 128 over 512 x 512: the diagonal crosses two kv
+    # blocks of every q block
+    plan = attn.causal_block_plan(512, 512, 256, 128)
+    assert plan.q_major == ((0, 0, False), (0, 1, False),
+                            (1, 0, True), (1, 1, True),
+                            (1, 2, False), (1, 3, False))
+    assert (plan.interior, plan.diagonal, plan.not_visited) == (2, 4, 2)
+
+    # s 320 in 128-blocks: the last q block holds padded rows (and the
+    # last kv block padded columns), so none of its blocks is interior
+    plan = attn.causal_block_plan(320, 320, 128, 128)
+    assert plan.q_major == ((0, 0, False), (1, 0, True), (1, 1, False),
+                            (2, 0, False), (2, 1, False), (2, 2, False))
+    # sk 200 in 128-blocks under sq 384: kv block 1 is below the
+    # diagonal for q block 2 and still not interior (56 padded columns)
+    assert attn.causal_block_plan(384, 200, 128, 128).q_major[-1] == \
+        (2, 1, False)
+
+    # every (row, column) with column <= row lies in exactly one visited
+    # block; an interior block holds no other kind of position
+    for sq, sk, bq, bk in [(4096, 4096, 512, 512), (384, 1024, 384, 512),
+                           (512, 512, 256, 128), (300, 450, 128, 128),
+                           (1536, 1152, 512, 128), (1024, 384, 512, 384)]:
+        plan = attn.causal_block_plan(sq, sk, bq, bk)
+        seen = np.zeros((sq, sk), np.int32)
+        for j, kk, interior in plan.q_major:
+            r = slice(j * bq, min((j + 1) * bq, sq))
+            c = slice(kk * bk, min((kk + 1) * bk, sk))
+            seen[r, c] += 1
+            if interior:
+                assert (kk + 1) * bk <= sk and (j + 1) * bq <= sq
+                assert np.tril(np.ones((sq, sk), bool))[r, c].all()
+        counts = np.tril(seen)
+        assert (counts[np.tril(np.ones((sq, sk), bool))] == 1).all()
+        assert plan.interior + plan.diagonal + plan.not_visited == \
+            plan.nq * plan.nk
+        # the kv-major order visits the same blocks, plus one masked
+        # visit for a kv block no row reaches
+        extra = set(plan.kv_major) - set(plan.q_major)
+        assert set(plan.q_major) <= set(plan.kv_major)
+        assert all(j == plan.nq - 1 and not interior and kk * bk >= sq
+                   for j, kk, interior in extra)
+        assert sorted({p[1] for p in plan.kv_major}) == list(range(plan.nk))
 
 
 @pytest.mark.parametrize("causal", [False, True])

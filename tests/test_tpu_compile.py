@@ -100,8 +100,10 @@ def test_layer_norm_fwd_bwd_bert_large_width(one_chip):
 
 
 @pytest.mark.parametrize("shape,causal", [((8, 16, 512, 64), False),
-                                          ((2, 16, 2048, 64), True)],
-                         ids=["bert_s512", "causal_s2048"])
+                                          ((2, 16, 2048, 64), True),
+                                          ((1, 16, 4096, 128), True)],
+                         ids=["bert_s512", "causal_s2048",
+                              "looped_causal_s4096"])
 def test_flash_attention_fwd_bwd(one_chip, shape, causal):
     from apex_tpu.ops.attention import flash_attention
     f = functools.partial(flash_attention, causal=causal)

@@ -228,20 +228,33 @@ def main():
     print(json.dumps({"noise_floor_pct": noise_pct,
                       "backend": backend}), flush=True)
 
-    # flash attention: bench shapes (BERT-L-ish and long-context)
+    def causal_blocks(q, k):
+        """The causal grid's three block counts at this shape's
+        geometry (ops/attention.py:causal_block_plan): visited blocks
+        wholly under the diagonal, visited blocks the diagonal (or
+        padding) crosses, and blocks that cost no grid step."""
+        sq, sk, bq, bk = (attn._geom(q, k)[i] for i in (2, 3, 6, 7))
+        plan = attn.causal_block_plan(sq, sk, bq, bk)
+        return {"interior": plan.interior, "diagonal": plan.diagonal,
+                "not_visited": plan.not_visited}
+
+    # flash attention: bench shapes (BERT-L-ish, long-context, and the
+    # looped decoder cell's b1 x 16 heads x s4096 x d128)
     for (b, h, s, d) in [(8, 16, 512, 64), (4, 16, 2048, 128),
-                         (1, 8, 8192, 128)]:
+                         (1, 16, 4096, 128), (1, 8, 8192, 128)]:
         ks = jax.random.split(key, 3)
         q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
                    for kk in ks)
         f_k = functools.partial(attn.flash_attention, causal=True)
-        # at s=8192 the unfused oracle materializes 8192^2 score/softmax
-        # buffers (bench.py skips it there too): kernel-only timing
+        # from s=4096 the unfused oracle materializes s^2 score/softmax
+        # buffers per head (bench.py skips it there too): kernel-only
         f_o = (functools.partial(attn.attention_ref, causal=True)
-               if s < 8192 else None)
+               if s < 4096 else None)
         for grad in (False, True):
-            rows.append(bench_pair("flash_attention", f"b{b}h{h}s{s}d{d}",
-                                   "bf16", f_k, f_o, q, k, v, grad=grad))
+            row = bench_pair("flash_attention", f"b{b}h{h}s{s}d{d}",
+                             "bf16", f_k, f_o, q, k, v, grad=grad)
+            row["causal_blocks"] = causal_blocks(q, k)
+            rows.append(row)
 
     # f32 precision class: HIGHEST-precision multi-pass dots — its own
     # dispatch family (attention_f32) so a loss here cannot disable the
@@ -255,6 +268,7 @@ def main():
         functools.partial(attn.flash_attention, causal=True),
         functools.partial(attn.attention_ref, causal=True),
         qf, kf, vf, grad=True))
+    rows[-1]["causal_blocks"] = causal_blocks(qf, kf)
 
     # layer norm
     for (r, hdim) in [(8192, 1024), (4096, 4096)]:
