@@ -13,6 +13,8 @@ from apex_tpu.models.bert import (BertLayer, BertModel, bert_base,
                                   bert_large)
 from apex_tpu.models.looped import (LoopedDecoder, LoopedDecoderLayer,
                                     LoopedPass)
+from apex_tpu.models.sparse_moe import (SparseMoEDecoder,
+                                        SparseMoEDecoderLayer)
 
 __all__ = [
     "BasicBlock", "Bottleneck", "ResNet",
@@ -20,4 +22,5 @@ __all__ = [
     "GPTLayer", "GPTModel", "GPTStage",
     "BertLayer", "BertModel", "bert_base", "bert_large",
     "LoopedDecoder", "LoopedDecoderLayer", "LoopedPass",
+    "SparseMoEDecoder", "SparseMoEDecoderLayer",
 ]

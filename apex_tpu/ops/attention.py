@@ -196,9 +196,11 @@ def _seg_inputs(segment_ids, b, sqp, skp):
 
 
 def _mask_for_block(j, kk, bq, bk, sq, sk, sqp, skp, causal,
-                    qs_tile, ks_row, *, mask_rows):
+                    qs_tile, ks_row, sel_tile=None, *, mask_rows):
     """Validity mask (BQ, BK) for one score block, or None if nothing
-    masks.  qs_tile: (BQ, 128) or None; ks_row: (1, BK) or None."""
+    masks.  qs_tile: (BQ, 128) or None; ks_row: (1, BK) or None;
+    sel_tile: (BQ, BK) int8 of the per-query key selection (non-zero =
+    the query attends the key) or None."""
     ok = None
 
     def _and(a, b):
@@ -216,6 +218,8 @@ def _mask_for_block(j, kk, bq, bk, sq, sk, sqp, skp, causal,
         reps = bk // _LANES
         qseg = jnp.tile(qs_tile, (1, reps)) if reps > 1 else qs_tile
         ok = _and(ok, qseg[:, :bk] == ks_row)
+    if sel_tile is not None:
+        ok = _and(ok, sel_tile.astype(jnp.int32) != 0)
     return ok
 
 
@@ -329,7 +333,7 @@ def _grid_position(flat, refs):
 # (causal: grid (B*H, visited blocks), a q block's kv blocks side by side)
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(scale, causal, seg, need_lse, rate, sq, sk, sqp, skp,
+def _fwd_kernel(scale, causal, seg, sel, need_lse, rate, sq, sk, sqp, skp,
                 bq, bk, nk, *refs):
     # causal: this step's block comes from the plan's q-major list
     i, j, kk, refs = _grid_position(causal, refs)
@@ -337,8 +341,7 @@ def _fwd_kernel(scale, causal, seg, need_lse, rate, sq, sk, sqp, skp,
     refs = refs[3:]
     if rate > 0.0:
         seed_ref, refs = refs[0], refs[1:]
-    qs_ref, ks_ref = (refs[:2] if seg else (None, None))
-    rest = refs[2:] if seg else refs
+    qs_ref, ks_ref, sel_ref, rest = _mask_refs(seg, sel, refs)
     if need_lse:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -362,7 +365,8 @@ def _fwd_kernel(scale, causal, seg, need_lse, rate, sq, sk, sqp, skp,
         ok = _mask_for_block(
             j, kk, bq, bk, sq, sk, sqp, skp, causal,
             qs_ref[0] if seg else None,
-            ks_ref[0, :1, :] if seg else None, mask_rows=False)
+            ks_ref[0, :1, :] if seg else None,
+            sel_ref[0] if sel else None, mask_rows=False)
         if ok is not None:
             s = jnp.where(ok, s, _NEG)
         m_prev = m_scr[:, :1]
@@ -400,7 +404,7 @@ def _fwd_kernel(scale, causal, seg, need_lse, rate, sq, sk, sqp, skp,
                                    _NEG)
 
 
-def _fwd_kernel_1kv(scale, causal, seg, need_lse, rate, sq, sk, sqp,
+def _fwd_kernel_1kv(scale, causal, seg, sel, need_lse, rate, sq, sk, sqp,
                     skp, bq, bk, *refs):
     """Forward body for the nk == 1 geometry (the whole padded KV range
     fits one block, i.e. sk <= the sequence-block cap — the common
@@ -418,8 +422,7 @@ def _fwd_kernel_1kv(scale, causal, seg, need_lse, rate, sq, sk, sqp,
     refs = refs[3:]
     if rate > 0.0:
         seed_ref, refs = refs[0], refs[1:]
-    qs_ref, ks_ref = (refs[:2] if seg else (None, None))
-    rest = refs[2:] if seg else refs
+    qs_ref, ks_ref, sel_ref, rest = _mask_refs(seg, sel, refs)
     if need_lse:
         o_ref, lse_ref = rest
     else:
@@ -432,7 +435,8 @@ def _fwd_kernel_1kv(scale, causal, seg, need_lse, rate, sq, sk, sqp,
     ok = _mask_for_block(
         j, 0, bq, bk, sq, sk, sqp, skp, causal,
         qs_ref[0] if seg else None,
-        ks_ref[0, :1, :] if seg else None, mask_rows=False)
+        ks_ref[0, :1, :] if seg else None,
+        sel_ref[0] if sel else None, mask_rows=False)
     if ok is not None:
         s = jnp.where(ok, s, _NEG)
     m = jnp.max(s, axis=1, keepdims=True)
@@ -515,8 +519,17 @@ def _dropout_keep_block(seed, i_flat, j, kk, bq, bk, rate):
     return _keep_mask(seed, i_flat, row_g, col_g, rate)
 
 
+def _sel_input(key_mask, sqp, skp):
+    """The per-query key selection (B, Sq, Sk), any dtype, non-zero =
+    attend, as the int8 array the kernels read a (BQ, BK) tile of per
+    score block; padding selects nothing."""
+    m = (key_mask != 0).astype(jnp.int8)
+    return jnp.pad(m, ((0, 0), (0, sqp - m.shape[1]),
+                       (0, skp - m.shape[2])))
+
+
 def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
-                rate=0.0, seed=None):
+                rate=0.0, seed=None, key_mask=None):
     b, h, sq, sk, d, dp, bq, bk, sqp, skp = _geom(q, k)
     nq, nk = sqp // bq, skp // bk
     hk = k.shape[1]
@@ -553,6 +566,11 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
                                               _kv_idx(i, j, kk)[1]))),
         ]
         args += [qs, ks]
+    sel = key_mask is not None
+    if sel:     # one tile per score block, the same for every head
+        in_specs.append(pl.BlockSpec(
+            (1, bq, bk), at(lambda i, j, kk: (i // h, j, kk))))
+        args.append(_sel_input(key_mask, sqp, skp))
 
     out_specs = [pl.BlockSpec((1, bq, dp),
                               at(lambda i, j, kk: (i, j, 0)))]
@@ -564,11 +582,11 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
             jax.ShapeDtypeStruct((b * h, sqp, _LANES), jnp.float32))
     if nk == 1:
         kernel = functools.partial(_fwd_kernel_1kv, scale, causal, seg,
-                                   need_lse, rate, sq, sk, sqp, skp,
-                                   bq, bk)
+                                   sel, need_lse, rate, sq, sk, sqp,
+                                   skp, bq, bk)
         scratch = []
     else:
-        kernel = functools.partial(_fwd_kernel, scale, causal, seg,
+        kernel = functools.partial(_fwd_kernel, scale, causal, seg, sel,
                                    need_lse, rate, sq, sk, sqp, skp,
                                    bq, bk, nk)
         scratch = [
@@ -586,31 +604,41 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
 # backward kernels: dq over the KV grid, dk/dv over the Q grid
 # ---------------------------------------------------------------------------
 
+def _mask_refs(seg, sel, refs):
+    """(qs_ref, ks_ref, sel_ref, the refs that follow): the segment-id
+    pair and the key-selection tile, each there only when its flag is
+    set."""
+    qs_ref = ks_ref = sel_ref = None
+    if seg:
+        qs_ref, ks_ref, refs = refs[0], refs[1], refs[2:]
+    if sel:
+        sel_ref, refs = refs[0], refs[1:]
+    return qs_ref, ks_ref, sel_ref, refs
+
+
 def _recompute_p(scale, causal, seg, sq, sk, sqp, skp, bq, bk, j, kk,
-                 q_ref, k_ref, qs_ref, ks_ref, lse_ref):
+                 q_ref, k_ref, qs_ref, ks_ref, lse_ref, sel_ref=None):
     s = _dot(q_ref[0], k_ref[0], ((1,), (1,))) * scale
     p = jnp.exp(s - lse_ref[0, :, :1])
     ok = _mask_for_block(
         j, kk, bq, bk, sq, sk, sqp, skp, causal,
         qs_ref[0] if seg else None,
-        ks_ref[0, :1, :] if seg else None, mask_rows=True)
+        ks_ref[0, :1, :] if seg else None,
+        None if sel_ref is None else sel_ref[0], mask_rows=True)
     if ok is not None:
         p = jnp.where(ok, p, 0.0)
     return p
 
 
-def _dq_kernel(scale, causal, seg, rate, sq, sk, sqp, skp, bq, bk, nk,
-               *refs):
+def _dq_kernel(scale, causal, seg, sel, rate, sq, sk, sqp, skp, bq, bk,
+               nk, *refs):
     # causal: this step's block comes from the plan's q-major list
     i, j, kk, refs = _grid_position(causal, refs)
     if rate > 0.0:
         seed_ref, refs = refs[0], refs[1:]
-    if seg:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, qs_ref, ks_ref, \
-            dq_ref, dq_scr = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_scr = refs
-        qs_ref = ks_ref = None
+    q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref = refs[:6]
+    qs_ref, ks_ref, sel_ref, refs = _mask_refs(seg, sel, refs[6:])
+    dq_ref, dq_scr = refs
 
     @pl.when(kk == 0)
     def _init():
@@ -621,7 +649,8 @@ def _dq_kernel(scale, causal, seg, rate, sq, sk, sqp, skp, bq, bk, nk,
 
     def _body():
         p = _recompute_p(scale, causal, seg, sq, sk, sqp, skp, bq, bk,
-                         j, kk, q_ref, k_ref, qs_ref, ks_ref, lse_ref)
+                         j, kk, q_ref, k_ref, qs_ref, ks_ref, lse_ref,
+                         sel_ref)
         dp = _dot(do_ref[0], v_ref[0], ((1,), (1,)))
         if rate > 0.0:
             # dP = mask . (dO V^T)/keep; the rowsum correction stays di
@@ -643,8 +672,8 @@ def _dq_kernel(scale, causal, seg, rate, sq, sk, sqp, skp, bq, bk, nk,
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(scale, causal, seg, rate, h, hk, sq, sk, sqp, skp, bq,
-                bk, nq, g, *refs):
+def _dkv_kernel(scale, causal, seg, sel, rate, h, hk, sq, sk, sqp, skp,
+                bq, bk, nq, g, *refs):
     """dk/dv accumulation.  The sequential axis ``t`` covers the whole
     q-head GROUP sharing this kv head times the q blocks (t = qh*NQ+j,
     grouped-query attention): every q head's contribution lands in the
@@ -655,13 +684,9 @@ def _dkv_kernel(scale, causal, seg, rate, h, hk, sq, sk, sqp, skp, bq,
     i, kk, t, refs = _grid_position(causal, refs)
     if rate > 0.0:
         seed_ref, refs = refs[0], refs[1:]
-    if seg:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, qs_ref, ks_ref, \
-            dk_ref, dv_ref, dk_scr, dv_scr = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, \
-            dk_ref, dv_ref, dk_scr, dv_scr = refs
-        qs_ref = ks_ref = None
+    q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref = refs[:6]
+    qs_ref, ks_ref, sel_ref, refs = _mask_refs(seg, sel, refs[6:])
+    dk_ref, dv_ref, dk_scr, dv_scr = refs
     j = t % nq if g > 1 else t
 
     # causal: first Q block whose rows reach this KV block (same for
@@ -676,7 +701,8 @@ def _dkv_kernel(scale, causal, seg, rate, h, hk, sq, sk, sqp, skp, bq,
 
     def _body():
         p = _recompute_p(scale, causal, seg, sq, sk, sqp, skp, bq, bk,
-                         j, kk, q_ref, k_ref, qs_ref, ks_ref, lse_ref)
+                         j, kk, q_ref, k_ref, qs_ref, ks_ref, lse_ref,
+                         sel_ref)
         if rate > 0.0:
             # the mask was drawn per FLAT Q row in fwd/dq; this grid
             # runs over kv heads, so recover that row from (i, t)
@@ -709,7 +735,7 @@ def _dkv_kernel(scale, causal, seg, rate, h, hk, sq, sk, sqp, skp, bq,
 
 
 def _bwd_pallas(q, k, v, o, lse, do, scale, causal, segment_ids,
-                rate=0.0, seed=None):
+                rate=0.0, seed=None, key_mask=None):
     b, h, sq, sk, d, dp, bq, bk, sqp, skp = _geom(q, k)
     nq, nk = sqp // bq, skp // bk
     hk = k.shape[1]
@@ -780,12 +806,18 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, segment_ids,
                                               _kv_idx(i, j, kk)[1]))),
         ]
         args += [qs, ks]
+    sel = key_mask is not None
+    sel_specs = []
+    if sel:
+        sel_specs = [pl.BlockSpec(
+            (1, bq, bk), at(lambda i, j, kk: (i // h, j, kk)))]
+        args.append(_sel_input(key_mask, sqp, skp))
 
     dq = _launch(
-        functools.partial(_dq_kernel, scale, causal, seg, rate, sq, sk,
-                          sqp, skp, bq, bk, nk),
+        functools.partial(_dq_kernel, scale, causal, seg, sel, rate, sq,
+                          sk, sqp, skp, bq, bk, nk),
         "apex_flash_attention_dq", (b * h, nq, nk),
-        seed_specs + base_specs + seg_specs,
+        seed_specs + base_specs + seg_specs + sel_specs,
         [pl.BlockSpec((1, bq, dp), at(lambda i, j, kk: (i, j, 0)))],
         [jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype)],
         [pltpu.VMEM((bq, dp), jnp.float32)],
@@ -808,9 +840,12 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, segment_ids,
             pl.BlockSpec((1, 8, bk),
                          at(lambda i, kk, t: (i // hk, 0, kk))),
         ]
+    if sel:
+        kv_specs.append(pl.BlockSpec(
+            (1, bq, bk), at(lambda i, kk, t: (i // hk, t % nq, kk))))
     dk, dv = _launch(
-        functools.partial(_dkv_kernel, scale, causal, seg, rate, h, hk,
-                          sq, sk, sqp, skp, bq, bk, nq, g),
+        functools.partial(_dkv_kernel, scale, causal, seg, sel, rate, h,
+                          hk, sq, sk, sqp, skp, bq, bk, nq, g),
         "apex_flash_attention_dkv", (b * hk, nk, g * nq),
         seed_specs + kv_specs,
         [pl.BlockSpec((1, bk, dp), at(lambda i, kk, t: (i, kk, 0))),
@@ -860,6 +895,35 @@ def _flash_bwd(causal, scale, rate, res, do):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_selected(q, k, v, key_mask, causal, scale):
+    """The same kernels under a per-query key selection -> (o, lse):
+    ``lse`` (B, H, Sq) float32 is each row's log-sum-exp over the keys
+    it attends (-1e30 for a row that attends none), what the kernels
+    save for their backward pass; no gradient flows through it."""
+    return _flash_selected_fwd(q, k, v, key_mask, causal, scale)[0]
+
+
+def _flash_selected_fwd(q, k, v, key_mask, causal, scale):
+    sc = scale if scale is not None else _default_scale(q.shape[-1])
+    o, lse = _fwd_pallas(q, k, v, sc, causal, None, key_mask=key_mask)
+    lse = lse[:, :, 0]
+    b, h, sq = q.shape[:3]
+    return ((o, lse.reshape(b, h, -1)[:, :, :sq]),
+            (q, k, v, key_mask, o, lse))
+
+
+def _flash_selected_bwd(causal, scale, res, cts):
+    q, k, v, key_mask, o, lse = res
+    sc = scale if scale is not None else _default_scale(q.shape[-1])
+    dq, dk, dv = _bwd_pallas(q, k, v, o, lse, cts[0], sc, causal, None,
+                             key_mask=key_mask)
+    return dq, dk, dv, None
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
 
 
 def dropout_seed_from_key(key):
@@ -930,7 +994,9 @@ def packed_segment_ids(segment_ids, xp=jnp):
 def flash_attention(q, k, v, causal=False, scale=None,
                     segment_ids: Optional[Tuple[jax.Array,
                                                 jax.Array]] = None,
-                    dropout_rate: float = 0.0, dropout_seed=None):
+                    dropout_rate: float = 0.0, dropout_seed=None,
+                    key_mask: Optional[jax.Array] = None,
+                    return_lse: bool = False):
     """Fused scaled-dot-product attention, (B, H, S, D) layout.
 
     Replaces the reference's fast_multihead_attn softmax-chain kernels
@@ -954,6 +1020,19 @@ def flash_attention(q, k, v, causal=False, scale=None,
     recomputed inside every kernel — no mask tensor is ever stored —
     and the backward drops the same elements.  Callers own the
     train/eval switch: pass rate 0 (or no seed) when not training.
+
+    key_mask: optional (B, Sq, Sk) per-query key selection, non-zero
+    where query row t attends key s, the same for every head (learned
+    sparse attention: ``ops/sparse_index.py`` makes one).  The softmax
+    and both backward kernels run over the selected keys only — a tile
+    of the mask is read per score block beside ``causal``, which still
+    prunes the blocks above the diagonal; a block whose tile selects
+    nothing is computed all the same.  A row that selects nothing gives
+    zeros.  Not combined with ``segment_ids`` or dropout, and always on
+    the kernels (a dense form of a selection has no use at the lengths
+    a selection is for).  ``return_lse`` (with ``key_mask``) returns
+    ``(o, lse)``, ``lse`` (B, H, Sq) float32 the rows' log-sum-exp over
+    their selected keys, constant under differentiation.
     """
     h, hk = q.shape[1], k.shape[1]
     if h % hk or v.shape[1] != hk:
@@ -977,6 +1056,20 @@ def flash_attention(q, k, v, causal=False, scale=None,
         dt = jnp.promote_types(jnp.promote_types(q.dtype, k.dtype),
                                v.dtype)
         q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
+    if key_mask is not None:
+        if segment_ids is not None or rate > 0.0:
+            raise ValueError(
+                "flash_attention: key_mask is not combined with "
+                "segment_ids or dropout")
+        if key_mask.shape != (q.shape[0], q.shape[2], k.shape[2]):
+            raise ValueError(
+                f"flash_attention: key_mask must be (B, Sq, Sk) = "
+                f"{(q.shape[0], q.shape[2], k.shape[2])}, got "
+                f"{key_mask.shape}")
+        o, lse = _flash_selected(q, k, v, key_mask, causal, scale)
+        return (o, jax.lax.stop_gradient(lse)) if return_lse else o
+    if return_lse:
+        raise ValueError("flash_attention: return_lse goes with key_mask")
     fam = _attn_family(q.dtype)
     if not op_enabled(fam) and not (
             _dispatch.prefs_disabled(fam)

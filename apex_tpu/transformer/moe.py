@@ -23,6 +23,15 @@ Static capacity C = ceil(2 * T * capacity_factor / E) (top-2:
 two assignments per token); overflow tokens are
 dropped by the position-in-expert cumsum mask (standard MoE semantics;
 dropped tokens pass through the residual path of the caller).
+
+``DroplessMoE`` / ``dropless_moe`` (further down) is the other kind of
+expert layer: top-k of a softmax router, no capacity and no dropped
+token, and TOLD WHICH EXPERTS IT HOLDS.  It routes over the router's
+whole width and computes its own experts' part of the result, with
+grouped matrix products (``lax.ragged_dot``) over the assignments
+routed here; the parts of all the holders add up to the whole layer.
+It contains no exchange: on one chip it is the chip's share, and an
+expert-parallel caller sums the parts.
 """
 
 from __future__ import annotations
@@ -34,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu import comm
+from apex_tpu.ops.attention import matmul_precision
 from apex_tpu.transformer.tensor_parallel import mappings
 
 Array = jax.Array
@@ -209,3 +219,186 @@ def moe_ref(x, router, w1, w2, capacity, activation=jax.nn.gelu):
     y = jnp.einsum("tef,efh->teh", h, w2.astype(jnp.float32))
     weight = jnp.sum(combine, axis=-1)                 # (T, E)
     return jnp.einsum("te,teh->th", weight, y).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k experts, a holder's share
+# ---------------------------------------------------------------------------
+
+def route_topk(x, router, top_k: int, norm_topk_prob: bool = True):
+    """x (T, H), router (H, E) -> (gates (T, k) float32, experts (T, k)
+    int32): the ``top_k`` largest of a float32 softmax over ALL E
+    experts, renormalised over the k chosen when ``norm_topk_prob`` —
+    whoever holds them."""
+    with jax.named_scope("apex_moe/router"):
+        logits = jnp.dot(x, router.astype(x.dtype),
+                         preferred_element_type=jnp.float32,
+                         precision=matmul_precision(x.dtype))
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, experts = jax.lax.top_k(jax.lax.stop_gradient(probs), top_k)
+        # the chosen probabilities by a one-hot select, not by top_k's
+        # own values: those transpose to a scatter
+        chosen = experts[..., None] == jnp.arange(probs.shape[-1])
+        gates = jnp.sum(jnp.where(chosen, probs[:, None, :], 0.0), axis=-1)
+        if norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, experts.astype(jnp.int32)
+
+
+def _take(x, idx):
+    return jnp.take(x, idx, axis=0, mode="clip")
+
+
+# Both moves between token order and expert order are GATHERS in both
+# directions (a row is looked up where it went, never scattered to
+# where it goes): XLA's scatter-add runs elements one by one on the TPU
+# (PERF.md section 6, PR 26).
+
+@jax.custom_vjp
+def _dispatch(x, source, slot, here):
+    """Rows of x (T, H) in expert order: out[m] = x[source[m]]."""
+    return _take(x, source)
+
+
+def _dispatch_fwd(x, source, slot, here):
+    return _take(x, source), (slot, here)
+
+
+def _dispatch_bwd(res, g):
+    slot, here = res
+    picked = jnp.where(here[..., None], _take(g, slot), 0)
+    dx = jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype)
+    return dx, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, gates, slot, here, order):
+    """y[t] = sum over the assignments (t, c) held here of
+    gates[t, c] * ys[slot[t, c]], float32 sum, ys' dtype."""
+    return _combine_fwd(ys, gates, slot, here, order)[0]
+
+
+def _combine_fwd(ys, gates, slot, here, order):
+    picked = jnp.where(here[..., None], _take(ys, slot), 0)
+    y = jnp.sum(gates[..., None] * picked.astype(jnp.float32), axis=1)
+    return y.astype(ys.dtype), (picked, gates, here, order, ys.shape[0])
+
+
+def _combine_bwd(res, dy):
+    picked, gates, here, order, rows = res
+    k = gates.shape[1]
+    dgates = jnp.where(here, jnp.sum(
+        dy.astype(jnp.float32)[:, None] * picked.astype(jnp.float32),
+        axis=-1), 0.0)
+    first = order[:rows]                  # the assignment in each row
+    weight = jnp.where(here.reshape(-1)[first],
+                       gates.reshape(-1)[first], 0.0)
+    dys = (weight[:, None] * _take(dy, first // k).astype(jnp.float32))
+    return dys.astype(picked.dtype), dgates, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dropless_moe(x, router, gate_up, down, *, top_k: int,
+                 expert_offset: int = 0, norm_topk_prob: bool = True):
+    """One holder's share of a dropless top-k expert layer.
+
+    x (T, H); router (H, E) over ALL E experts; this holder's experts
+    ``expert_offset .. expert_offset + held``: gate_up (held, H, 2F) =
+    [gate | up], down (held, F, H).  Returns (y (T, H), counts (held,)
+    int32):
+
+        y[t] = sum over e in top_k(t), e held here, of
+               g[t, e] * down_e( silu(gate_e x[t]) * up_e x[t] )
+
+    with g from ``route_topk`` (normalised over all k chosen experts,
+    held here or not), and counts[e] the tokens routed to each held
+    expert.  No token is dropped: the assignments routed here are
+    sorted by expert into a buffer of T * min(k, held) rows (all that
+    can arrive), and the two grouped products run over the rows in use.
+    What the other holders' experts add is left out: the holders' parts
+    sum to the whole layer."""
+    t, h = x.shape
+    held = gate_up.shape[0]
+    gates, experts = route_topk(x, router, top_k, norm_topk_prob)
+    with jax.named_scope("apex_moe/dispatch"):
+        local = experts - expert_offset
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held).reshape(-1)
+        counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
+                         axis=0, dtype=jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        rows = t * min(top_k, held)
+        slot = jnp.minimum(jnp.argsort(order).astype(jnp.int32),
+                           rows - 1).reshape(t, top_k)
+        xs = _dispatch(x, order[:rows] // top_k, slot, here)
+    with jax.named_scope("apex_moe/experts"):
+        prec = matmul_precision(x.dtype)
+        mid = jax.lax.ragged_dot(xs, gate_up.astype(x.dtype), counts,
+                                 precision=prec)
+        with jax.named_scope("apex_swiglu"):
+            gate, up = jnp.split(mid.astype(jnp.float32), 2, axis=-1)
+            act = (jax.nn.silu(gate) * up).astype(x.dtype)
+        ys = jax.lax.ragged_dot(act, down.astype(x.dtype), counts,
+                                precision=prec)
+    with jax.named_scope("apex_moe/combine"):
+        y = _combine(ys, gates, slot, here, order)
+    return y, counts
+
+
+def dropless_moe_ref(x, router, gate_up, down, *, top_k: int,
+                     expert_offset: int = 0, norm_topk_prob: bool = True):
+    """Dense oracle of ``dropless_moe``: every held expert applied to
+    every token, weighted by its gate where chosen."""
+    gates, experts = route_topk(x, router, top_k, norm_topk_prob)
+    held = gate_up.shape[0]
+    xf = x.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    y = jnp.zeros(x.shape, jnp.float32)
+    counts = []
+    for e in range(held):
+        chosen = experts == expert_offset + e
+        g = jnp.sum(jnp.where(chosen, gates, 0.0), axis=-1)
+        gate, up = jnp.split(jnp.dot(xf, gate_up[e].astype(jnp.float32),
+                                     precision=hi), 2, axis=-1)
+        out = jnp.dot(jax.nn.silu(gate) * up, down[e].astype(jnp.float32),
+                      precision=hi)
+        y = y + g[:, None] * out
+        counts.append(jnp.sum(chosen))
+    return y.astype(x.dtype), jnp.stack(counts).astype(jnp.int32)
+
+
+class DroplessMoE(nn.Module):
+    """``dropless_moe`` with its parameters: ``router`` (H, num_experts),
+    ``gate_up`` (experts_held, H, 2 * ffn_hidden_size), ``down``
+    (experts_held, ffn_hidden_size, H), float32, normal(0.02).
+    ``__call__(x (T, H)) -> (y, counts)``."""
+    hidden_size: int
+    ffn_hidden_size: int
+    num_experts: int                  # the router's width
+    experts_held: int
+    top_k: int
+    expert_offset: int = 0
+    norm_topk_prob: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        h, f = self.hidden_size, self.ffn_hidden_size
+        if not 0 <= self.expert_offset <= self.num_experts - self.experts_held:
+            raise ValueError(
+                f"experts {self.expert_offset}..+{self.experts_held} are "
+                f"not among the router's {self.num_experts}")
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (h, self.num_experts),
+                            jnp.float32)
+        gate_up = self.param("gate_up", init,
+                             (self.experts_held, h, 2 * f), jnp.float32)
+        down = self.param("down", init, (self.experts_held, f, h),
+                          jnp.float32)
+        return dropless_moe(x, router, gate_up, down, top_k=self.top_k,
+                            expert_offset=self.expert_offset,
+                            norm_topk_prob=self.norm_topk_prob)

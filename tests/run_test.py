@@ -41,9 +41,11 @@ SUITES = {
     "run_transformer": ["tests/test_tensor_parallel.py",
                         "tests/test_pipeline_parallel.py",
                         "tests/test_comm.py", "tests/test_moe.py",
+                        "tests/test_sparse_moe_decoder.py",
                         "tests/test_microbatches.py"],
     "run_fp16util": ["tests/test_fp16_rnn_reparam.py"],
     "run_attention": ["tests/test_attention.py",
+                      "tests/test_sparse_index.py",
                       "tests/test_contrib_multihead_attn.py"],
     "run_contrib": ["tests/test_contrib_xentropy_clipgrad.py",
                     "tests/test_contrib_transducer.py",

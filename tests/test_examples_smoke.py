@@ -622,6 +622,14 @@ def test_gpt_looped_tiny(capsys):
     assert "looped decoder L1 x2" in out and "step time" in out
 
 
+def test_gpt_moe_tiny(capsys):
+    _run("examples/gpt/train_moe.py",
+         ["--cpu", "--steps", "3", "--layers", "1"])
+    out = capsys.readouterr().out
+    assert "sparse-attention expert decoder L1" in out
+    assert "4/16 experts top-2" in out and "step time" in out
+
+
 def test_train_tp_converges(capsys):
     _run("examples/simple/train_tp.py", [])
     assert "OK: loss" in capsys.readouterr().out

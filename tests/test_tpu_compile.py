@@ -115,6 +115,64 @@ def test_flash_attention_fwd_bwd(one_chip, shape, causal):
 
 
 # ---------------------------------------------------------------------------
+# learned sparse attention at the expert decoder's cell: b1 s8192, 32
+# query heads over 4 key heads of 128, a 16 x 64 indexer, 2048 keys a query
+# ---------------------------------------------------------------------------
+
+S8K = 8192
+
+
+def test_flash_attention_under_a_key_selection_fwd_bwd(one_chip):
+    from apex_tpu.ops.attention import flash_attention
+
+    def f(q, k, v, m):
+        return flash_attention(q, k, v, causal=True, key_mask=m)
+    specs = (((1, 32, S8K, 128), BF16), ((1, 4, S8K, 128), BF16),
+             ((1, 4, S8K, 128), BF16), ((1, S8K, S8K), jnp.int8))
+    _assert_kernels(_compile(f, one_chip, *specs),
+                    "apex_flash_attention_fwd")
+    grads = jax.grad(lambda *a: jnp.sum(f(*a).astype(F32) ** 2),
+                     argnums=(0, 1, 2))
+    _assert_kernels(_compile(grads, one_chip, *specs),
+                    "apex_flash_attention_dq", "apex_flash_attention_dkv")
+
+
+def test_index_scores_selection_and_objective(one_chip):
+    from apex_tpu.ops import sparse_index as si
+    scorer = (((1, 16, S8K, 64), BF16), ((1, S8K, 64), BF16),
+              ((1, S8K, 16), F32))
+    _assert_kernels(_compile(si.index_scores, one_chip, *scorer),
+                    "apex_index_scores_fwd")
+    _assert_kernels(_compile(_grads(si.index_scores, 3), one_chip, *scorer),
+                    "apex_index_scores_bwd")
+    square = ((1, S8K, S8K), F32)
+    text = _compile(lambda x: si.select_topk(x, 2048), one_chip, square)
+    _assert_kernels(text, "apex_index_select")
+    assert " sort(" not in text
+    loss = jax.value_and_grad(si.index_loss)
+    _assert_kernels(
+        _compile(loss, one_chip, square, ((1, S8K, S8K), jnp.int8),
+                 ((1, 32, S8K, 128), BF16), ((1, 4, S8K, 128), BF16),
+                 ((1, 32, S8K), F32)), "apex_index_loss")
+
+
+def test_dropless_experts_are_grouped_products(one_chip):
+    """One holder's share at the cell's size: 8 of 128 experts, 8192
+    tokens top-8; the two expert products and their transposes compile
+    to the compiler's grouped matmul, and no scatter moves a row."""
+    from apex_tpu.transformer.moe import dropless_moe
+
+    def f(x, router, gate_up, down):
+        return dropless_moe(x, router, gate_up, down, top_k=8)[0]
+    specs = (((S8K, 2048), BF16), ((2048, 128), BF16),
+             ((8, 2048, 1536), BF16), ((8, 768, 2048), BF16))
+    for text in (_compile(f, one_chip, *specs),
+                 _compile(_grads(f, 4), one_chip, *specs)):
+        assert "ragged-dot" in text and "tpu_custom_call" in text
+        assert " scatter(" not in text
+
+
+# ---------------------------------------------------------------------------
 # the flat optimizer updates (XLA sweeps) and the AMP kernel at real
 # bucket sizes
 # ---------------------------------------------------------------------------
