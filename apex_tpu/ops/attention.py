@@ -77,24 +77,28 @@ def _round_up(n: int, m: int) -> int:
 
 
 def _block(s: int, cap: int, explicit: bool = False) -> int:
-    """Block size for a sequence dim: 128-multiple, <= cap, dividing the
-    padded length.  The cap is clamped to the padded length (a short
-    sequence runs as one block rather than falling to 128); a
-    non-dividing cap falls back to 128 — loudly when it was an explicit
-    APEX_TPU_ATTN_BLOCK_CAP, since silently tiling at 128 would be a
-    perf regression the operator asked against."""
+    """Block size for a sequence dim: the largest of 1024 / 512 / 256 /
+    128 that is <= cap and divides the padded length.  The cap is
+    clamped to the padded length first (a short sequence runs as one
+    block, whatever its length), so a cap the length is a multiple of
+    is the block.  A length 1024 does not divide (1536, 2560: GPT-style
+    and packed batches) steps down to 512, not to 128; an explicit
+    APEX_TPU_ATTN_BLOCK_CAP that cannot be honored says so loudly,
+    since the operator asked for another tile."""
     sp = _round_up(s, _LANES)
     if sp:                 # sp==0 (degenerate dim): keep old behavior
         cap = min(cap, sp)
     if sp % cap == 0:
         return cap
+    blk = next(b for b in (1024, 512, 256, _LANES)
+               if b <= cap and sp % b == 0)
     if explicit:
         import warnings
         warnings.warn(
             f"APEX_TPU_ATTN_BLOCK_CAP={cap} does not divide the padded "
-            f"sequence length {sp}; falling back to 128-blocks for "
+            f"sequence length {sp}; falling back to {blk}-blocks for "
             f"this shape")
-    return _LANES
+    return blk
 
 
 def _attn_family(dtype) -> str:
@@ -109,15 +113,16 @@ def _attn_family(dtype) -> str:
             "attention")
 
 
-def _block_cap(dp: int):
-    """(cap, explicit): tunable via APEX_TPU_ATTN_BLOCK_CAP (a
-    128-multiple; tools/kernel_bench.py --sweep-attn sweeps it on
-    hardware), else the measured-best cap the sweep recorded in
-    dispatch_prefs.json for this padded head dim, else a VMEM-safe
-    static default.  The env var is read and interpreted HERE only;
-    ``explicit`` tells _block to complain loudly when the requested cap
-    can't be honored (the measured table is advisory — a non-dividing
-    measured cap quietly falls back to 128-blocks for that shape)."""
+def _block_cap(dp: int, itemsize: int, s: int):
+    """(cap, explicit) for a sequence dim of length ``s``: tunable via
+    APEX_TPU_ATTN_BLOCK_CAP (a 128-multiple; tools/kernel_bench.py
+    --sweep-attn sweeps it on hardware), else the measured-best cap the
+    sweep recorded in dispatch_prefs.json for this padded head dim, else
+    the default ``_default_block_cap`` reads off the call.  The env var
+    is read and interpreted HERE only; ``explicit`` tells _block to
+    complain loudly when the requested cap can't be honored (the
+    measured table is advisory — a non-dividing measured cap quietly
+    steps down to the largest block that divides)."""
     env = os.environ.get("APEX_TPU_ATTN_BLOCK_CAP")
     if env:
         try:
@@ -134,10 +139,31 @@ def _block_cap(dp: int):
         # VMEM-feasibility ceiling: the measured table is advisory and
         # sweep-written (tools/kernel_bench.py only records caps that
         # compiled and won), but a hand-edited value must not push the
-        # double-buffered blocks + f32 score tile past ~16 MiB VMEM —
-        # clamp to the largest cap the sweep grid explores for this dp.
+        # double-buffered blocks + f32 score tile past the kernels'
+        # VMEM limit — clamp to the largest cap the sweep grid explores
+        # for this dp.
         return min(measured, _sweep_cap_ceiling(dp)), False
-    return (512 if dp <= 128 else (256 if dp <= 256 else 128)), False
+    return _default_block_cap(dp, itemsize, s), False
+
+
+def _default_block_cap(dp: int, itemsize: int, s: int) -> int:
+    """The default sequence-block cap, from what the call shows: padded
+    head dim, operand width, sequence length.  Measured on the v5e
+    (PERF.md section 6, PR 32; kernels alone and inside the looped and
+    the sparse-attention cells' steps): at dp 128 with 16-bit operands a
+    1024 tile beats 512 wherever the sequence holds more than one of
+    them — the forward's two lane reductions and dkv's transposed-LHS
+    matmuls are paid per block step, a quarter as often per pair.  What
+    the chip has not measured keeps what it had: a sequence of at most
+    1024 (one tile would be the whole call) and float32 operands (they
+    dot at Precision.HIGHEST, and at 1024 dq does not fit the scoped
+    VMEM on the chip) 512; dp 256 256; wider 128 — the cap shrinks as
+    the head dim grows so that the q/k/v/do blocks, double-buffered,
+    stay beside the f32 score tile."""
+    if dp <= _LANES:
+        long_16bit = itemsize == 2 and _round_up(s, _LANES) > 1024
+        return 1024 if long_16bit else 512
+    return 256 if dp <= 256 else 128
 
 
 def _sweep_cap_ceiling(dp: int) -> int:
@@ -149,18 +175,17 @@ def _sweep_cap_ceiling(dp: int) -> int:
 
 def _geom(q, k):
     """Shared fwd/bwd tiling geometry — the saved lse layout depends on
-    it, so both passes MUST derive it from this one place.
-
-    The sequence-block cap shrinks as the padded head dim grows so the
-    working set (q/k/v/do blocks, double-buffered, plus the f32 score
-    tile and accumulators) stays well inside the ~16 MiB VMEM at any d.
+    it, so both passes MUST derive it from this one place.  bq follows
+    from (head dim, operand width, sq), bk from (head dim, operand
+    width, sk): ``_block_cap`` then ``_block``, 1024-tiles for long
+    16-bit sequences at dp 128 and 512 / 256 / 128 otherwise.
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
     dp = _round_up(d, _LANES)
-    cap, explicit = _block_cap(dp)
-    bq = _block(sq, cap, explicit)
-    bk = _block(sk, cap, explicit)
+    itemsize = jnp.dtype(q.dtype).itemsize
+    bq = _block(sq, *_block_cap(dp, itemsize, sq))
+    bk = _block(sk, *_block_cap(dp, itemsize, sk))
     sqp, skp = _round_up(sq, bq), _round_up(sk, bk)
     return b, h, sq, sk, d, dp, bq, bk, sqp, skp
 

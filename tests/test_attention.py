@@ -218,6 +218,13 @@ _CAUSAL_GEOMETRIES = {
                         False, 0.0),
     "ragged_gqa_dropout_segments_at_256": ("256", 2, 4, 2, 700, 700, 64,
                                            jnp.float32, True, 0.1),
+    # 1024 tiles with sq != sk: 2 x 1 (the forward's one-kv-block body;
+    # dq and dkv on the flattened grid) and 1 x 2 (the second kv block
+    # above every row: one masked visit in dkv)
+    "sq_gt_sk_at_1024": ("1024", 1, 2, 1, 2048, 1024, 64, jnp.bfloat16,
+                         False, 0.0),
+    "sq_lt_sk_at_1024": ("1024", 1, 1, 1, 1024, 2048, 64, jnp.bfloat16,
+                         False, 0.0),
 }
 
 
@@ -244,7 +251,8 @@ def test_causal_block_classes_match_ref(geometry, monkeypatch):
     bq, bk = attn._geom(q, k)[6:8]
     plan = attn.causal_block_plan(sq, sk, bq, bk)
     assert plan.nq * plan.nk > 1 and plan.diagonal > 0
-    if geometry not in ("bq_ne_bk", "sq_gt_sk"):
+    if geometry not in ("bq_ne_bk", "sq_gt_sk", "sq_gt_sk_at_1024",
+                        "sq_lt_sk_at_1024"):
         assert plan.interior > 0 and plan.not_visited > 0
 
     kw, ref_kw = dict(causal=True), dict(causal=True)
@@ -346,6 +354,163 @@ def test_causal_block_plan_hand_worked():
         assert all(j == plan.nq - 1 and not interior and kk * bk >= sq
                    for j, kk, interior in extra)
         assert sorted({p[1] for p in plan.kv_major}) == list(range(plan.nk))
+
+
+# the geometry rule (PR 32), one case per clause: id -> (sq, sk, d,
+# dtype, APEX_TPU_ATTN_BLOCK_CAP or None, prefs table, (bq, bk) wanted)
+_BF16, _F32 = jnp.bfloat16, jnp.float32
+_GEOMETRY_RULE = {
+    # BERT's cell: one block whatever the cap
+    "bert_s512_d64": (512, 512, 64, _BF16, None, {}, (512, 512)),
+    # the looped and the sparse-attention cells: 1024 tiles
+    "looped_s4096_d128": (4096, 4096, 128, _BF16, None, {}, (1024, 1024)),
+    "expert_s8192_d128": (8192, 8192, 128, _BF16, None, {}, (1024, 1024)),
+    "s2048_d64_two_tiles": (2048, 2048, 64, _BF16, None, {}, (1024, 1024)),
+    # not longer than one 1024 tile: what it had
+    "s1024_keeps_512": (1024, 1024, 128, _BF16, None, {}, (512, 512)),
+    # each side by its own length
+    "sq512_sk4096": (512, 4096, 128, _BF16, None, {}, (512, 1024)),
+    # 1024 does not divide: 512, never 128
+    "s1536": (1536, 1536, 128, _BF16, None, {}, (512, 512)),
+    "s2560": (2560, 2560, 128, _BF16, None, {}, (512, 512)),
+    "s3584": (3584, 3584, 64, _BF16, None, {}, (512, 512)),
+    "s1500_pads_to_1536": (1500, 1500, 128, _BF16, None, {}, (512, 512)),
+    # the largest block that divides, where 512 does not either
+    "s1280_at_256": (1280, 1280, 128, _BF16, None, {}, (256, 256)),
+    "s1152_at_128": (1152, 1152, 128, _BF16, None, {}, (128, 128)),
+    # short and ragged: one block of the padded length
+    "s320_one_block": (320, 320, 64, _BF16, None, {}, (384, 384)),
+    # float32 operands dot at HIGHEST: unmeasured at 1024, keep 512
+    "s4096_f32": (4096, 4096, 128, _F32, None, {}, (512, 512)),
+    "s1536_f32": (1536, 1536, 64, _F32, None, {}, (512, 512)),
+    # wider heads as before
+    "dp256": (4096, 4096, 256, _BF16, None, {}, (256, 256)),
+    "dp256_from_d160": (4096, 4096, 160, _BF16, None, {}, (256, 256)),
+    "dp384": (4096, 4096, 384, _BF16, None, {}, (128, 128)),
+    # the variable, then the prefs table, then the default
+    "env_512_wins": (4096, 4096, 128, _BF16, "512", {"128": 256},
+                     (512, 512)),
+    "env_128_wins_f32": (4096, 4096, 128, _F32, "128", {}, (128, 128)),
+    "env_1024_over_s1536": (1536, 1536, 128, _BF16, "1024", {},
+                            (512, 512)),
+    "table_256_wins": (4096, 4096, 128, _BF16, None, {"128": 256},
+                       (256, 256)),
+    "table_512_over_default_1024": (8192, 8192, 128, _BF16, None,
+                                    {"128": 512}, (512, 512)),
+    "table_other_dp": (4096, 4096, 128, _BF16, None, {"256": 128},
+                       (1024, 1024)),
+    "table_clamped_dp256": (4096, 4096, 256, _BF16, None, {"256": 1024},
+                            (512, 512)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GEOMETRY_RULE))
+def test_block_geometry_rule(case, monkeypatch):
+    """``_geom``'s tile from head dim, operand width and sequence
+    length; the variable and the prefs table still win, in that order.
+    Pure: no kernel runs."""
+    import warnings
+
+    from apex_tpu.ops import _dispatch
+
+    sq, sk, d, dtype, env, table, want = _GEOMETRY_RULE[case]
+    monkeypatch.setattr(_dispatch, "_ATTN_CAPS", table)
+    if env is None:
+        monkeypatch.delenv("APEX_TPU_ATTN_BLOCK_CAP", raising=False)
+    else:
+        monkeypatch.setenv("APEX_TPU_ATTN_BLOCK_CAP", env)
+    q = jax.ShapeDtypeStruct((1, 2, sq, d), dtype)
+    k = jax.ShapeDtypeStruct((1, 2, sk, d), dtype)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        b, h, sq_, sk_, d_, dp, bq, bk, sqp, skp = attn._geom(q, k)
+    assert (bq, bk) == want
+    assert (sqp, skp) == (-(-sq // 128) * 128, -(-sk // 128) * 128)
+    assert sqp % bq == 0 and skp % bk == 0
+    # what the rule before PR 32 tiled at 512 is never tiled smaller
+    if env is None and not table and dp == 128:
+        for s_, blk in ((sqp, bq), (skp, bk)):
+            if s_ % 512 == 0:
+                assert blk >= 512
+    # a cap the operator asked for and did not get is said aloud
+    assert bool(caught) == (case == "env_1024_over_s1536")
+
+
+@pytest.mark.parametrize("s,visited,interior,diagonal,not_visited",
+                         [(2048, 3, 1, 2, 1), (4096, 10, 6, 4, 6),
+                          (8192, 36, 28, 8, 28)])
+def test_causal_block_plan_at_1024(s, visited, interior, diagonal,
+                                   not_visited):
+    """The cells' grids at the new tile: looped s4096 visits 10 of 16
+    tiles, 4 of them diagonal; the expert cell's s8192 36 of 64, 8."""
+    plan = attn.causal_block_plan(s, s, 1024, 1024)
+    assert len(plan.q_major) == visited == len(plan.kv_major)
+    assert (plan.interior, plan.diagonal, plan.not_visited) == (
+        interior, diagonal, not_visited)
+    assert all((j == kk) != inner for j, kk, inner in plan.q_major)
+
+
+# numerical cases at the default tile of long 16-bit sequences: id ->
+# (h, hk, s, want block, key selection, segments, dropout rate)
+_TILE_1024_CASES = {
+    "causal_s2048": (2, 2, 2048, 1024, False, False, 0.0),
+    "key_mask_gqa_s2048": (4, 1, 2048, 1024, True, False, 0.0),
+    "segments_s2048": (1, 1, 2048, 1024, False, True, 0.0),
+    "ragged_gqa_dropout_segments_s2000": (2, 1, 2000, 1024, False, True,
+                                          0.1),
+    "s1536_steps_down_to_512": (1, 1, 1536, 512, False, False, 0.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TILE_1024_CASES))
+def test_default_tile_matches_ref(case, monkeypatch):
+    """bf16 d128 at the DEFAULT geometry (no cap forced): two 1024
+    tiles a side — one interior, two diagonal — forward and all three
+    gradients against the oracle; under a key selection with grouped
+    heads, with segments (a 1024 tile tiles the 128-lane segment row 8
+    times), with padding, grouped heads and dropout together, and at a
+    length 1024 does not divide."""
+    from apex_tpu.ops import _dispatch
+
+    h, hk, s, blk, sel, seg, rate = _TILE_1024_CASES[case]
+    monkeypatch.delenv("APEX_TPU_ATTN_BLOCK_CAP", raising=False)
+    monkeypatch.setattr(_dispatch, "_ATTN_CAPS", {})
+    ks = jax.random.split(jax.random.key(32), 5)
+    q = jax.random.normal(ks[0], (1, h, s, 128)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, hk, s, 128)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, hk, s, 128)).astype(jnp.bfloat16)
+    ct = jax.random.normal(ks[3], (1, h, s, 128))
+    assert attn._geom(q, k)[6:8] == (blk, blk)
+
+    kw, ref_kw = dict(causal=True), dict(causal=True)
+    if rate:
+        kw.update(dropout_rate=rate, dropout_seed=jnp.int32(77))
+        ref_kw.update(kw)
+    if sel:     # a selection that keeps the diagonal, so no row is empty
+        mask = (jax.random.bernoulli(ks[4], 0.3, (1, s, s))
+                | jnp.eye(s, dtype=bool)[None])
+        kw.update(key_mask=mask)
+        ref_kw.update(mask=jnp.where(mask[:, None], 0.0, attn._NEG))
+    if seg:     # three uneven segments, boundaries inside the tiles
+        ids = jnp.asarray(np.repeat([1, 2, 3], [700, 900, s - 1600])[None],
+                          jnp.int32)
+        same = ids[:, None, :, None] == ids[:, None, None, :]
+        kw.update(segment_ids=(ids, ids))
+        ref_kw.update(mask=jnp.where(same, 0.0, attn._NEG))
+
+    def loss(f, kwargs):
+        return lambda *a: jnp.sum(f(*a, **kwargs).astype(jnp.float32) * ct)
+
+    got = (attn.flash_attention(q, k, v, **kw),) + jax.grad(
+        loss(attn.flash_attention, kw), argnums=(0, 1, 2))(q, k, v)
+    want = (attn.attention_ref(q, k, v, **ref_kw),) + jax.grad(
+        loss(attn.attention_ref, ref_kw), argnums=(0, 1, 2))(q, k, v)
+    assert got[2].shape == (1, hk, s, 128) == got[3].shape
+    for g, w in zip(got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        # the file's bf16 tolerance, in units of the oracle's range
+        unit = max(float(np.abs(w).max()), 1.0)
+        np.testing.assert_allclose(g, w, rtol=2e-2, atol=2e-2 * unit)
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -114,6 +114,28 @@ def test_flash_attention_fwd_bwd(one_chip, shape, causal):
                     "apex_flash_attention_dq", "apex_flash_attention_dkv")
 
 
+def test_flash_attention_segments_dropout_at_the_1024_tile(one_chip):
+    """A long 16-bit sequence tiles at 1024 (PR 32): the variant that
+    holds most beside the 4 MiB score tile — segment ids, the dropout
+    hash, grouped heads, padding — still fits the kernels' VMEM."""
+    from apex_tpu.ops.attention import _geom, flash_attention
+
+    def f(q, k, v, ids, seed):
+        return flash_attention(q, k, v, causal=True, segment_ids=(ids, ids),
+                               dropout_rate=0.1, dropout_seed=seed)
+    s = 4000
+    specs = (((1, 4, s, 128), BF16), ((1, 2, s, 128), BF16),
+             ((1, 2, s, 128), BF16), ((1, s), I32), ((), I32))
+    q, k = (jax.ShapeDtypeStruct(sh, dt) for sh, dt in specs[:2])
+    assert _geom(q, k)[6:10] == (1024, 1024, 4096, 4096)
+    _assert_kernels(_compile(f, one_chip, *specs),
+                    "apex_flash_attention_fwd")
+    grads = jax.grad(lambda *a: jnp.sum(f(*a).astype(F32) ** 2),
+                     argnums=(0, 1, 2))
+    _assert_kernels(_compile(grads, one_chip, *specs),
+                    "apex_flash_attention_dq", "apex_flash_attention_dkv")
+
+
 # ---------------------------------------------------------------------------
 # learned sparse attention at the expert decoder's cell: b1 s8192, 32
 # query heads over 4 key heads of 128, a 16 x 64 indexer, 2048 keys a query

@@ -23,6 +23,7 @@ no child (a chip belongs to one process at a time).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import json
 import os as _os
@@ -89,6 +90,209 @@ def select_attn_caps(sweep_times):
         if cands:
             caps_out[str(dp)] = min(cands, key=cands.get)
     return caps_out
+
+
+# the benchmark cells' flash-attention calls (benchmarks/configs): the
+# looped decoder's (16 causal heads of 128 at s4096) and the
+# sparse-attention expert decoder's (32 query heads over 4 key heads of
+# 128 at s8192 under a top-2048 key selection), each with its control:
+# (name, b, h, hk, s, d, dtype, topk; 0 = no key_mask)
+CELL_ATTN_CALLS = (
+    ("looped", 1, 16, 16, 4096, 128, "bf16", 0),
+    ("looped_f32", 1, 16, 16, 4096, 128, "f32", 0),
+    ("expert_unmasked", 1, 32, 4, 8192, 128, "bf16", 0),
+    ("expert", 1, 32, 4, 8192, 128, "bf16", 2048),
+)
+FLASH_KERNELS = ("apex_flash_attention_fwd", "apex_flash_attention_dq",
+                 "apex_flash_attention_dkv")
+
+
+def attn_geometry(q, k):
+    """The tile a causal flash call of these operands runs at and its
+    grid (ops/attention.py:_geom, causal_block_plan): the blocks visited
+    — wholly under the diagonal, or crossed by it (or by padding) — and
+    those that cost no grid step."""
+    from apex_tpu.ops import attention as attn
+    sq, sk, bq, bk = (attn._geom(q, k)[i] for i in (2, 3, 6, 7))
+    plan = attn.causal_block_plan(sq, sk, bq, bk)
+    return {"bq": bq, "bk": bk, "visited": plan.interior + plan.diagonal,
+            "interior": plan.interior, "diagonal": plan.diagonal,
+            "not_visited": plan.not_visited}
+
+
+def flash_kernel_ms(fn, args, calls=4):
+    """ms a call of each of the three flash kernels inside jitted
+    ``fn(*args)``, read from a device trace of ``calls`` executions
+    the way the benchmark's ``attn_roofline`` reads them inside a step:
+    the durations of the device ops named ``apex_flash_attention_*``.
+    A kernel's time alone — not the ``di`` / ``lse`` broadcasts around
+    it, which a host clock around ``fn`` would count."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.block_until_ready(fn(*args))            # compile
+    with tempfile.TemporaryDirectory() as logdir:
+        with jax.profiler.trace(logdir):
+            for _ in range(calls):
+                jax.block_until_ready(fn(*args))
+        (path,) = glob.glob(_os.path.join(
+            logdir, "plugins", "profile", "*", "*.xplane.pb"))
+        planes = [p for p in ProfileData.from_file(path).planes
+                  if p.name == "/device:TPU:0"]
+        ops = [e for p in planes for ln in p.lines
+               if ln.name == "XLA Ops" for e in ln.events]
+    out = {}
+    for kern in FLASH_KERNELS:
+        durs = [e.duration_ns for e in ops
+                if e.name.lstrip("%").startswith(kern)]
+        if durs:
+            out[kern.rsplit("_", 1)[1] + "_ms"] = round(
+                sum(durs) / len(durs) / 1e6, 4)
+            out[kern.rsplit("_", 1)[1] + "_calls"] = len(durs) // calls
+    return out
+
+
+@contextlib.contextmanager
+def _forced_cap(cap):
+    """APEX_TPU_ATTN_BLOCK_CAP set to ``cap`` (None: unset, the default
+    geometry) for the block, an operator's own value put back after."""
+    prev = _os.environ.pop("APEX_TPU_ATTN_BLOCK_CAP", None)
+    if cap is not None:
+        _os.environ["APEX_TPU_ATTN_BLOCK_CAP"] = str(cap)
+    try:
+        yield
+    finally:
+        _os.environ.pop("APEX_TPU_ATTN_BLOCK_CAP", None)
+        if prev is not None:
+            _os.environ["APEX_TPU_ATTN_BLOCK_CAP"] = prev
+
+
+def _causal_flash_grad():
+    """jit(grad) of a causal flash call w.r.t. q, k, v; a fourth operand
+    is its ``key_mask``.  A fresh jit per call ON PURPOSE: the cap is
+    read at trace time, so each geometry must trace anew."""
+    import jax
+    import jax.numpy as jnp
+    from apex_tpu.ops.attention import flash_attention
+
+    # apexlint: disable-next=APX302
+    return jax.jit(jax.grad(
+        lambda q, k, v, *m: jnp.sum(flash_attention(
+            q, k, v, causal=True, **({"key_mask": m[0]} if m else {})
+        ).astype(jnp.float32) ** 2), argnums=(0, 1, 2)))
+
+
+def sweep_attn_cells(caps=(512, 1024)):
+    """The three flash kernels alone at the cells' calls: one JSON line
+    per (call, cap) with the geometry chosen, the causal plan's counts
+    and fwd / dq / dkv ms a call (PERF.md section 6 holds the table
+    this wrote on the chip).  ``default`` marks the cap whose geometry
+    the call gets with nothing forced."""
+    import jax
+    import jax.numpy as jnp
+    from apex_tpu.ops import attention as attn
+    from apex_tpu.ops.sparse_index import select_topk
+
+    for name, b, h, hk, s, d, dtype, topk in CELL_ATTN_CALLS:
+        dt = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+        ks = jax.random.split(jax.random.key(7), 4)
+        q = jax.random.normal(ks[0], (b, h, s, d), dt)
+        k, v = (jax.random.normal(kk, (b, hk, s, d), dt) for kk in ks[1:3])
+        args = (q, k, v)
+        if topk:
+            causal = jnp.tril(jnp.ones((s, s), bool))
+            scores = jnp.where(
+                causal, jax.random.normal(ks[3], (b, s, s)), attn._NEG)
+            args += (select_topk(scores, topk),)
+        with _forced_cap(None):
+            default = attn_geometry(q, k)
+        for cap in caps:
+            row = {"sweep": "attention_cells", "call": name,
+                   "shape": f"b{b}h{h}hk{hk}s{s}d{d}", "dtype": dtype,
+                   "key_mask_topk": topk, "cap": cap}
+            try:
+                with _forced_cap(cap):
+                    row.update(attn_geometry(q, k))
+                    row["default"] = row["bq"] == default["bq"]
+                    row.update(flash_kernel_ms(_causal_flash_grad(), args))
+            except Exception as e:
+                row["error"] = repr(e)[:300]
+            print(json.dumps(row), flush=True)
+
+
+def sweep_attn_caps(backend, noise_pct, write):
+    """Flash geometry sweep: the best sequence-block cap per shape
+    (re-jit per cap — the env knob is read at trace time) and the
+    per-head-dim winner.  ``write`` (--write-prefs) records the winners
+    in dispatch_prefs.json, where ``_block_cap`` reads them BEFORE its
+    own default — an entry caps every sequence length and operand width
+    of that head dim, which the default tells apart (the dp 128 winner
+    over a short and a long shape is 512, and written down it would
+    undo the long sequences' 1024 tile): record one knowingly."""
+    import jax
+    import jax.numpy as jnp
+    from apex_tpu.ops import attention as attn
+
+    sweep_times = {}          # (dp, cap) -> [relative time per shape]
+    # one shape per runtime head-dim tier (dp=128 twice: BERT-ish
+    # short-seq AND long-context must agree before a cap becomes
+    # that tier's default; dp=256 gets its own winner)
+    for (b, h, s, d) in [(8, 16, 512, 64), (4, 16, 2048, 128),
+                         (2, 16, 2048, 256)]:
+        ks = jax.random.split(jax.random.key(7), 3)
+        q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
+                   for kk in ks)
+        dp = attn._round_up(d, attn._LANES)
+        best, shape_ms = None, {}
+        for cap in (128, 256, 512, 1024):
+            if (cap > attn._round_up(s, attn._LANES)
+                    or cap > attn._sweep_cap_ceiling(dp)):
+                continue
+            try:
+                with _forced_cap(cap):
+                    ms = time_fn(_causal_flash_grad(), q, k, v)
+            except Exception as e:
+                print(json.dumps({"sweep": "attention", "cap": cap,
+                                  "shape": f"b{b}h{h}s{s}d{d}",
+                                  "error": repr(e)[:200]}), flush=True)
+                continue
+            print(json.dumps({"sweep": "attention", "cap": cap,
+                              "shape": f"b{b}h{h}s{s}d{d}",
+                              "fwdbwd_ms": round(ms, 3)}), flush=True)
+            shape_ms[cap] = ms
+            if best is None or ms < best[1]:
+                best = (cap, ms)
+        if best:
+            print(json.dumps({"sweep": "attention",
+                              "shape": f"b{b}h{h}s{s}d{d}",
+                              "best_cap": best[0],
+                              "best_ms": round(best[1], 3)}),
+                  flush=True)
+            for cap, ms in shape_ms.items():
+                sweep_times.setdefault((dp, cap), []).append(
+                    ms / best[1])
+    caps_out = select_attn_caps(sweep_times)
+    print(json.dumps({"attn_caps_measured": caps_out}), flush=True)
+    if caps_out and write:
+        from apex_tpu.ops import _dispatch
+        prefs_doc = _load_trusted_doc(_dispatch._PREFS_PATH)
+        prefs_doc.setdefault("source", "tools/kernel_bench.py")
+        prefs_doc.setdefault("attn_block_cap", {}).update(caps_out)
+        prefs_doc["attn_sweep_backend"] = backend
+        prefs_doc["topology"] = _dispatch.topology_block()
+        prefs_doc["schema"] = _dispatch.SCHEMA_VERSION
+        prefs_doc["noise_floor_pct"] = noise_pct
+        # the sweep times with the same amortized timer; a
+        # sweep-only run must still produce a table _load_prefs
+        # will trust (see write_prefs)
+        prefs_doc["methodology"] = "amortized"
+        with open(_dispatch._PREFS_PATH, "w") as f:
+            json.dump(prefs_doc, f, indent=1, sort_keys=True)
+            f.write("\n")
+        print(json.dumps({"attn_caps_written": caps_out}), flush=True)
 
 
 # kernel_bench row name -> dispatch op family (apex_tpu.ops._dispatch)
@@ -178,8 +382,12 @@ def main():
                     help="write apex_tpu/ops/dispatch_prefs.json from "
                          "the measured speedups")
     ap.add_argument("--sweep-attn", action="store_true",
-                    help="sweep APEX_TPU_ATTN_BLOCK_CAP geometries for "
-                         "the flash kernel and report the best")
+                    help="run ONLY the flash-attention sweeps: the three "
+                         "kernels alone at the benchmark cells' calls per "
+                         "block cap (fwd / dq / dkv ms, geometry, causal "
+                         "plan), then APEX_TPU_ATTN_BLOCK_CAP's winner "
+                         "per padded head dim (recorded in the prefs "
+                         "table only with --write-prefs)")
     args = ap.parse_args()
 
     import jax
@@ -228,15 +436,10 @@ def main():
     print(json.dumps({"noise_floor_pct": noise_pct,
                       "backend": backend}), flush=True)
 
-    def causal_blocks(q, k):
-        """The causal grid's three block counts at this shape's
-        geometry (ops/attention.py:causal_block_plan): visited blocks
-        wholly under the diagonal, visited blocks the diagonal (or
-        padding) crosses, and blocks that cost no grid step."""
-        sq, sk, bq, bk = (attn._geom(q, k)[i] for i in (2, 3, 6, 7))
-        plan = attn.causal_block_plan(sq, sk, bq, bk)
-        return {"interior": plan.interior, "diagonal": plan.diagonal,
-                "not_visited": plan.not_visited}
+    if args.sweep_attn:     # the sweeps alone: minutes, not the whole bench
+        sweep_attn_cells()
+        sweep_attn_caps(backend, noise_pct, args.write_prefs)
+        return
 
     # flash attention: bench shapes (BERT-L-ish, long-context, and the
     # looped decoder cell's b1 x 16 heads x s4096 x d128)
@@ -253,7 +456,7 @@ def main():
         for grad in (False, True):
             row = bench_pair("flash_attention", f"b{b}h{h}s{s}d{d}",
                              "bf16", f_k, f_o, q, k, v, grad=grad)
-            row["causal_blocks"] = causal_blocks(q, k)
+            row["causal_blocks"] = attn_geometry(q, k)
             rows.append(row)
 
     # f32 precision class: HIGHEST-precision multi-pass dots — its own
@@ -268,7 +471,7 @@ def main():
         functools.partial(attn.flash_attention, causal=True),
         functools.partial(attn.attention_ref, causal=True),
         qf, kf, vf, grad=True))
-    rows[-1]["causal_blocks"] = causal_blocks(qf, kf)
+    rows[-1]["causal_blocks"] = attn_geometry(qf, kf)
 
     # layer norm
     for (r, hdim) in [(8192, 1024), (4096, 4096)]:
@@ -456,78 +659,6 @@ def main():
         "kernel_ms": rw["int8_weight_matmul_ms"],
         "oracle_ms": rw["f32_weight_matmul_ms"],
         "speedup": (round(f32_ms / int8_ms, 2) if int8_ms else None)})
-
-    # flash geometry sweep: find the best sequence-block cap per shape
-    # (re-jit per cap — the env knob is read at trace time), then
-    # record the per-head-dim winner in dispatch_prefs.json so the
-    # measurement changes the kernel's DEFAULT geometry (VERDICT r3 #3),
-    # not just a CSV.
-    if args.sweep_attn:
-        sweep_times = {}          # (dp, cap) -> [relative time per shape]
-        # one shape per runtime head-dim tier (dp=128 twice: BERT-ish
-        # short-seq AND long-context must agree before a cap becomes
-        # that tier's default; dp=256 gets its own winner)
-        for (b, h, s, d) in [(8, 16, 512, 64), (4, 16, 2048, 128),
-                             (2, 16, 2048, 256)]:
-            ks = jax.random.split(jax.random.key(7), 3)
-            q, k, v = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
-                       for kk in ks)
-            dp = attn._round_up(d, attn._LANES)
-            best, shape_ms = None, {}
-            for cap in (128, 256, 512, 1024):
-                if (cap > attn._round_up(s, attn._LANES)
-                        or cap > attn._sweep_cap_ceiling(dp)):
-                    continue
-                os.environ["APEX_TPU_ATTN_BLOCK_CAP"] = str(cap)
-                try:
-                    # re-jit per cap ON PURPOSE: the env knob changes
-                    # kernel geometry, so each cap must compile fresh
-                    # apexlint: disable-next=APX302
-                    fn = jax.jit(jax.grad(
-                        lambda q, k, v: jnp.sum(attn.flash_attention(
-                            q, k, v, causal=True).astype(jnp.float32) ** 2),
-                        argnums=(0, 1, 2)))
-                    ms = time_fn(fn, q, k, v)
-                except Exception as e:
-                    print(json.dumps({"sweep": "attention", "cap": cap,
-                                      "shape": f"b{b}h{h}s{s}d{d}",
-                                      "error": repr(e)[:200]}), flush=True)
-                    continue
-                finally:
-                    os.environ.pop("APEX_TPU_ATTN_BLOCK_CAP", None)
-                print(json.dumps({"sweep": "attention", "cap": cap,
-                                  "shape": f"b{b}h{h}s{s}d{d}",
-                                  "fwdbwd_ms": round(ms, 3)}), flush=True)
-                shape_ms[cap] = ms
-                if best is None or ms < best[1]:
-                    best = (cap, ms)
-            if best:
-                print(json.dumps({"sweep": "attention",
-                                  "shape": f"b{b}h{h}s{s}d{d}",
-                                  "best_cap": best[0],
-                                  "best_ms": round(best[1], 3)}),
-                      flush=True)
-                for cap, ms in shape_ms.items():
-                    sweep_times.setdefault((dp, cap), []).append(
-                        ms / best[1])
-        caps_out = select_attn_caps(sweep_times)
-        if caps_out:
-            from apex_tpu.ops import _dispatch
-            prefs_doc = _load_trusted_doc(_dispatch._PREFS_PATH)
-            prefs_doc.setdefault("source", "tools/kernel_bench.py")
-            prefs_doc.setdefault("attn_block_cap", {}).update(caps_out)
-            prefs_doc["attn_sweep_backend"] = backend
-            prefs_doc["topology"] = _dispatch.topology_block()
-            prefs_doc["schema"] = _dispatch.SCHEMA_VERSION
-            prefs_doc["noise_floor_pct"] = noise_pct
-            # the sweep times with the same amortized timer; a
-            # sweep-only run must still produce a table _load_prefs
-            # will trust (see write_prefs)
-            prefs_doc["methodology"] = "amortized"
-            with open(_dispatch._PREFS_PATH, "w") as f:
-                json.dump(prefs_doc, f, indent=1, sort_keys=True)
-                f.write("\n")
-            print(json.dumps({"attn_caps_written": caps_out}), flush=True)
 
     # welford mean/var (SyncBN's local-stats kernel), NHWC-flat shape
     from apex_tpu.ops import welford as wf
