@@ -291,8 +291,13 @@ def collective_compute_cones(jaxpr, compute_prims=("dot_general",)):
 
 
 def donated_alias_count(lowered_text: str) -> int:
-    """How many input buffers the lowered module aliases to outputs —
+    """How many input buffers the lowered module gives to outputs —
     ``tf.aliasing_output`` argument attributes in StableHLO are the
     trace of ``donate_argnums`` actually taking effect (a donation
-    XLA could not honor simply lacks the attribute)."""
-    return lowered_text.count("tf.aliasing_output")
+    jax could not match to an output simply lacks the attribute).
+    Where the outputs' shardings are the compiler's to choose (a step
+    over a mesh) jax marks the donor ``jax.buffer_donor`` instead and
+    XLA pairs it with an output; an argument carries one or the
+    other."""
+    return (lowered_text.count("tf.aliasing_output")
+            + lowered_text.count("jax.buffer_donor"))

@@ -117,7 +117,10 @@ def _build_bucketed(name, **kw):
     assert opt._plan is not None, f"{name}: packer declined tiny tree"
     args = _step_args(opt)
     nb = len(opt._plan.buckets)
-    n_state = len(jax.tree_util.tree_leaves(opt.opt_state))
+    # the step updates in place what the optimizer holds packed
+    # (FusedLAMB keeps its masters out: fused_lamb.py says why)
+    n_donated = len(jax.tree_util.tree_leaves(
+        [args[i] for i in opt._donation["donate_argnums"]]))
     expect = {
         "no_host_transfer": True,
         "no_f64": True,
@@ -125,15 +128,16 @@ def _build_bucketed(name, **kw):
         "bucket_concats": {"count": nb * (2 if name in _SEGMENTED else 1),
                            "sizes": {(b.size,)
                                      for b in opt._plan.buckets}},
-        # donation honored: every packed state buffer aliases an output
-        "donated_aliases": n_state,
+        # donation honored: every donated packed buffer aliases an
+        # output
+        "donated_aliases": n_donated,
         "no_orphan_collectives": True,
         # the update is jnp: XLA fuses it with the skip and the cast
         "pallas_calls": 0,
         "is_finite_max": 0,           # found_inf arrives as a flag
     }
     return {"fn": opt._full_step_impl, "args": args,
-            "jit_kwargs": {"donate_argnums": (2,)}, "expect": expect}
+            "jit_kwargs": opt._donation, "expect": expect}
 
 
 def _build_per_leaf(name, **kw):
@@ -144,7 +148,7 @@ def _build_per_leaf(name, **kw):
     n_state = len(jax.tree_util.tree_leaves(opt.opt_state))
     return {
         "fn": opt._full_step_impl, "args": args,
-        "jit_kwargs": {"donate_argnums": (2,)},
+        "jit_kwargs": opt._donation,
         "expect": {
             "no_host_transfer": True,
             "no_f64": True,

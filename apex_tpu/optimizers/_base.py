@@ -25,6 +25,15 @@ vice versa.  ``fuse_buckets=False`` (or any
 tree the packer declines: non-float leaves, multi-device shardings)
 falls back to the traced per-leaf update.
 
+Ownership on the bucketed path (docs/optimizers.md): the optimizer
+COPIES IN whatever it is handed (constructor, ``params`` / ``masters``
+setters, ``load_state_dict``, ``load_packed_snapshot``: the pack, or a
+copy where the pack would be the caller's own array), the step DONATES
+the packed parameters, masters and state and updates them in place, and
+everything handed OUT (``step()``'s result, ``params``, ``masters``,
+``state_dict()``, ``packed_snapshot()``) is a fresh array the caller
+may keep across any number of steps.
+
 Master weights: when params are bf16/fp16 and ``master_weights=True`` the
 facade keeps f32 masters, steps those, and writes back model-dtype params
 (reference O2 contract, apex/amp/_process_optimizer.py).
@@ -86,6 +95,23 @@ def _replica_mesh(tree: Pytree):
             mesh = comm.mesh()
         return mesh
     return None
+
+
+def _own_pack(bufs, tree: Pytree) -> List[jax.Array]:
+    """``bufs`` as packed from ``tree``, none of them one of its
+    leaves: a one-leaf bucket of a flat leaf that already has the
+    target dtype packs to the leaf ITSELF (``BucketPlan.pack``), and
+    the step donates what the optimizer holds — that one is copied."""
+    theirs = {id(x) for x in jax.tree_util.tree_leaves(tree)}
+    return [jnp.copy(b) if id(b) in theirs else b for b in bufs]
+
+
+def _owned(buf) -> jax.Array:
+    """``buf`` as a device buffer that nothing outside the optimizer
+    holds (the step donates it): a jax array is copied, host data is
+    put on the device."""
+    return _device_copy(buf) if isinstance(buf, jax.Array) \
+        else jnp.asarray(buf)
 
 
 def _device_copy(buf: jax.Array) -> jax.Array:
@@ -173,6 +199,11 @@ class FusedOptimizerBase:
     """Subclasses set ``defaults`` and implement ``_step_math`` (per-leaf
     oracle path) plus ``_flat_bucket_step`` (bucketed flat path)."""
 
+    # whether the bucketed step donates the packed WORK buffers (the
+    # masters, or the parameters where there are none) beside the state
+    # and the model-dtype copy: FusedLAMB says no, and why
+    _donate_work = True
+
     def __init__(self, params: Pytree, master_weights: Optional[bool] = None,
                  masters: Optional[Pytree] = None,
                  offload_state: bool = False,
@@ -227,13 +258,7 @@ class FusedOptimizerBase:
         self._params_cache = None
         self._masters_cache = None
         if self._plan is not None:
-            self._param_bufs = self._plan.pack_model(params)
-            self._master_bufs = (self._plan.pack_work(masters)
-                                 if masters is not None else None)
-            self._params_cache = params
-            self._masters_cache = masters
-            self._unpack_model_jit = jax.jit(self._plan.unpack_model)
-            self._unpack_work_jit = jax.jit(self._plan.unpack)
+            self._adopt(params, masters)
             self.opt_state = self.init_state_packed(self._plan, work)
             self._full_step_impl = self._full_step_flat
         else:
@@ -270,7 +295,38 @@ class FusedOptimizerBase:
                 self._full_step_offload,
                 out_shardings=(None, None,
                                tree_map(_host_sharding, self.opt_state)))
-        return jax.jit(self._full_step_impl, donate_argnums=(2,))
+        return jax.jit(self._full_step_impl, **self._donation)
+
+    @property
+    def _donation(self):
+        """``jax.jit``'s arguments that say what the step program
+        updates in place: the state and, on the bucketed path, the
+        packed parameter and master buffers, which nothing but the
+        optimizer holds (``_adopt``).  ``keep_unused``: with masters
+        the program never reads the packed model-dtype parameters, and
+        an argument jit prunes cannot give its buffer to the output
+        that replaces it.  The per-leaf path's trees are the caller's."""
+        if self._plan is None:
+            return {"donate_argnums": (2,)}
+        work = 0 if self._master_bufs is None else 1
+        return {"donate_argnums": tuple(
+                    i for i in (0, 1, 2)
+                    if self._donate_work or i != work),
+                "keep_unused": True}
+
+    def _adopt(self, params, masters):
+        """Pack ``params`` (and ``masters``) under the current plan
+        into buffers of the optimizer's own.  ``params`` stays as the
+        cached tree the model reads; the masters' tree is not kept —
+        the ``masters`` property unpacks on demand, as after a step."""
+        self._param_bufs = _own_pack(self._plan.pack_model(params), params)
+        self._master_bufs = (
+            _own_pack(self._plan.pack_work(masters), masters)
+            if masters is not None else None)
+        self._params_cache = params
+        self._masters_cache = None
+        self._unpack_model_jit = jax.jit(self._plan.unpack_model)
+        self._unpack_work_jit = jax.jit(self._plan.unpack)
 
     # ---- packed views ----------------------------------------------------
     @property
@@ -292,7 +348,8 @@ class FusedOptimizerBase:
         if self._plan is None:
             self._params_tree = value
         else:
-            self._param_bufs = self._plan.pack_model(value)
+            self._param_bufs = _own_pack(self._plan.pack_model(value),
+                                         value)
             self._params_cache = value
 
     @property
@@ -315,19 +372,24 @@ class FusedOptimizerBase:
             self._master_bufs = None
             self._masters_cache = None
         else:
-            self._master_bufs = self._plan.pack_work(value)
-            self._masters_cache = value
+            self._master_bufs = _own_pack(self._plan.pack_work(value),
+                                          value)
+            self._masters_cache = None
 
     # ---- functional core -------------------------------------------------
     def init_state(self, params: Pytree) -> Pytree:
         raise NotImplementedError
 
     def init_state_packed(self, plan: BucketPlan, work: Pytree) -> Pytree:
-        """Packed optimizer state: each field of the per-leaf state,
-        bucket-packed (param-shaped fields -> flat buffers; per-tensor
-        scalar fields -> one (num leaves,) vector per bucket)."""
-        state = self.init_state(work)
-        return {k: plan.pack_state_field(v) for k, v in state.items()}
+        """Packed optimizer state, built packed: each field of the
+        per-leaf state, bucket-packed (param-shaped fields -> flat
+        buffers; per-tensor scalar fields -> one (num leaves,) vector
+        per bucket).  ``init_state`` and the packs are ONE program over
+        the work tree whose outputs are the buffers, so no per-leaf
+        state tree is ever live beside them."""
+        return jax.jit(lambda work: {
+            k: plan.pack_state_field(v)
+            for k, v in self.init_state(work).items()})(work)
 
     def _step_math(self, params, grads, opt_state, step, grad_scale, hypers):
         """Pure per-leaf update on the (possibly master) params."""
@@ -672,7 +734,7 @@ class FusedOptimizerBase:
                 jax.shard_map(self._full_step_impl, mesh=mesh,
                               in_specs=spec, out_specs=spec,
                               check_vma=False),
-                donate_argnums=(2,))
+                **self._donation)
         return fn
 
     def _step_args(self, grads, grad_scale=1.0, found_inf=None):
@@ -749,15 +811,10 @@ class FusedOptimizerBase:
         self._plan = BucketPlan.from_tree(
             work, params if masters is not None else None,
             max_bucket_bytes=max_bucket_bytes)
-        self._param_bufs = self._plan.pack_model(params)
-        self._master_bufs = (self._plan.pack_work(masters)
-                             if masters is not None else None)
-        self._params_cache = params
-        self._masters_cache = masters
-        self._unpack_model_jit = jax.jit(self._plan.unpack_model)
-        self._unpack_work_jit = jax.jit(self._plan.unpack)
-        self.opt_state = {k: self._plan.pack_state_field(v)
-                          for k, v in state_trees.items()}
+        self._adopt(params, masters)
+        self.opt_state = {
+            k: _own_pack(self._plan.pack_state_field(v), v)
+            for k, v in state_trees.items()}
         if self.offload_state:
             self.opt_state = place_on_host(self.opt_state)
         # fresh jit: the step body closes over the plan
@@ -816,11 +873,11 @@ class FusedOptimizerBase:
                 "load_packed_snapshot requires the bucketed path")
         self.step_count = jnp.int32(step)
         self.hypers.update(hypers)
-        self._param_bufs = [jnp.asarray(b) for b in param_bufs]
-        if master_bufs is not None:
-            self._master_bufs = [jnp.asarray(b) for b in master_bufs]
-        else:
-            self._master_bufs = None
+        # copied in: the step donates what it is given here, and the
+        # caller's snapshot must stay readable (and loadable again)
+        self._param_bufs = [_owned(b) for b in param_bufs]
+        self._master_bufs = ([_owned(b) for b in master_bufs]
+                             if master_bufs is not None else None)
         self._params_cache = None
         self._masters_cache = None
         # the v2 payload stores every state buffer flattened; a
@@ -852,7 +909,7 @@ class FusedOptimizerBase:
                 for k, v in state.items()}
         else:
             self.opt_state = {
-                k: [jnp.asarray(_shaped(b, o))
+                k: [_owned(_shaped(b, o))
                     for b, o in zip(v, old.get(k, [None] * len(v)))]
                 for k, v in state.items()}
 
@@ -891,11 +948,11 @@ class FusedOptimizerBase:
         self.step_count = jnp.int32(sd["step"])
         self.hypers.update(sd["hypers"])
         if self._plan is not None:
-            # per-leaf checkpoint layout -> packed buffers (the pack
-            # concatenates, so the checkpoint dict is never aliased by
-            # the donating step)
-            self.opt_state = {k: self._plan.pack_state_field(v)
-                              for k, v in sd["state"].items()}
+            # per-leaf checkpoint layout -> packed buffers of our own:
+            # the checkpoint dict is never aliased by the donating step
+            self.opt_state = {
+                k: _own_pack(self._plan.pack_state_field(v), v)
+                for k, v in sd["state"].items()}
         else:
             # copy: step() donates opt_state to the compiled update, and
             # the caller's checkpoint dict must stay readable after we

@@ -32,6 +32,15 @@ class FusedLAMB(FusedOptimizerBase):
                     bias_correction=True, max_grad_norm=1.0,
                     use_nvlamb=False)
 
+    # The two-phase update reads the work buffers in both phases, and
+    # with them donated XLA:TPU assigns the gradient pack's relayouts
+    # to memory spaces differently: +1.65 ms of copies a step at
+    # BERT-Large on a v5e, where Adam's one-phase step LOSES four f32
+    # bucket copies to the same donation (PERF.md section 6, PR 33).
+    # Donating the state and the model-dtype parameters alone compiles
+    # to the undonated program's schedule, so LAMB keeps to that.
+    _donate_work = False
+
     def __init__(self, params, betas=None, **kw):
         if betas is not None:
             kw["beta1"], kw["beta2"] = betas

@@ -34,6 +34,7 @@ SUITES = {
                        "tests/test_optimizers.py",
                        "tests/test_bucketed_optimizers.py",
                        "tests/test_flat_step_one_sweep.py",
+                       "tests/test_optimizer_ownership.py",
                        "tests/test_distributed_optimizers.py"],
     "run_fused_layer_norm": ["tests/test_fused_layer_norm.py"],
     "run_fused_softmax": ["tests/test_fused_softmax_rope.py"],
