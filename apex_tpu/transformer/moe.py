@@ -36,7 +36,7 @@ expert-parallel caller sums the parts.
 
 from __future__ import annotations
 
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -253,54 +253,242 @@ def _take(x, idx):
 # directions (a row is looked up where it went, never scattered to
 # where it goes): XLA's scatter-add runs elements one by one on the TPU
 # (PERF.md section 6, PR 26).
+#
+# The buffers between them have ``rows = T * min(k, held)`` rows, all
+# that can arrive, and ``n_used = sum(counts)`` rows in use, sorted to
+# the front.  Every pass over such a buffer is a loop over chunks of
+# ``_CHUNK`` rows whose trip count is read off ``n_used``: it costs the
+# rows routed here, and the rows past the last chunk stay the zeros the
+# buffer was made of (the grouped products are handed these buffers).
+# The loops live inside custom VJP rules, so JAX never differentiates
+# through one; each backward rule is its own loop.
+
+_CHUNK = 2048
+
+
+class _Routed(NamedTuple):
+    """Where the assignments held here are, as integers and copies of
+    their gates that carry no gradient (``_route`` makes it)."""
+    n_used: Array     # () rows in use
+    order: Array      # (T * k,) the assignment t * k + c in each row of
+    #                   expert order, rows in use first
+    token: Array      # (rows,) the token in each row of expert order
+    gate: Array       # (rows,) and its gate
+    # the rows in use again, sorted by the assignment they hold (token
+    # order), each (rows,): their row in expert order, gate and token
+    t_row: Array
+    t_gate: Array
+    t_token: Array
+    last: Array       # (T,) the place in token order of each token's
+    #                   last assignment held here
+    some: Array       # (T,) whether it has any
+
+
+def _over_rows_in_use(n_used, after, like, chunk_fn):
+    """One buffer for each (shape, dtype) of ``like``, ``rows`` leading
+    rows each, made once ``after`` (the arrays the chunks read) is
+    there.  For every chunk of rows that holds a row in use,
+    ``chunk_fn(start, size)`` gives each buffer's ``size`` rows from
+    ``start`` on (zeros at rows past ``n_used``), which are written
+    there; the rows of the chunks after them are zeros.  Returns the
+    buffers."""
+    rows = like[0][0][0]
+    size = min(_CHUNK, rows)
+    # zeros the compiler cannot fold (``n_used`` is never negative,
+    # which it does not know): constant zeros of one shape are merged
+    # across a model's layers and lose the scope they were made under,
+    # and the layer's metric the time to fill them (0.4 ms for 65 536 x
+    # 2048 bfloat16 on a v5e).  Each loop's zero is its own value, there
+    # no sooner than what the loop reads: zeros of one value and shape
+    # are one buffer to the compiler, held across the loops that share
+    # it, and a fill that waits for nothing is made long before its loop
+    # (+0.34 and +0.19 GiB of the expert cell's train step's scratch,
+    # PERF.md section 6, PR 34)
+    zero = jnp.minimum(jax.lax.optimization_barrier((n_used, after))[0], 0)
+
+    def body(i, bufs):
+        # the last chunk of a buffer that ``size`` does not divide is
+        # moved back to end with it: it writes some rows twice, the same
+        start = jnp.minimum(i * size, rows - size)
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(b, c.astype(b.dtype), start, 0)
+            for b, c in zip(bufs, chunk_fn(start, size)))
+    return jax.lax.fori_loop(
+        0, (n_used + size - 1) // size, body,
+        tuple(jnp.full(shape, zero, dtype) for shape, dtype in like))
+
+
+def _in_use(start, size, n_used):
+    at = start + jnp.arange(size)
+    return ((at >= 0) & (at < n_used))[:, None]
+
+
+def _window(v, start, size):
+    return jax.lax.dynamic_slice_in_dim(v, start, size)
+
+
+def _sum_by_token(buf, routed, weighted):
+    """out[t] = float32 sum over the assignments of token t held here of
+    [their gate times] their row of buf, in buf's dtype.  In token
+    order a token's assignments are at most ``reach = min(k, held)``
+    adjacent entries, so a running sum over ``reach`` neighbours of the
+    same token leaves each token's sum at its last entry: ``n_used``
+    rows are gathered, summed in chunks, and T looked up."""
+    n_used = routed.n_used
+    rows = routed.token.shape[0]
+    reach = rows // routed.last.shape[0]
+    halo = reach - 1
+    row, gate = (jnp.pad(v, (halo, 0)) for v in (routed.t_row, routed.t_gate))
+    token = jnp.pad(routed.t_token, (halo, 0), constant_values=-1)
+
+    def chunk(start, size):
+        # entries start - halo .. start + size are [start, start + halo
+        # + size) of the padded vectors
+        tok = _window(token, start, halo + size)
+        z = _take(buf, _window(row, start, halo + size)).astype(jnp.float32)
+        if weighted:
+            z = z * _window(gate, start, halo + size)[:, None]
+        z = jnp.where(_in_use(start - halo, halo + size, n_used), z, 0.0)
+        d = 1
+        while d < reach:                   # sums of 2, 4, 8... neighbours
+            same = (tok[d:] == tok[:-d])[:, None]
+            z = z + jnp.pad(jnp.where(same, z[:-d], 0.0), ((d, 0), (0, 0)))
+            d *= 2
+        return (z[halo:],)
+    sums, = _over_rows_in_use(
+        n_used, buf, [((rows,) + buf.shape[1:], buf.dtype)], chunk)
+    return jnp.where(routed.some[:, None], _take(sums, routed.last), 0)
+
 
 @jax.custom_vjp
-def _dispatch(x, source, slot, here):
-    """Rows of x (T, H) in expert order: out[m] = x[source[m]]."""
-    return _take(x, source)
+def _dispatch(x, routed):
+    """Rows of x (T, H) in expert order: out[m] = x[token[m]] for the
+    ``n_used`` rows in use, zeros after them."""
+    return _dispatch_fwd(x, routed)[0]
 
 
-def _dispatch_fwd(x, source, slot, here):
-    return _take(x, source), (slot, here)
+def _dispatch_fwd(x, routed):
+    def chunk(start, size):
+        return (jnp.where(_in_use(start, size, routed.n_used),
+                          _take(x, _window(routed.token, start, size)), 0),)
+    xs, = _over_rows_in_use(
+        routed.n_used, x, [(routed.token.shape + x.shape[1:], x.dtype)],
+        chunk)
+    return xs, routed
 
 
-def _dispatch_bwd(res, g):
-    slot, here = res
-    picked = jnp.where(here[..., None], _take(g, slot), 0)
-    dx = jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype)
-    return dx, None, None, None
+def _dispatch_bwd(routed, g):
+    return _sum_by_token(g, routed, False), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(ys, gates, slot, here, order):
-    """y[t] = sum over the assignments (t, c) held here of
-    gates[t, c] * ys[slot[t, c]], float32 sum, ys' dtype."""
-    return _combine_fwd(ys, gates, slot, here, order)[0]
+def _gated_silu(mid, n_used):
+    """silu(gate) * up of mid (rows, 2F) = [gate | up] through float32,
+    over the rows in use; zeros after them."""
+    return _gated_silu_fwd(mid, n_used)[0]
 
 
-def _combine_fwd(ys, gates, slot, here, order):
-    picked = jnp.where(here[..., None], _take(ys, slot), 0)
-    y = jnp.sum(gates[..., None] * picked.astype(jnp.float32), axis=1)
-    return y.astype(ys.dtype), (picked, gates, here, order, ys.shape[0])
+def _gate_up(mid, start, size):
+    return jnp.split(_window(mid, start, size).astype(jnp.float32), 2,
+                     axis=-1)
+
+
+def _gated_silu_fwd(mid, n_used):
+    def chunk(start, size):
+        gate, up = _gate_up(mid, start, size)
+        return (jnp.where(_in_use(start, size, n_used),
+                          jax.nn.silu(gate) * up, 0.0),)
+    act, = _over_rows_in_use(
+        n_used, mid, [((mid.shape[0], mid.shape[1] // 2), mid.dtype)], chunk)
+    return act, (mid, n_used)
+
+
+def _gated_silu_bwd(res, dact):
+    mid, n_used = res
+
+    def chunk(start, size):
+        gate, up = _gate_up(mid, start, size)
+        d = _window(dact, start, size).astype(jnp.float32)
+        sig = jax.nn.sigmoid(gate)
+        dgate = d * up * sig * (1.0 + gate * (1.0 - sig))
+        return (jnp.where(_in_use(start, size, n_used), jnp.concatenate(
+            [dgate, d * gate * sig], axis=-1), 0.0),)
+    dmid, = _over_rows_in_use(
+        n_used, (mid, dact), [(mid.shape, mid.dtype)], chunk)
+    return dmid, None
+
+
+_gated_silu.defvjp(_gated_silu_fwd, _gated_silu_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, gates, routed):
+    """y[t] = sum over the assignments (t, c) held here of gates[t, c]
+    times their row of ys, float32 sum, ys' dtype.  The forward reads
+    the gates from ``routed``'s sorted copies; the backward rule gives
+    ``gates`` (T, k) their gradient."""
+    return _combine_fwd(ys, gates, routed)[0]
+
+
+def _combine_fwd(ys, gates, routed):
+    return _sum_by_token(ys, routed, True), (ys, routed)
 
 
 def _combine_bwd(res, dy):
-    picked, gates, here, order, rows = res
-    k = gates.shape[1]
-    dgates = jnp.where(here, jnp.sum(
-        dy.astype(jnp.float32)[:, None] * picked.astype(jnp.float32),
-        axis=-1), 0.0)
-    first = order[:rows]                  # the assignment in each row
-    weight = jnp.where(here.reshape(-1)[first],
-                       gates.reshape(-1)[first], 0.0)
-    dys = (weight[:, None] * _take(dy, first // k).astype(jnp.float32))
-    return dys.astype(picked.dtype), dgates, None, None, None
+    ys, routed = res
+    rows, n_used = ys.shape[0], routed.n_used
+
+    def chunk(start, size):
+        # in expert order: a row's token's dy, once for both results
+        dyt = _take(dy, _window(routed.token, start, size)).astype(
+            jnp.float32)
+        live = _in_use(start, size, n_used)
+        dys = _window(routed.gate, start, size)[:, None] * dyt
+        dgate = jnp.sum(dyt * _window(ys, start, size).astype(jnp.float32),
+                        axis=-1)
+        return jnp.where(live, dys, 0.0), jnp.where(live[:, 0], dgate, 0.0)
+    dys, dgate = _over_rows_in_use(
+        n_used, (dy, ys), [(ys.shape, ys.dtype), ((rows,), jnp.float32)],
+        chunk)
+    # back to assignment order by a sort on the assignment each row
+    # holds (the rows past ``rows`` hold none that is here: zeros)
+    order = routed.order
+    _, dgate = jax.lax.sort(
+        (order, jnp.pad(dgate, (0, order.shape[0] - rows))), num_keys=1)
+    return dys, dgate.reshape(-1, order.shape[0] // routed.last.shape[0]), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _route(gates, experts, expert_offset, held):
+    """gates, experts (T, k) of ``route_topk`` -> (``_Routed``, counts
+    (held,)) for the holder of experts ``expert_offset .. + held``."""
+    t, top_k = experts.shape
+    local = experts - expert_offset
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held).reshape(-1)
+    counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
+                     axis=0, dtype=jnp.int32)
+    n_used = jnp.sum(counts)
+    rows = t * min(top_k, held)
+    # the moves read where a row went from sorted copies, scalars too: a
+    # sort carries them, a gather would look each one up
+    every = jnp.arange(t * top_k, dtype=jnp.int32)
+    _, order, gate = jax.lax.sort(
+        (key, every, jax.lax.stop_gradient(gates).reshape(-1)),
+        num_keys=1, is_stable=True)
+    assignment, row, gate_by_token = jax.lax.sort(
+        (jnp.where(every < n_used, order, t * top_k), every, gate),
+        num_keys=1)
+    return _Routed(
+        n_used, order, order[:rows] // top_k, gate[:rows],
+        row[:rows], gate_by_token[:rows], assignment[:rows] // top_k,
+        jnp.cumsum(jnp.sum(here, axis=-1, dtype=jnp.int32)) - 1,
+        jnp.any(here, axis=-1)), counts
 
 
 def dropless_moe(x, router, gate_up, down, *, top_k: int,
@@ -319,34 +507,27 @@ def dropless_moe(x, router, gate_up, down, *, top_k: int,
     held here or not), and counts[e] the tokens routed to each held
     expert.  No token is dropped: the assignments routed here are
     sorted by expert into a buffer of T * min(k, held) rows (all that
-    can arrive), and the two grouped products run over the rows in use.
+    can arrive), and the moves, the activation, the two grouped
+    products and their backward passes run over the rows in use
+    (``sum(counts)``, in chunks of ``_CHUNK``): the layer costs what is
+    routed to it, up to the whole buffer when everything is.
     What the other holders' experts add is left out: the holders' parts
     sum to the whole layer."""
-    t, h = x.shape
-    held = gate_up.shape[0]
     gates, experts = route_topk(x, router, top_k, norm_topk_prob)
     with jax.named_scope("apex_moe/dispatch"):
-        local = experts - expert_offset
-        here = (local >= 0) & (local < held)
-        key = jnp.where(here, local, held).reshape(-1)
-        counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
-                         axis=0, dtype=jnp.int32)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        rows = t * min(top_k, held)
-        slot = jnp.minimum(jnp.argsort(order).astype(jnp.int32),
-                           rows - 1).reshape(t, top_k)
-        xs = _dispatch(x, order[:rows] // top_k, slot, here)
+        routed, counts = _route(gates, experts, expert_offset,
+                                gate_up.shape[0])
+        xs = _dispatch(x, routed)
     with jax.named_scope("apex_moe/experts"):
         prec = matmul_precision(x.dtype)
         mid = jax.lax.ragged_dot(xs, gate_up.astype(x.dtype), counts,
                                  precision=prec)
         with jax.named_scope("apex_swiglu"):
-            gate, up = jnp.split(mid.astype(jnp.float32), 2, axis=-1)
-            act = (jax.nn.silu(gate) * up).astype(x.dtype)
+            act = _gated_silu(mid, routed.n_used)
         ys = jax.lax.ragged_dot(act, down.astype(x.dtype), counts,
                                 precision=prec)
     with jax.named_scope("apex_moe/combine"):
-        y = _combine(ys, gates, slot, here, order)
+        y = _combine(ys, gates, routed)
     return y, counts
 
 

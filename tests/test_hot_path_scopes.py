@@ -187,3 +187,63 @@ def test_scaler_update_and_prefetcher_open_their_spans(records):
     names = [r.name for r in records]
     assert names.count("apex/amp/update_scaler") == 1
     assert names.count("apex/data/next") == 3        # two batches, the end
+
+
+# ---- the dropless expert layer (PR 34) ---------------------------------------------
+
+MOE_SCOPES = ["apex_moe/router", "apex_moe/dispatch", "apex_moe/experts",
+              "apex_moe/combine"]
+# what moves or multiplies rows: the ops ``moe_ms`` is there to read
+MOE_WORK = r"\s(gather|while|sort|dot|dynamic-update-slice|custom-call)\("
+
+
+@pytest.fixture(scope="module")
+def moe_layer_ops():
+    """(opcode, op_name) of the working instructions of ``dropless_moe``
+    forward and of its four gradients, compiled here at a toy size."""
+    from apex_tpu.transformer.moe import dropless_moe
+    k = jax.random.split(jax.random.key(0), 5)
+    args = (jax.random.normal(k[0], (96, 64), jnp.bfloat16),
+            jax.random.normal(k[1], (64, 16), jnp.bfloat16),
+            jax.random.normal(k[2], (4, 64, 64), jnp.bfloat16),
+            jax.random.normal(k[3], (4, 32, 64), jnp.bfloat16))
+    ct = jax.random.normal(k[4], (96, 64), jnp.bfloat16)
+
+    def layer(*a):
+        return dropless_moe(*a, top_k=2, expert_offset=4)[0]
+
+    def working_ops(fn):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        return [
+            (m.group(1), re.search(r'op_name="([^"]*)"', line).group(1))
+            for line in text.splitlines()
+            if (m := re.search(MOE_WORK, line.partition(" = ")[2]))
+            and "op_name=" in line]
+    return {"forward": working_ops(layer),
+            "backward": working_ops(lambda *a: jax.vjp(layer, *a)[1](ct))}
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_expert_layer_keeps_every_move_under_its_scopes(moe_layer_ops,
+                                                        direction):
+    """Every gather, loop, sort, product and chunk write of the layer,
+    forward and backward, carries one of the four ``apex_moe/*`` scopes
+    in its name (by the rule ``moe_ms``'s reader matches with), and the
+    loops over the rows in use are there to be read."""
+    from benchmarks.readers.scope_span import _components, _holds
+    ops = moe_layer_ops[direction]
+    assert ops
+
+    def scopes_of(name):
+        parts = _components(name + ":")
+        return [s for s in MOE_SCOPES if _holds(parts, s.split("/"))]
+    stray = [(op, name) for op, name in ops if not scopes_of(name)]
+    assert not stray, stray
+    if direction == "backward":
+        ops = [(op, name) for op, name in ops if "transpose(" in name]
+    loops = {s for op, name in ops if op == "while" for s in scopes_of(name)}
+    assert loops == {"apex_moe/dispatch", "apex_moe/experts",
+                     "apex_moe/combine"}, loops
+    gathers = {s for op, name in ops if op == "gather"
+               for s in scopes_of(name)}
+    assert {"apex_moe/dispatch", "apex_moe/combine"} <= gathers, gathers

@@ -85,12 +85,16 @@ def flat(tree):
             jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-def load_example():
-    path = os.path.join(ROOT, "examples", "gpt", "train_moe.py")
-    spec = importlib.util.spec_from_file_location("train_moe_t", path)
+def load_script(name, *parts):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, *parts))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_example():
+    return load_script("train_moe_t", "examples", "gpt", "train_moe.py")
 
 
 def reference_grads(params, tokens, labels, sizes=SIZES):
@@ -262,6 +266,159 @@ def test_dropless_moe_gradients_agree_with_the_dense_oracle():
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=0,
                                    atol=1e-4 * float(jnp.max(jnp.abs(w))))
+
+
+# ---- the cost follows the rows routed here: every fill of the buffer ---------------
+
+FILL_T, FILL_K, FILL_HELD = 1536, 2, 4
+
+
+def _forced_layer(pairs, experts, dtype):
+    """A layer whose router sends token t to the experts ``pairs[t]``
+    (first choice, second choice): the first ``experts`` directions of
+    x carry the wanted logits, the router passes them on."""
+    layer = _expert_layer(4, len(pairs), experts=experts)
+    pairs = np.asarray(pairs)
+    logits = np.zeros((len(pairs), experts), np.float32)
+    logits[np.arange(len(pairs)), pairs[:, 0]] = 6.0
+    logits[np.arange(len(pairs)), pairs[:, 1]] = 5.0
+    layer["x"] = layer["x"].at[:, :experts].set(logits)
+    layer["router"] = jnp.zeros_like(layer["router"]).at[:experts].set(
+        jnp.eye(experts))
+    return {k: v.astype(dtype) for k, v in layer.items()}
+
+
+def _pairs_with(n_here, tokens=FILL_T):
+    """Choices of which exactly ``n_here`` fall on experts 4..8: some
+    tokens with both choices there, some with one, the rest with none,
+    anywhere in token order."""
+    rng = np.random.default_rng(n_here)
+    both = n_here // 3
+    one = n_here - 2 * both
+    none = tokens - both - one
+    assert none >= 0
+    elsewhere = np.array([0, 1, 2, 3, 8, 9, 12, 15])
+    inside = rng.integers(4, 8, both + one)
+    out = rng.integers(0, 8, one + none)
+    first = np.concatenate([inside, elsewhere[out[one:]]])
+    second = np.concatenate([
+        (inside[:both] - 4 + rng.integers(1, 4, both)) % 4 + 4,
+        elsewhere[out[:one]], elsewhere[(out[one:] + 3) % 8]])
+    pairs = np.stack([first, second], axis=1)
+    rng.shuffle(pairs)
+    return pairs
+
+
+# name -> (the router's width, the first held expert, the choices or
+# None for a seeded router, n_used or None, dtype); FILL_HELD are held
+FILLS = {
+    "none_routed_here": (16, 4, _pairs_with(0), 0, jnp.float32),
+    "a_quarter": (16, 4, None, None, jnp.float32),
+    "on_a_chunk_boundary": (16, 4, _pairs_with(2048), 2048, jnp.float32),
+    "one_past_a_chunk_boundary": (16, 4, _pairs_with(2049), 2049,
+                                  jnp.float32),
+    "every_assignment_here": (4, 0, None, 2 * FILL_T, jnp.float32),
+    "a_quarter_bf16": (16, 4, None, None, jnp.bfloat16),
+}
+
+
+def _fill_case(name):
+    experts, offset, pairs, n_used, dtype = FILLS[name]
+    if pairs is None:
+        layer = _expert_layer(7, FILL_T, experts=experts)
+        layer = {k: v.astype(dtype) for k, v in layer.items()}
+    else:
+        layer = _forced_layer(pairs, experts, dtype)
+    args = (layer["x"], layer["router"],
+            layer["gate_up"][offset:offset + FILL_HELD],
+            layer["down"][offset:offset + FILL_HELD])
+    return args, dict(top_k=FILL_K, expert_offset=offset), n_used, dtype
+
+
+@pytest.mark.parametrize("name", list(FILLS))
+def test_every_fill_of_the_buffer_agrees_with_the_dense_oracle(name):
+    """The loops' trip counts follow ``sum(counts)``: nothing routed
+    here, the expected share, a chunk's last row and the next chunk's
+    first, and the whole buffer, forward and the four gradients."""
+    args, kw, n_used, dtype = _fill_case(name)
+    assert moe._CHUNK == 2048 and FILL_T * FILL_K % moe._CHUNK   # a last
+    # chunk that the buffer's rows do not divide is in every case
+    ct = jax.random.normal(jax.random.key(9), args[0].shape)
+
+    def total(fn):
+        def f(*a):
+            y, counts = fn(*a, **kw)
+            return jnp.sum(y.astype(jnp.float32) * ct), (y, counts)
+        return jax.jit(jax.value_and_grad(f, (0, 1, 2, 3), has_aux=True))
+    (_, (y, counts)), got = total(moe.dropless_moe)(*args)
+    (_, (want_y, want_counts)), want = total(moe.dropless_moe_ref)(*args)
+    assert counts.tolist() == want_counts.tolist()
+    if n_used is not None:
+        assert int(jnp.sum(counts)) == n_used
+    else:                                 # about 1/4 of 2 T assignments
+        assert 600 < int(jnp.sum(counts)) < 950
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    f32 = lambda a: np.asarray(a, np.float32)             # noqa: E731
+    np.testing.assert_allclose(
+        f32(y), f32(want_y), rtol=0,
+        atol=tol * max(float(jnp.max(jnp.abs(want_y))), 1e-30))
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and bool(jnp.all(jnp.isfinite(g)))
+        np.testing.assert_allclose(
+            f32(g), f32(w), rtol=0,
+            atol=tol * float(jnp.max(jnp.abs(w.astype(jnp.float32)))))
+    if n_used == 0:
+        assert float(jnp.max(jnp.abs(y))) == 0.0
+
+
+@pytest.mark.parametrize("name", ["none_routed_here", "a_quarter",
+                                  "one_past_a_chunk_boundary",
+                                  "a_quarter_bf16"])
+def test_rows_past_the_rows_in_use_are_zeros_in_every_buffer(name):
+    """Expert order keeps the rows in use first; every buffer the
+    layer's own passes write (the moved rows, the activation, and their
+    gradients) is exact zeros after them, whatever the chunk."""
+    (x, router, gate_up, down), kw, _, dtype = _fill_case(name)
+    gates, experts = moe.route_topk(x, router, FILL_K)
+    routed, counts = moe._route(gates, experts, kw["expert_offset"],
+                                gate_up.shape[0])
+    n = int(routed.n_used)
+    assert n == int(jnp.sum(counts))
+    rows = FILL_T * min(FILL_K, gate_up.shape[0])
+    xs, pull_x = jax.vjp(lambda x: moe._dispatch(x, routed), x)
+    mid = jax.lax.ragged_dot(xs, gate_up, counts)
+    act, pull_mid = jax.vjp(lambda m: moe._gated_silu(m, routed.n_used), mid)
+    ys = jax.lax.ragged_dot(act, down, counts)
+    y, pull_y = jax.vjp(lambda ys, g: moe._combine(ys, g, routed), ys, gates)
+    dys, dgates = pull_y(jnp.ones_like(y))
+    dmid, = pull_mid(jnp.ones_like(act))
+    for label, buf in (("xs", xs), ("act", act), ("dys", dys),
+                       ("dmid", dmid)):
+        assert buf.shape[0] == rows and buf.dtype == dtype, label
+        assert float(jnp.max(jnp.abs(buf[n:].astype(jnp.float32)),
+                             initial=0.0)) == 0.0, label
+        if n:
+            assert float(jnp.min(jnp.max(jnp.abs(buf[:n].astype(
+                jnp.float32)), axis=-1))) > 0.0, label
+    # the gates of assignments held elsewhere get no gradient
+    local = experts - kw["expert_offset"]
+    here = (local >= 0) & (local < gate_up.shape[0])
+    assert float(jnp.max(jnp.abs(jnp.where(here, 0.0, dgates)))) == 0.0
+    assert pull_x(jnp.ones_like(xs))[0].shape == x.shape
+
+
+@pytest.mark.parametrize("fill", [0.0625, 0.25, 1.0])
+def test_the_layer_bench_routes_about_the_share_it_is_asked_for(
+        monkeypatch, fill):
+    """``tools/moe_bench.py`` lifts the held experts' logits until about
+    ``fill`` of the assignments are routed to them."""
+    bench = load_script("moe_bench_t", "tools", "moe_bench.py")
+    for name, value in dict(T=512, H=128, E=16, HELD=4, K=2, F=64).items():
+        monkeypatch.setattr(bench, name, value)
+    x, router, gate_up, down, ct = bench.inputs(fill)
+    assert ct.shape == x.shape == (512, 128)
+    _, counts = moe.dropless_moe(x, router, gate_up, down, top_k=2)
+    assert abs(int(jnp.sum(counts)) / (512 * 2) - fill) < 0.03
 
 
 # ---- the indexer's isolation ---------------------------------------------------------
