@@ -42,6 +42,12 @@ timing, retrace counters — docs/observability.md).
 
 from apex_tpu._version import __version__
 from apex_tpu import comm
+from apex_tpu.telemetry import retrace as _retrace
+
+# the process's set-up account (telemetry/retrace.py): every program
+# traced, lowered or loaded from here to the first optimizer steps is
+# in it; it closes itself after them
+_retrace.process().install()
 
 # Feature-detection registry: the reference gates optional features on
 # "is my CUDA extension importable?" (setup.py --xentropy etc., SURVEY.md §5

@@ -20,6 +20,7 @@ from apex_tpu.amp.policies import (Policy, Properties, opt_level_properties)
 from apex_tpu.amp.scaler import (LossScaleConfig, LossScaleState,
                                  re_anchor, update_state)
 from apex_tpu.amp.wrap import auto_cast, cast_inputs
+from apex_tpu.telemetry.retrace import phased
 from apex_tpu.telemetry.spans import span
 
 Pytree = Any
@@ -127,6 +128,8 @@ class AmpState:
             ))
 
 
+# the casts of the model's tree: one small program a distinct leaf shape
+@phased("apex/amp/initialize")
 def initialize(params: Pytree,
                opt_level: str = "O1",
                half_dtype=jnp.bfloat16,

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 
 from apex_tpu.multi_tensor_apply.packer import BucketPlan
 from apex_tpu.telemetry import _tape
+from apex_tpu.telemetry.retrace import mark_step, phased
 from apex_tpu.telemetry.spans import span
 
 Pytree = Any
@@ -204,6 +205,8 @@ class FusedOptimizerBase:
     # and the model-dtype copy: FusedLAMB says no, and why
     _donate_work = True
 
+    # the plan, the packed parameters, masters and state: once a process
+    @phased("apex/optim/init")
     def __init__(self, params: Pytree, master_weights: Optional[bool] = None,
                  masters: Optional[Pytree] = None,
                  offload_state: bool = False,
@@ -666,6 +669,7 @@ class FusedOptimizerBase:
         ``clip_coef``: optional traced global-norm clip coefficient in
         (0, 1]; folded into the update's grad scaling (see
         ``_fold_clip``) so clipping costs zero extra gradient passes."""
+        mark_step()
         with span("apex/optim/step"):
             return self._step(grads, grad_scale, found_inf, clip_coef)
 

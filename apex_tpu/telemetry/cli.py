@@ -359,11 +359,17 @@ def summarize(path, tail: int = 32, as_json: bool = False,
              for n, c in sorted(counters.items())], out)
     if retraces:
         print("\ncompilation:", file=out)
+        # <process>: every event's seconds by kind; program/<name>: the
+        # outermost events' (wall time); a wrapped function: its traces
         _render_table(
-            ["name", "traces", "retraces", "compile_s"],
+            ["name", "traces", "retraces", "compile_s", "trace_s",
+             "lower_s", "backend_s", "cache"],
             [[n, str(r.get("traces", "-")),
               str(r.get("retraces", "-")),
-              _fmt_cell(r.get("compile_s"))]
+              _fmt_cell(r.get("compile_s")), _fmt_cell(r.get("trace_s")),
+              _fmt_cell(r.get("lower_s")), _fmt_cell(r.get("backend_s")),
+              (f"{r['cache_hits']} hits, {r['cache_misses']} misses"
+               if "cache_hits" in r else str(r.get("cache") or "-"))]
              for n, r in sorted(retraces.items())], out)
     return 0
 

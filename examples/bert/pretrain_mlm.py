@@ -36,6 +36,7 @@ from apex_tpu import amp
 from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
 from apex_tpu.models.bert import BertModel, bert_large
 from apex_tpu.optimizers import FusedLAMB
+from apex_tpu.telemetry import retrace as startup
 from apex_tpu.telemetry.retrace import BACKEND_COMPILE_EVENT, RetraceCounter
 
 # untimed leading steps: the first compiles the programs, the second
@@ -180,6 +181,11 @@ def main(argv=None):
         amp_state = amp.update_scaler(amp_state, found_inf)
         losses.append(loss)
         infs.append(found_inf)
+        # what set-up was made of, once: at the first step during which
+        # nothing was traced or loaded (docs/observability.md)
+        told = startup.process().report_once()
+        if told:
+            print(told)
         if i == WARMUP_STEPS - 1:
             jax.block_until_ready((loss, opt.params))
             compiles0 = retrace.events[BACKEND_COMPILE_EVENT]

@@ -35,6 +35,7 @@ import apex_tpu
 from apex_tpu import amp
 from apex_tpu.models.looped import LoopedDecoder
 from apex_tpu.optimizers import FusedAdam
+from apex_tpu.telemetry import retrace
 
 WARMUP_STEPS = 2
 # the flat Adam step chunked as the BERT example's LAMB step is: one
@@ -140,6 +141,11 @@ def main(argv=None):
         amp_state = amp.update_scaler(amp_state, found_inf)
         losses.append(loss)
         infs.append(found_inf)
+        # what set-up was made of, once: at the first step during which
+        # nothing was traced or loaded (docs/observability.md)
+        told = retrace.process().report_once()
+        if told:
+            print(told)
         if i == WARMUP_STEPS - 1:
             jax.block_until_ready((loss, opt.params))
             t0 = time.perf_counter()

@@ -516,6 +516,226 @@ def test_retrace_counter_monitoring_hook_counts_compiles():
     assert c.traces() == before               # uninstalled: no counting
 
 
+def _compile_two(counter):
+    def alpha(x):
+        return jnp.sin(x) + 1
+
+    def beta(x):
+        return jnp.where(x > 0, jnp.tanh(x), x)     # nested jits inside
+
+    counter.install()
+    try:
+        # apexlint: disable-next=APX302
+        jax.jit(alpha)(jnp.zeros((3, 11)))
+        # apexlint: disable-next=APX302
+        jax.jit(beta)(jnp.zeros((5, 13)))
+    finally:
+        counter.uninstall()
+
+
+def test_retrace_counter_keeps_seconds_and_counts_by_kind():
+    from apex_tpu.telemetry import retrace
+    c = telemetry.RetraceCounter()
+    _compile_two(c)
+    kinds = (retrace.TRACE_EVENT, retrace.LOWER_EVENT,
+             retrace.BACKEND_COMPILE_EVENT)
+    assert all(c.events[k] >= 2 and c.seconds[k] > 0 for k in kinds)
+    # compile_secs is the kinds' sum, no accumulator of its own
+    assert c.compile_secs == pytest.approx(sum(c.seconds[k] for k in kinds))
+    # the tracing of `beta` traced jnp.where and jnp.tanh inside it:
+    # jax counts those too, the kept spans hold the outermost only
+    traced = [sp for sp in c.spans if sp.kind == "trace"]
+    assert c.events[retrace.TRACE_EVENT] > len(traced)
+    assert {"alpha", "beta"} <= {sp.name for sp in traced}
+    assert not {"_where", "tanh"} & {sp.name for sp in c.spans}
+    process, = [r for r in c.records() if r["name"] == "<process>"]
+    assert process["compile_s"] == pytest.approx(c.compile_secs, abs=1e-3)
+    assert {"trace_s", "lower_s", "backend_s", "cache_hits",
+            "cache_misses", "cache_retrieval_s"} <= set(process)
+
+
+def test_retrace_counter_keeps_a_row_a_program_and_each_events_span():
+    from apex_tpu.telemetry import retrace
+    c = telemetry.RetraceCounter()
+    _compile_two(c)
+    rows = c.program_rows()
+    for name in ("alpha", "beta"):
+        # tracing says `alpha`, lowering and the backend `jit(alpha)`
+        row = rows[name]
+        assert row["traces"] == 1
+        assert min(row["trace_s"], row["lower_s"], row["backend_s"]) > 0
+        assert row["compile_s"] == pytest.approx(
+            row["trace_s"] + row["lower_s"] + row["backend_s"])
+        mine = [sp for sp in c.spans if sp.name == name]
+        assert [sp.kind for sp in mine] == ["trace", "lower", "backend"]
+        assert all(a.start <= a.end <= b.start
+                   for a, b in zip(mine, mine[1:]))
+    # one thread's kept spans never overlap: their seconds are wall time
+    assert all(a.end <= b.start for a, b in zip(c.spans, c.spans[1:]))
+    named = {r["name"]: r for r in c.records(step=3)}
+    assert named["program/alpha"]["traces"] == 1
+    assert named["program/alpha"]["step"] == 3
+    assert c.spans_dropped == 0
+    # beyond max_spans the counts go on and the spans stop
+    small = telemetry.RetraceCounter(max_spans=2)
+    _compile_two(small)
+    assert len(small.spans) == 2 and small.spans_dropped > 0
+    assert small.events[retrace.BACKEND_COMPILE_EVENT] >= 2
+
+
+class _Clock:
+    """Stands in for the ``time`` module in telemetry/retrace.py."""
+
+    def __init__(self, now=1000.0):
+        self.now, self.reads = now, 0
+
+    def time(self):
+        self.reads += 1
+        return self.now
+
+    def perf_counter(self):
+        self.reads += 1
+        return self.now
+
+
+def _listeners():
+    from jax._src import monitoring
+    return (monitoring.get_event_time_span_listeners()
+            + monitoring.get_event_duration_listeners()
+            + monitoring.get_event_listeners())
+
+
+def _account(monkeypatch, **caps):
+    """A fresh process account in place of the process's, on a clock
+    the test sets, fed by hand."""
+    from apex_tpu.telemetry import retrace
+    clock = _Clock()
+    monkeypatch.setattr(retrace, "time", clock)
+    account = retrace.ProcessAccount(**caps)
+    monkeypatch.setattr(retrace, "_PROCESS", account)
+    account.install()
+    return retrace, account, clock
+
+
+def _feed(account, kind, name, start, end):
+    from apex_tpu.telemetry import retrace
+    event = {v: k for k, v in retrace.KINDS.items()}[kind]
+    account._on_time_span(event, start, end, fun_name=name)
+
+
+def test_account_until_step_leaves_out_what_came_after_the_mark(monkeypatch):
+    retrace, account, clock = _account(monkeypatch)
+    try:
+        _feed(account, "trace", "step", 1001.0, 1003.0)
+        _feed(account, "lower", "jit(step)", 1003.0, 1004.5)
+        account._on_event(retrace.CACHE_HIT_EVENT)
+        account._on_duration(retrace.CACHE_RETRIEVAL_EVENT, 0.25)
+        _feed(account, "backend", "jit(step)", 1004.5, 1005.0)
+        for at in (1006.0, 1007.0, 1008.0, 1011.0, 1012.0):
+            clock.now = at
+            retrace.mark_step()
+            if at == 1008.0:        # step 2 compiles something more
+                account._on_event(retrace.CACHE_MISS_EVENT)
+                _feed(account, "backend", "jit(late)", 1009.0, 1010.0)
+    finally:
+        account.close()
+    assert account.until_step(5) is None        # never began
+    first = account.until_step(0)
+    assert first["wall_s"] == pytest.approx(6.0)
+    assert (first["trace_s"], first["lower_s"], first["backend_s"]) == \
+        pytest.approx((2.0, 1.5, 0.5))
+    assert (first["cache_hits"], first["cache_misses"]) == (1, 0)
+    assert first["cache_retrieval_s"] == pytest.approx(0.25)
+    assert first["programs"]["step"]["cache"] == "hit"
+    assert account.until_step(2) == {**first, "wall_s": 2.0 + 6.0}
+    later = account.until_step(3)               # after jit(late)
+    assert later["backend_s"] == pytest.approx(1.5)
+    assert later["cache_misses"] == 1 and "late" in later["programs"]
+    assert account.compile_secs == pytest.approx(5.0)
+    # steps 0 and 1 were quiet; step 2 (1008-1011) was not
+    assert account.first_quiet_step() == 0
+    report = account.report()
+    assert "to the first step" in report and "program step" in report
+    assert account.report_once() and account.report_once() is None
+
+
+def test_account_nests_compile_events_under_the_open_phase(monkeypatch):
+    retrace, account, clock = _account(monkeypatch)
+    try:
+        _feed(account, "backend", "jit(before)", 1000.5, 1000.9)
+        clock.now = 1001.0
+        with retrace.phase("apex/test/build"):
+            _feed(account, "trace", "init_state_packed", 1001.5, 1001.75)
+            _feed(account, "backend", "jit(init_state_packed)", 1002.0, 1003.0)
+            clock.now = 1005.0
+        _feed(account, "backend", "jit(after)", 1005.5, 1006.0)
+    finally:
+        account.close()
+    row = account.summary()["phases"]["apex/test/build"]
+    assert row["seconds"] == pytest.approx(4.0)
+    assert row["own_s"] == pytest.approx(4.0 - 0.25 - 1.0)
+    assert (row["trace_s"], row["backend_s"], row["backend_n"]) == \
+        pytest.approx((0.25, 1.0, 1))
+    # a phase that ended after the cut is not in it
+    assert account.summary(before=1004.0)["phases"] == {}
+    # another thread's event in the same seconds is not inside it
+    stray = retrace.CompileSpan("lower", "x", 1002.0, 1002.5, thread=-1)
+    account.spans.append(stray)
+    assert account.summary()["phases"]["apex/test/build"]["lower_s"] == 0
+
+
+def test_account_unregisters_itself_at_its_caps(monkeypatch):
+    retrace, account, clock = _account(monkeypatch, max_steps=3)
+    mine = set(account._listeners)
+    assert mine <= set(_listeners())
+    for _ in range(3):
+        retrace.mark_step()
+    assert not account.open and not mine & set(_listeners())
+    reads = clock.reads
+    retrace.mark_step()                          # one attribute test
+    with retrace.phase("apex/test/late"):
+        pass
+    assert len(account.marks) == 3 and account.phases == []
+    assert clock.reads == reads
+    assert retrace.process() is account and not account.open
+
+    # the other cap: spans.  Full, it drops what comes; its listeners
+    # go at the next mark (inside jax's walk over them is no place)
+    retrace, account, clock = _account(monkeypatch, max_spans=2)
+    mine = set(account._listeners)
+    for i in range(4):
+        _feed(account, "backend", f"jit(p{i})", 1001.0 + i, 1001.5 + i)
+    assert len(account.spans) == 2 and account.spans_dropped == 2
+    assert account.open
+    retrace.mark_step()
+    assert not account.open and account.marks == []
+    assert not mine & set(_listeners())
+
+
+def test_closed_account_costs_a_step_and_a_span_no_clock_read(monkeypatch):
+    from apex_tpu.telemetry import spans
+    params = {"w": jnp.ones((8, 8)), "b": jnp.zeros((8,))}
+    grads = tree_map(jnp.ones_like, params)
+    retrace, account, clock = _account(monkeypatch)
+    monkeypatch.setattr(spans, "time", clock)
+    try:
+        opt = FusedAdam(params, lr=1e-3)         # a phase: start and end
+        assert [p[0] for p in account.phases] == ["apex/optim/init"]
+        reads = clock.reads
+        opt.step(grads)                          # open: the mark's one read
+        assert clock.reads == reads + 1 and len(account.marks) == 1
+    finally:
+        account.close()
+    reads = clock.reads
+    opt.step(grads)
+    with telemetry.span("apex/test/hot"):
+        pass
+    FusedAdam(params, lr=1e-3)
+    amp.initialize(params, opt_level="O2")
+    assert clock.reads == reads
+    assert len(account.marks) == 1 and len(account.phases) == 1
+
+
 # ---------------------------------------------------------------------------
 # lockwatch (the RetraceCounter pattern for locks)
 # ---------------------------------------------------------------------------

@@ -91,3 +91,41 @@ def test_span_with_a_sink_records_parent_and_step():
     with telemetry.span("after"):           # sink gone: no record
         pass
     assert len(got) == 3
+
+
+def test_construction_phases_reach_the_account_and_a_sink(monkeypatch):
+    """``apex/amp/initialize`` and ``apex/optim/init`` (PR 35) are spans
+    like the others, and while the process's set-up account is open
+    their start and end go there too, the programs they load inside."""
+    from apex_tpu import amp
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.telemetry import retrace
+
+    account = retrace.ProcessAccount()
+    monkeypatch.setattr(retrace, "_PROCESS", account)
+    account.install()
+    got = []
+
+    def sink(name, record):
+        got.append((name, record.parent))
+
+    spans.add_sink(sink)
+    try:
+        params = {"w": jnp.ones((16, 24)), "b": jnp.zeros((24,))}
+        params, state = amp.initialize(params, opt_level="O2")
+        opt = FusedAdam(params, masters=state.master_params, lr=1e-3)
+        opt.step(jax.tree_util.tree_map(jnp.ones_like, params))
+    finally:
+        spans.remove_sink(sink)
+        account.close()
+    assert ("apex/amp/initialize", None) in got
+    assert ("apex/optim/init", None) in got
+    assert [p[0] for p in account.phases] == ["apex/amp/initialize",
+                                              "apex/optim/init"]
+    assert len(account.marks) == 1
+    phases = account.until_step(0)["phases"]
+    # the state's one program is loaded inside the constructor
+    assert phases["apex/optim/init"]["backend_n"] >= 1
+    assert phases["apex/optim/init"]["seconds"] >= \
+        phases["apex/optim/init"]["backend_s"] > 0
+    assert phases["apex/amp/initialize"]["seconds"] > 0
