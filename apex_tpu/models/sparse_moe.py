@@ -23,11 +23,18 @@ over the k chosen, and THIS holder's ``experts_held`` experts starting
 at ``expert_offset`` — the part of the layer's result they give goes on
 to the next layer.
 
-Every layer is rematerialised; the one thing a layer keeps between its
-forward and its backward pass beside its input is its selection (int8,
-S x S a sequence, under the checkpoint name ``apex_sparse_select``), so
-that the backward pass recomputes the index scores (it needs them) but
-not the counting passes that turn them into a set.
+Every layer is rematerialised; beside its input a layer keeps two things
+between its forward and its backward pass.  Its selection (int8, S x S
+a sequence, checkpoint name ``apex_sparse_select``), so that the flash
+forward is recomputed without the counting passes that turn scores into
+a set.  And the gradient of ``L_I`` with respect to the indexer's own
+leaves (``apex_sparse_index_grad``: 2.26 M numbers at the 30B-A3B
+widths, 4.5 MB in bfloat16), made in the forward pass where the
+objective's kernel has just written its gradient in the scores
+(``_index_objective``): kept instead of those scores' gradient
+(float32 S x S, 268 MB at 8192), it leaves the backward pass a scaling
+by its cotangent, and the rematerialised pass runs nothing of the
+indexer.
 
 Scopes: ``apex_sparse_attn/{indexer,select,index_loss}``,
 ``apex_moe/{router,dispatch,experts,combine}``, ``apex_swiglu``,
@@ -46,7 +53,8 @@ from apex_tpu.models.looped import rotary_freqs
 from apex_tpu.normalization import FusedLayerNorm, FusedRMSNorm
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb
-from apex_tpu.ops.sparse_index import index_loss, index_scores, select_topk
+from apex_tpu.ops.sparse_index import (index_loss_and_grad, index_scores,
+                                       select_topk)
 from apex_tpu.ops.xentropy import softmax_cross_entropy
 from apex_tpu.transformer import tensor_parallel as tp
 from apex_tpu.transformer.moe import DroplessMoE
@@ -58,6 +66,62 @@ _INIT = nn.initializers.normal(0.02)
 # token of a layer routes to the same ``top_k`` experts.
 _EMBED_INIT = nn.initializers.normal(1.0)
 SELECTION = "apex_sparse_select"
+INDEX_GRAD = "apex_sparse_index_grad"
+
+
+def _scaled(tree, c):
+    """``c`` times every leaf, multiplied in float32, in the leaf's
+    dtype."""
+    return jax.tree_util.tree_map(
+        lambda x: (c * x.astype(jnp.float32)).astype(x.dtype), tree)
+
+
+@jax.custom_vjp
+def _with_gradient(value, theta, dtheta):
+    """``value``, whose gradient with respect to ``theta`` is
+    ``dtheta`` (already computed) and with respect to nothing else."""
+    return value
+
+
+def _with_gradient_fwd(value, theta, dtheta):
+    return value, dtheta
+
+
+def _with_gradient_bwd(dtheta, ct):
+    return None, _scaled(dtheta, ct), None
+
+
+_with_gradient.defvjp(_with_gradient_fwd, _with_gradient_bwd)
+
+
+def _index_objective(scores_of, theta):
+    """-> (scores, objective): ``scores = scores_of(theta)`` and
+    ``objective(key_mask, q, k, lse)`` the indexer's loss ``L_I`` (see
+    ``index_loss``), DIFFERENTIATED WHERE IT IS COMPUTED.  ``theta``,
+    the indexer's leaves, is all ``L_I`` sends a gradient to, and that
+    gradient is linear in the one scalar the backward pass brings.  So
+    the kernel pass that gives the loss also gives its gradient with
+    respect to the scores, ``scores_of``'s pullback takes that to
+    ``dtheta`` there and then, and ``dtheta`` (checkpoint name
+    ``INDEX_GRAD``) is what a rematerialised layer keeps: its backward
+    pass scales it and runs nothing of the indexer.  Everything is
+    evaluated at ``stop_gradient(theta)`` and handed over behind
+    ``stop_gradient``: differentiated THROUGH, the pullback would bring
+    the indexer back into the backward pass.  A program that asks for no
+    gradient never reads ``dtheta``, and the compiler drops the
+    pullback."""
+    scores, pull = jax.vjp(scores_of, jax.lax.stop_gradient(theta))
+
+    def objective(key_mask, q, k, lse):
+        value, g = index_loss_and_grad(scores, key_mask, q, k, lse)
+        dtheta, = pull(g)
+        with jax.named_scope("apex_sparse_attn/index_loss"):
+            rows = scores.shape[0] * scores.shape[1]    # L_I is their mean
+            dtheta = _scaled(dtheta, 1.0 / rows)
+        dtheta = checkpoint_name(jax.lax.stop_gradient(dtheta), INDEX_GRAD)
+        return _with_gradient(jax.lax.stop_gradient(value), theta, dtheta)
+
+    return scores, objective
 
 
 class SparseMoEDecoderLayer(nn.Module):
@@ -108,23 +172,40 @@ class SparseMoEDecoderLayer(nn.Module):
         q, k, v = (jnp.transpose(t, (0, 2, 1, 3)) for t in (q, k, v))
 
         # the indexer reads the layer's input and sends it nothing
-        proj = column(hi * di + di + hi, "index_proj")(      # [q | k | w]
-            jax.lax.stop_gradient(xn))
-        qi, ki, w = jnp.split(proj, [hi * di, hi * di + di], axis=-1)
-        ki = FusedLayerNorm(normalized_shape=di, eps=self.eps,
-                            name="index_k_norm")(ki).astype(self.dtype)
-        qi = fused_apply_rotary_pos_emb(qi.reshape(b, s, hi, di),
-                                        index_freqs)
-        ki = fused_apply_rotary_pos_emb(ki.reshape(b, s, 1, di),
-                                        index_freqs)
-        scores = index_scores(
-            jnp.transpose(qi, (0, 2, 1, 3)), ki[:, :, 0],
-            w.astype(jnp.float32) * (hi ** -0.5 * di ** -0.5))
+        xs = jax.lax.stop_gradient(xn)
+        proj = column(hi * di + di + hi, "index_proj")       # [q | k | w]
+        norm = FusedLayerNorm(normalized_shape=di, eps=self.eps,
+                              name="index_k_norm")
+
+        def indexer(project, normalize):
+            qi, ki, w = jnp.split(project(xs), [hi * di, hi * di + di],
+                                  axis=-1)
+            ki = normalize(ki).astype(self.dtype)
+            qi = fused_apply_rotary_pos_emb(qi.reshape(b, s, hi, di),
+                                            index_freqs)
+            ki = fused_apply_rotary_pos_emb(ki.reshape(b, s, 1, di),
+                                            index_freqs)
+            return index_scores(
+                jnp.transpose(qi, (0, 2, 1, 3)), ki[:, :, 0],
+                w.astype(jnp.float32) * (hi ** -0.5 * di ** -0.5))
+
+        if self.is_initializing():
+            indexer(proj, norm)                 # declares the two leaves
+        (proj, proj_vars), (norm, norm_vars) = proj.unbind(), norm.unbind()
+        theta = {"index_proj": proj_vars["params"],
+                 "index_k_norm": norm_vars["params"]}
+
+        def scores_of(theta):
+            return indexer(
+                lambda x: proj.apply({"params": theta["index_proj"]}, x),
+                lambda x: norm.apply({"params": theta["index_k_norm"]}, x))
+
+        scores, objective = _index_objective(scores_of, theta)
         selected = checkpoint_name(select_topk(scores, self.index_topk),
                                    SELECTION)
         attn, lse = flash_attention(q, k, v, causal=True,
                                     key_mask=selected, return_lse=True)
-        l_index = index_loss(scores, selected, q, k, lse)
+        l_index = objective(selected, q, k, lse)
         attn = jnp.transpose(attn, (0, 2, 1, 3)).reshape(b, s, nh * d)
         x = x + tp.RowParallelLinear(
             nh * d, h, bias=False, input_is_parallel=True,
@@ -177,7 +258,8 @@ class SparseMoEDecoder(nn.Module):
         index_freqs = rotary_freqs(s, self.index_head_dim, self.rope_theta)
         layer = nn.remat(
             SparseMoEDecoderLayer,
-            policy=jax.checkpoint_policies.save_only_these_names(SELECTION))
+            policy=jax.checkpoint_policies.save_only_these_names(
+                SELECTION, INDEX_GRAD))
         index_losses, counts = [], []
         for i in range(self.num_layers):
             x, (l_index, c) = layer(

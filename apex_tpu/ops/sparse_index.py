@@ -31,6 +31,10 @@ Three ops, one per line above, none of which ever holds a
   averages them in VMEM and folds the tile into each row's divergence;
   the same pass writes the gradient with respect to ``I``
   (softmax_S(I) - p), so the backward is a scaling.
+  ``index_loss_and_grad`` hands out both results of that one pass, for
+  a caller that pulls the gradient back to the scorer's few parameters
+  where the loss is computed and keeps that instead of recomputing the
+  pass (``models/sparse_moe.py``).
 
 Scopes: ``apex_sparse_attn/indexer`` (scores, forward and backward),
 ``apex_sparse_attn/select``, ``apex_sparse_attn/index_loss``.
@@ -444,6 +448,17 @@ def _index_loss_bwd(scale, g_scores, ct):
 _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 
+def _loss_operands(scores, key_mask, q, k, lse, scale):
+    """``_index_loss``'s arguments: the main attention detached (it is
+    the target, not a participant), q and k in one dtype."""
+    q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
+    if q.dtype != k.dtype:
+        dt = jnp.promote_types(q.dtype, k.dtype)
+        q, k = q.astype(dt), k.astype(dt)
+    sc = scale if scale is not None else _default_scale(q.shape[-1])
+    return scores, key_mask, q, k, lse, sc
+
+
 @jax.named_scope("apex_sparse_attn/index_loss")
 def index_loss(scores, key_mask, q, k, lse, scale=None):
     """The indexer's objective: the mean over queries of
@@ -456,12 +471,20 @@ def index_loss(scores, key_mask, q, k, lse, scale=None):
     ``flash_attention(key_mask=..., return_lse=True)`` returned for
     them.  Differentiable in ``scores`` alone: the main attention is
     the target, not a participant."""
-    q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
-    if q.dtype != k.dtype:
-        dt = jnp.promote_types(q.dtype, k.dtype)
-        q, k = q.astype(dt), k.astype(dt)
-    sc = scale if scale is not None else _default_scale(q.shape[-1])
-    return _index_loss(scores, key_mask, q, k, lse, sc)
+    return _index_loss(*_loss_operands(scores, key_mask, q, k, lse, scale))
+
+
+@jax.named_scope("apex_sparse_attn/index_loss")
+def index_loss_and_grad(scores, key_mask, q, k, lse, scale=None):
+    """``index_loss``'s value AND ``g`` (B, S, S) float32, both from its
+    one kernel pass: ``g = softmax_{S_t}(I_t) - p_t`` on the selected
+    keys and 0 elsewhere, the gradient with respect to ``scores`` of
+    the SUM of the rows' divergences.  The loss is their mean, so
+    ``dL_I/dI = g / (B * S)``: that factor is the caller's to apply, to
+    whatever small thing it pulls ``g`` back to rather than to a
+    (B, S, S) array.  Neither result is differentiable."""
+    return _index_loss_fwd(*_loss_operands(
+        jax.lax.stop_gradient(scores), key_mask, q, k, lse, scale))
 
 
 def index_loss_ref(scores, key_mask, q, k, scale=None):

@@ -178,3 +178,25 @@ def test_the_indexers_objective_agrees_with_the_oracle(h, hk, s, d, topk):
     # the main attention is the target, not a participant
     dq = jax.grad(lambda q: si.index_loss(scores, mask, q, k, lse))(q)
     assert float(jnp.max(jnp.abs(dq))) == 0.0
+
+
+def test_loss_and_grad_are_the_objectives_one_pass_and_send_nothing_back():
+    """``index_loss_and_grad``: the value is ``index_loss``'s, ``g`` its
+    gradient in the scores times the number of rows, and neither is
+    differentiable in anything."""
+    b, s = 2, 192
+    qi, ki, w, _ = _indexer(b, 2, s, 16)
+    q, k, v, _, _ = _problem(b, 4, 2, s, 32, seed=3)
+    scores = si.index_scores(qi, ki, w)
+    mask = si.select_topk(scores, 16)
+    _, lse = flash_attention(q, k, v, causal=True, key_mask=mask,
+                             return_lse=True)
+    want, want_grad = jax.value_and_grad(
+        lambda x: si.index_loss(x, mask, q, k, lse))(scores)
+    got, g = si.index_loss_and_grad(scores, mask, q, k, lse)
+    assert float(got) == float(want)
+    np.testing.assert_allclose(g / (b * s), want_grad, rtol=1e-6, atol=0)
+    for i in range(2):
+        d = jax.grad(lambda x: jnp.sum(si.index_loss_and_grad(
+            x, mask, q, k, lse)[i]))(scores)
+        assert float(jnp.max(jnp.abs(d))) == 0.0

@@ -20,8 +20,9 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from apex_tpu import amp  # noqa: E402
-from apex_tpu.models import SparseMoEDecoder  # noqa: E402
+from apex_tpu.models import SparseMoEDecoder, sparse_moe  # noqa: E402
 from apex_tpu.optimizers import FusedAdam  # noqa: E402
+from apex_tpu.ops import sparse_index  # noqa: E402
 from apex_tpu.transformer import moe  # noqa: E402
 from benchmarks import weights  # noqa: E402
 from benchmarks.reference import keye_vl2_30b_a3b_adamw as reference  # noqa: E402
@@ -423,6 +424,18 @@ def test_the_layer_bench_routes_about_the_share_it_is_asked_for(
 
 # ---- the indexer's isolation ---------------------------------------------------------
 
+REMAT = pytest.mark.parametrize("remat", [True, False],
+                                ids=["remat", "no_remat"])
+
+
+def _layers_as_written(monkeypatch, remat):
+    """``remat`` False: the decoder's layers run as plain modules, so a
+    test holds what the model computes and not what rematerialisation
+    makes of it."""
+    if not remat:
+        monkeypatch.setattr(sparse_moe.nn, "remat", lambda cls, **kw: cls)
+
+
 def _grads_of(which):
     params, tokens, labels = seeded()
     model = model_for()
@@ -431,18 +444,68 @@ def _grads_of(which):
             params)
 
 
-def test_the_language_loss_sends_the_indexer_no_gradient():
+@REMAT
+def test_the_language_loss_sends_the_indexer_no_gradient(monkeypatch, remat):
+    _layers_as_written(monkeypatch, remat)
     for leaf, g in flat(_grads_of("lm_loss")).items():
         if any(name in leaf for name in INDEXER):
             assert float(jnp.max(jnp.abs(g))) == 0.0, leaf
 
 
-def test_the_indexers_objective_sends_no_gradient_elsewhere():
+@REMAT
+def test_the_indexers_objective_sends_no_gradient_elsewhere(monkeypatch,
+                                                            remat):
+    _layers_as_written(monkeypatch, remat)
     for leaf, g in flat(_grads_of("index_loss")).items():
         if any(name in leaf for name in INDEXER):
             assert float(jnp.max(jnp.abs(g))) > 0.0, leaf
         else:
             assert float(jnp.max(jnp.abs(g))) == 0.0, leaf
+
+
+def _straight_objective(scores_of, theta):
+    """``sparse_moe._index_objective`` as ``jax.grad`` would have it:
+    the scores differentiable in the leaves, the loss in the scores."""
+    scores = scores_of(theta)
+    return scores, lambda key_mask, q, k, lse: sparse_index.index_loss(
+        scores, key_mask, q, k, lse)
+
+
+def _losses_and_grads(ct=1.0, **kw):
+    params, tokens, labels = seeded()
+    model = model_for(**kw)
+
+    def scaled(p):
+        loss, aux = model.loss({"params": p}, tokens, labels)
+        return ct * loss, aux
+    (_, aux), grads = jax.jit(jax.value_and_grad(scaled, has_aux=True))(
+        params)
+    return aux, flat(grads)
+
+
+@REMAT
+def test_the_gradient_kept_in_the_forward_pass_is_the_straight_one(
+        monkeypatch, remat):
+    """The objective's parameter gradient is made where the objective
+    is computed and scaled by the cotangent afterwards: both losses and
+    every leaf's gradient are those of differentiating straight through
+    ``index_loss``, and the indexer's leaves follow the cotangent (a
+    loss scale, the objective's weight) exactly."""
+    _layers_as_written(monkeypatch, remat)
+    aux, grads = _losses_and_grads()
+    _, scaled = _losses_and_grads(ct=2.0 ** 15, index_loss_weight=0.5)
+    monkeypatch.setattr(sparse_moe, "_index_objective", _straight_objective)
+    aux0, grads0 = _losses_and_grads()
+    for name in ("lm_loss", "index_loss"):
+        np.testing.assert_allclose(aux[name], aux0[name], rtol=1e-6)
+    assert grads.keys() == grads0.keys()
+    for leaf, g0 in grads0.items():
+        top = float(jnp.max(jnp.abs(g0)))
+        assert top > 0.0, leaf
+        assert float(jnp.max(jnp.abs(grads[leaf] - g0))) <= 1e-6 * top, leaf
+        if any(name in leaf for name in INDEXER):
+            np.testing.assert_array_equal(scaled[leaf],
+                                          2.0 ** 14 * grads[leaf], leaf)
 
 
 def test_selecting_every_key_is_dense_causal_attention():

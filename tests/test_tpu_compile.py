@@ -22,8 +22,10 @@ worker imports every test file, and only the worker that RUNS this
 file may load it.  Keep all such tests in this ONE file.
 """
 
+import collections
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -176,6 +178,57 @@ def test_index_scores_selection_and_objective(one_chip):
         _compile(loss, one_chip, square, ((1, S8K, S8K), jnp.int8),
                  ((1, 32, S8K, 128), BF16), ((1, 4, S8K, 128), BF16),
                  ((1, 32, S8K), F32)), "apex_index_loss")
+
+
+def _kernel_calls(text):
+    """Mosaic kernel name -> its custom-call instructions in a compiled
+    program's text."""
+    return collections.Counter(re.findall(
+        r'%([A-Za-z_]\w*?)(?:\.\d+)* = [^\n]*'
+        r'custom_call_target="tpu_custom_call"', text))
+
+
+# the same program at the parent of PR 36, where the rematerialised pass
+# ran the index scores and the objective's kernel a second time
+_TWO_LAYER_GRAD_TEMP_BEFORE = 2_304_238_592
+
+
+def test_a_rematerialised_layer_runs_each_indexer_kernel_once(one_chip):
+    """Two layers of the expert decoder at the cell's widths, loss and
+    gradient: the objective's parameter gradient is made in the forward
+    pass and kept (``models/sparse_moe.py:_index_objective``), so the
+    rematerialised pass holds nothing of the indexer, a program that
+    asks for no gradient holds nothing of its backward, and the step's
+    temporaries are not above what they were."""
+    from apex_tpu.models import SparseMoEDecoder
+    layers = 2
+    model = SparseMoEDecoder(
+        vocab_size=18992, hidden_size=2048, num_heads=32, num_kv_heads=4,
+        head_dim=128, num_layers=layers, moe_ffn_hidden_size=768,
+        num_experts=128, experts_held=8, top_k=8, index_heads=16,
+        index_head_dim=64, index_topk=2048, dtype=BF16)
+    few = jnp.zeros((1, 128), I32)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, BF16, sharding=one_chip),
+        jax.eval_shape(lambda: model.init(jax.random.key(0), few,
+                                          few)["params"]))
+    tokens = jax.ShapeDtypeStruct((1, S8K), I32, sharding=one_chip)
+
+    def loss(p, t, y):
+        return model.loss({"params": p}, t, y)
+    step = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        params, tokens, tokens).compile()
+    calls = _kernel_calls(step.as_text())
+    for name in ("apex_index_loss", "apex_index_scores_fwd",
+                 "apex_index_scores_bwd", "apex_index_select"):
+        assert calls[name] == layers, (name, calls)
+    assert calls["apex_flash_attention_fwd"] == 2 * layers
+    assert (step.memory_analysis().temp_size_in_bytes
+            <= _TWO_LAYER_GRAD_TEMP_BEFORE)
+    forward = _kernel_calls(jax.jit(loss).lower(
+        params, tokens, tokens).compile().as_text())
+    assert forward["apex_index_loss"] == layers
+    assert forward["apex_index_scores_bwd"] == 0, forward
 
 
 def test_dropless_experts_are_grouped_products(one_chip):
