@@ -558,10 +558,11 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
     b, h, sq, sk, d, dp, bq, bk, sqp, skp = _geom(q, k)
     nq, nk = sqp // bq, skp // bk
     hk = k.shape[1]
+    dv, dvp = v.shape[3], _round_up(v.shape[3], _LANES)
 
     q3 = _pad_head(_pad_seq(q, sqp), dp).reshape(b * h, sqp, dp)
     k3 = _pad_head(_pad_seq(k, skp), dp).reshape(b * hk, skp, dp)
-    v3 = _pad_head(_pad_seq(v, skp), dp).reshape(b * hk, skp, dp)
+    v3 = _pad_head(_pad_seq(v, skp), dvp).reshape(b * hk, skp, dvp)
 
     # the index maps are written over (i, j, kk); on the flattened
     # causal grid (nk > 1) ``at`` looks (j, kk) up in the plan's vectors
@@ -574,7 +575,7 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
     in_specs = [
         pl.BlockSpec((1, bq, dp), at(lambda i, j, kk: (i, j, 0))),
         pl.BlockSpec((1, bk, dp), at(_kv_idx)),
-        pl.BlockSpec((1, bk, dp), at(_kv_idx)),
+        pl.BlockSpec((1, bk, dvp), at(_kv_idx)),
     ]
     args = [q3, k3, v3]
     if rate > 0.0:
@@ -597,9 +598,9 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
             (1, bq, bk), at(lambda i, j, kk: (i // h, j, kk))))
         args.append(_sel_input(key_mask, sqp, skp))
 
-    out_specs = [pl.BlockSpec((1, bq, dp),
+    out_specs = [pl.BlockSpec((1, bq, dvp),
                               at(lambda i, j, kk: (i, j, 0)))]
-    out_shape = [jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((b * h, sqp, dvp), q.dtype)]
     if need_lse:
         out_specs.append(
             pl.BlockSpec((1, bq, _LANES), at(lambda i, j, kk: (i, j, 0))))
@@ -617,11 +618,11 @@ def _fwd_pallas(q, k, v, scale, causal, segment_ids, need_lse=True,
         scratch = [
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, dp), jnp.float32),
+            pltpu.VMEM((bq, dvp), jnp.float32),
         ]
     outs = _launch(kernel, "apex_flash_attention_fwd", (b * h, nq, nk),
                    in_specs, out_specs, out_shape, scratch, args, steps)
-    o = outs[0].reshape(b, h, sqp, dp)[:, :, :sq, :d]
+    o = outs[0].reshape(b, h, sqp, dvp)[:, :, :sq, :dv]
     return o, (outs[1] if need_lse else None)
 
 
@@ -770,10 +771,11 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, segment_ids,
         seed_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
         seed_args = [jnp.asarray(seed, jnp.int32).reshape(1)]
 
+    dvp = _round_up(v.shape[3], _LANES)
     q3 = _pad_head(_pad_seq(q, sqp), dp).reshape(b * h, sqp, dp)
     k3 = _pad_head(_pad_seq(k, skp), dp).reshape(b * hk, skp, dp)
-    v3 = _pad_head(_pad_seq(v, skp), dp).reshape(b * hk, skp, dp)
-    do3 = _pad_head(_pad_seq(do, sqp), dp).reshape(b * h, sqp, dp)
+    v3 = _pad_head(_pad_seq(v, skp), dvp).reshape(b * hk, skp, dvp)
+    do3 = _pad_head(_pad_seq(do, sqp), dvp).reshape(b * h, sqp, dvp)
 
     # di = rowsum(do * o): plain-XLA elementwise; both di and the saved
     # one-lane lse are broadcast to the kernel's 128-lane layout so
@@ -814,8 +816,8 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, segment_ids,
     base_specs = [
         pl.BlockSpec((1, bq, dp), at(lambda i, j, kk: (i, j, 0))),
         pl.BlockSpec((1, bk, dp), at(_kv_idx)),
-        pl.BlockSpec((1, bk, dp), at(_kv_idx)),
-        pl.BlockSpec((1, bq, dp), at(lambda i, j, kk: (i, j, 0))),
+        pl.BlockSpec((1, bk, dvp), at(_kv_idx)),
+        pl.BlockSpec((1, bq, dvp), at(lambda i, j, kk: (i, j, 0))),
         pl.BlockSpec((1, bq, _LANES), at(lambda i, j, kk: (i, j, 0))),
         pl.BlockSpec((1, bq, _LANES), at(lambda i, j, kk: (i, j, 0))),
     ]
@@ -852,8 +854,8 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, segment_ids,
     kv_specs = [
         pl.BlockSpec((1, bq, dp), at(_q_idx_kv)),
         pl.BlockSpec((1, bk, dp), at(lambda i, kk, j: (i, kk, 0))),
-        pl.BlockSpec((1, bk, dp), at(lambda i, kk, j: (i, kk, 0))),
-        pl.BlockSpec((1, bq, dp), at(_q_idx_kv)),
+        pl.BlockSpec((1, bk, dvp), at(lambda i, kk, j: (i, kk, 0))),
+        pl.BlockSpec((1, bq, dvp), at(_q_idx_kv)),
         pl.BlockSpec((1, bq, _LANES), at(_q_idx_kv)),
         pl.BlockSpec((1, bq, _LANES), at(_q_idx_kv)),
     ]
@@ -874,16 +876,16 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, segment_ids,
         "apex_flash_attention_dkv", (b * hk, nk, g * nq),
         seed_specs + kv_specs,
         [pl.BlockSpec((1, bk, dp), at(lambda i, kk, t: (i, kk, 0))),
-         pl.BlockSpec((1, bk, dp), at(lambda i, kk, t: (i, kk, 0)))],
+         pl.BlockSpec((1, bk, dvp), at(lambda i, kk, t: (i, kk, 0)))],
         [jax.ShapeDtypeStruct((b * hk, skp, dp), k.dtype),
-         jax.ShapeDtypeStruct((b * hk, skp, dp), v.dtype)],
+         jax.ShapeDtypeStruct((b * hk, skp, dvp), v.dtype)],
         [pltpu.VMEM((bk, dp), jnp.float32),
-         pltpu.VMEM((bk, dp), jnp.float32)],
+         pltpu.VMEM((bk, dvp), jnp.float32)],
         seed_args + args, dkv_steps)
 
     dq = dq.reshape(b, h, sqp, dp)[:, :, :sq, :d]
     dk = dk.reshape(b, hk, skp, dp)[:, :, :sk, :d]
-    dv = dv.reshape(b, hk, skp, dp)[:, :, :sk, :d]
+    dv = dv.reshape(b, hk, skp, dvp)[:, :, :sk, :v.shape[3]]
     return dq, dk, dv
 
 
@@ -1046,6 +1048,11 @@ def flash_attention(q, k, v, causal=False, scale=None,
     and the backward drops the same elements.  Callers own the
     train/eval switch: pass rate 0 (or no seed) when not training.
 
+    v may have a head width of its own, (B, HK, Sk, Dv) (latent
+    attention: scores at q/k's width, values at another): the output
+    and the dv accumulator are at Dv's, q.k at D's, v is padded to a
+    lane multiple of its own width only.
+
     key_mask: optional (B, Sq, Sk) per-query key selection, non-zero
     where query row t attends key s, the same for every head (learned
     sparse attention: ``ops/sparse_index.py`` makes one).  The softmax
@@ -1060,10 +1067,14 @@ def flash_attention(q, k, v, causal=False, scale=None,
     their selected keys, constant under differentiation.
     """
     h, hk = q.shape[1], k.shape[1]
-    if h % hk or v.shape[1] != hk:
+    if v.shape[:3] != k.shape[:3] or q.shape[-1] != k.shape[-1]:
+        raise ValueError(
+            f"flash_attention: k {k.shape} and v {v.shape} must share "
+            f"(B, HK, Sk), and q {q.shape} k's head width")
+    if h % hk:
         raise ValueError(
             f"flash_attention: q heads ({h}) must be a multiple of kv "
-            f"heads ({hk}, v: {v.shape[1]})")
+            f"heads ({hk})")
     rate = float(dropout_rate)
     if not 0.0 <= rate < 1.0:
         raise ValueError(

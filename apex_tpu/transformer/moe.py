@@ -225,24 +225,57 @@ def moe_ref(x, router, w1, w2, capacity, activation=jax.nn.gelu):
 # dropless top-k experts, a holder's share
 # ---------------------------------------------------------------------------
 
-def route_topk(x, router, top_k: int, norm_topk_prob: bool = True):
+def route_topk(x, router, top_k: int, norm_topk_prob: bool = True, *,
+               score: str = "softmax", selection_bias=None,
+               routed_scale: float = 1.0):
     """x (T, H), router (H, E) -> (gates (T, k) float32, experts (T, k)
-    int32): the ``top_k`` largest of a float32 softmax over ALL E
-    experts, renormalised over the k chosen when ``norm_topk_prob`` —
-    whoever holds them."""
+    int32) over ALL E experts, whoever holds them.  ``score``
+    ``"softmax"``: the ``top_k`` largest of a float32 softmax,
+    renormalised over the k chosen when ``norm_topk_prob``.
+    ``"sigmoid"`` (DeepSeek-V3's router): s = sigmoid(logits) in
+    float32; the k chosen are the largest of s + ``selection_bias``
+    (E,), which picks and does not weigh (no gradient reaches it); the
+    gates are their s, renormalised over the k when ``norm_topk_prob``
+    (plus 1e-20), times ``routed_scale``."""
     with jax.named_scope("apex_moe/router"):
         logits = jnp.dot(x, router.astype(x.dtype),
                          preferred_element_type=jnp.float32,
                          precision=matmul_precision(x.dtype))
-        probs = jax.nn.softmax(logits, axis=-1)
-        _, experts = jax.lax.top_k(jax.lax.stop_gradient(probs), top_k)
+        if score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        elif score == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"route_topk: unknown score {score!r}")
+        pick = jax.lax.stop_gradient(probs)
+        if selection_bias is not None:
+            pick = pick + jax.lax.stop_gradient(
+                selection_bias.astype(jnp.float32))
+        _, experts = jax.lax.top_k(pick, top_k)
         # the chosen probabilities by a one-hot select, not by top_k's
         # own values: those transpose to a scatter
         chosen = experts[..., None] == jnp.arange(probs.shape[-1])
         gates = jnp.sum(jnp.where(chosen, probs[:, None, :], 0.0), axis=-1)
         if norm_topk_prob:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            total = jnp.sum(gates, axis=-1, keepdims=True)
+            gates = gates / (total + 1e-20 if score == "sigmoid" else total)
+        if routed_scale != 1.0:
+            gates = gates * routed_scale
     return gates, experts.astype(jnp.int32)
+
+
+def _shared_expert(x, gate_up, down):
+    """The expert every token goes through: down(silu(gate x) * up x),
+    gate_up (H, 2F) = [gate | up]; float32 sum of products, the
+    activation in float32, x's dtype out."""
+    with jax.named_scope("apex_moe/shared"):
+        prec = matmul_precision(x.dtype)
+        mid = jnp.dot(x, gate_up.astype(x.dtype),
+                      preferred_element_type=jnp.float32, precision=prec)
+        gate, up = jnp.split(mid, 2, axis=-1)
+        return jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype),
+                       down.astype(x.dtype),
+                       preferred_element_type=jnp.float32, precision=prec)
 
 
 def _take(x, idx):
@@ -492,7 +525,9 @@ def _route(gates, experts, expert_offset, held):
 
 
 def dropless_moe(x, router, gate_up, down, *, top_k: int,
-                 expert_offset: int = 0, norm_topk_prob: bool = True):
+                 expert_offset: int = 0, norm_topk_prob: bool = True,
+                 score: str = "softmax", selection_bias=None,
+                 routed_scale: float = 1.0, shared=None):
     """One holder's share of a dropless top-k expert layer.
 
     x (T, H); router (H, E) over ALL E experts; this holder's experts
@@ -504,16 +539,22 @@ def dropless_moe(x, router, gate_up, down, *, top_k: int,
                g[t, e] * down_e( silu(gate_e x[t]) * up_e x[t] )
 
     with g from ``route_topk`` (normalised over all k chosen experts,
-    held here or not), and counts[e] the tokens routed to each held
-    expert.  No token is dropped: the assignments routed here are
-    sorted by expert into a buffer of T * min(k, held) rows (all that
-    can arrive), and the moves, the activation, the two grouped
-    products and their backward passes run over the rows in use
+    held here or not; ``score``, ``selection_bias`` and
+    ``routed_scale`` are its), and counts[e] the tokens routed to each
+    held expert.  ``shared`` = (gate_up (H, 2F'), down (F', H)): an
+    expert every token goes through, which this holder computes for
+    all of its tokens and adds (``apex_moe/shared``).  No token is
+    dropped: the assignments routed here are sorted by expert into a
+    buffer of T * min(k, held) rows (all that can arrive), and the
+    moves, the activation, the two grouped products and their backward
+    passes run over the rows in use
     (``sum(counts)``, in chunks of ``_CHUNK``): the layer costs what is
     routed to it, up to the whole buffer when everything is.
     What the other holders' experts add is left out: the holders' parts
     sum to the whole layer."""
-    gates, experts = route_topk(x, router, top_k, norm_topk_prob)
+    gates, experts = route_topk(x, router, top_k, norm_topk_prob,
+                                score=score, selection_bias=selection_bias,
+                                routed_scale=routed_scale)
     with jax.named_scope("apex_moe/dispatch"):
         routed, counts = _route(gates, experts, expert_offset,
                                 gate_up.shape[0])
@@ -528,14 +569,21 @@ def dropless_moe(x, router, gate_up, down, *, top_k: int,
                                 precision=prec)
     with jax.named_scope("apex_moe/combine"):
         y = _combine(ys, gates, routed)
+    if shared is not None:
+        y = (y.astype(jnp.float32) + _shared_expert(x, *shared)).astype(
+            y.dtype)
     return y, counts
 
 
 def dropless_moe_ref(x, router, gate_up, down, *, top_k: int,
-                     expert_offset: int = 0, norm_topk_prob: bool = True):
+                     expert_offset: int = 0, norm_topk_prob: bool = True,
+                     score: str = "softmax", selection_bias=None,
+                     routed_scale: float = 1.0, shared=None):
     """Dense oracle of ``dropless_moe``: every held expert applied to
     every token, weighted by its gate where chosen."""
-    gates, experts = route_topk(x, router, top_k, norm_topk_prob)
+    gates, experts = route_topk(x, router, top_k, norm_topk_prob,
+                                score=score, selection_bias=selection_bias,
+                                routed_scale=routed_scale)
     held = gate_up.shape[0]
     xf = x.astype(jnp.float32)
     hi = jax.lax.Precision.HIGHEST
@@ -550,13 +598,22 @@ def dropless_moe_ref(x, router, gate_up, down, *, top_k: int,
                       precision=hi)
         y = y + g[:, None] * out
         counts.append(jnp.sum(chosen))
+    if shared is not None:
+        gate, up = jnp.split(jnp.dot(xf, shared[0].astype(jnp.float32),
+                                     precision=hi), 2, axis=-1)
+        y = y + jnp.dot(jax.nn.silu(gate) * up,
+                        shared[1].astype(jnp.float32), precision=hi)
     return y.astype(x.dtype), jnp.stack(counts).astype(jnp.int32)
 
 
 class DroplessMoE(nn.Module):
     """``dropless_moe`` with its parameters: ``router`` (H, num_experts),
     ``gate_up`` (experts_held, H, 2 * ffn_hidden_size), ``down``
-    (experts_held, ffn_hidden_size, H), float32, normal(0.02).
+    (experts_held, ffn_hidden_size, H), float32, normal(0.02).  With
+    ``score`` ``"sigmoid"`` also ``selection_bias`` (num_experts,),
+    zeros, which the gradient does not reach (a balancing rule would
+    write it between steps); with ``shared_ffn_hidden_size`` also
+    ``shared_gate_up`` (H, 2 * that) and ``shared_down``.
     ``__call__(x (T, H)) -> (y, counts)``."""
     hidden_size: int
     ffn_hidden_size: int
@@ -565,6 +622,9 @@ class DroplessMoE(nn.Module):
     top_k: int
     expert_offset: int = 0
     norm_topk_prob: bool = True
+    score: str = "softmax"
+    routed_scale: float = 1.0
+    shared_ffn_hidden_size: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -580,6 +640,17 @@ class DroplessMoE(nn.Module):
                              (self.experts_held, h, 2 * f), jnp.float32)
         down = self.param("down", init, (self.experts_held, f, h),
                           jnp.float32)
+        bias = shared = None
+        if self.score == "sigmoid":
+            bias = self.param("selection_bias", nn.initializers.zeros,
+                              (self.num_experts,), jnp.float32)
+        if self.shared_ffn_hidden_size:
+            fs = self.shared_ffn_hidden_size
+            shared = (self.param("shared_gate_up", init, (h, 2 * fs),
+                                 jnp.float32),
+                      self.param("shared_down", init, (fs, h), jnp.float32))
         return dropless_moe(x, router, gate_up, down, top_k=self.top_k,
                             expert_offset=self.expert_offset,
-                            norm_topk_prob=self.norm_topk_prob)
+                            norm_topk_prob=self.norm_topk_prob,
+                            score=self.score, selection_bias=bias,
+                            routed_scale=self.routed_scale, shared=shared)

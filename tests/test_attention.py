@@ -1033,3 +1033,39 @@ def test_f32_attention_is_its_own_dispatch_family(monkeypatch):
     want = A.attention_ref(qf, kf, vf, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", [
+    # (cap, s, d, dv, kv heads, causal): latent attention's widths
+    # scaled down, one block and several (the generic and the nk == 1
+    # forward), grouped kv heads, dv wider than d
+    (None, 96, 48, 32, 2, True),
+    ("128", 320, 48, 32, 2, True),
+    ("128", 256, 64, 160, 1, False),
+], ids=["one_block", "multiblock_causal", "dv_wider"])
+def test_flash_attention_with_a_value_width_of_its_own(case, monkeypatch):
+    """v at dv != d: the output and the dv accumulator at dv, q.k at d,
+    against the oracle, forward and the three gradients."""
+    cap, s, d, dv, hk, causal = case
+    if cap:
+        monkeypatch.setenv("APEX_TPU_ATTN_BLOCK_CAP", cap)
+    ks = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(ks[0], (1, 4, s, d))
+    k = jax.random.normal(ks[1], (1, hk, s, d))
+    v = jax.random.normal(ks[2], (1, hk, s, dv))
+    ct = jax.random.normal(ks[3], (1, 4, s, dv))
+    o = attn.flash_attention(q, k, v, causal)
+    assert o.shape == (1, 4, s, dv)
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(attn.attention_ref(q, k, v, causal=causal)),
+        rtol=1e-5, atol=1e-5)
+
+    def total(fn, **kw):
+        return lambda *a: jnp.sum(fn(*a, causal=causal, **kw) * ct)
+    got = jax.grad(total(attn.flash_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(total(attn.attention_ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(got, want):
+        assert a.shape == b_.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-4, atol=5e-5)
+

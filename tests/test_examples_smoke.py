@@ -630,6 +630,16 @@ def test_gpt_moe_tiny(capsys):
     assert "4/16 experts top-2" in out and "step time" in out
 
 
+def test_gpt_kimi_linear_tiny(capsys):
+    example = runpy.run_path("examples/gpt/train_kimi_linear.py")
+    summary = example["main"](["--cpu", "--steps", "3", "--layers", "4"])
+    out = capsys.readouterr().out
+    assert "hybrid linear-attention expert decoder L4" in out
+    assert "4/16 experts top-2 + shared" in out and "step time" in out
+    assert summary["found_inf"] == 0
+    assert len(summary["expert_counts"]) == 3       # layers 2-4 hold experts
+
+
 def test_train_tp_converges(capsys):
     _run("examples/simple/train_tp.py", [])
     assert "OK: loss" in capsys.readouterr().out

@@ -116,6 +116,28 @@ def test_flash_attention_fwd_bwd(one_chip, shape, causal):
                     "apex_flash_attention_dq", "apex_flash_attention_dkv")
 
 
+def test_flash_attention_latent_widths_fwd_bwd(one_chip):
+    """Latent attention's call: scores at 192, values at 128, causal,
+    4096 positions."""
+    from apex_tpu.ops.attention import flash_attention
+    q = ((1, 32, 4096, 192), BF16)
+    v = ((1, 32, 4096, 128), BF16)
+    _assert_kernels(_compile(_grads(
+        lambda q, k, v: flash_attention(q, k, v, True), 3), one_chip, q, q, v),
+        "apex_flash_attention_fwd", "apex_flash_attention_dq",
+        "apex_flash_attention_dkv")
+
+
+def test_kda_scan_fwd_bwd_at_the_cell_width(one_chip):
+    """The chunked delta rule at 32 heads of 128 and 4096 positions,
+    forward and backward: both kernels, and the program fits."""
+    from apex_tpu.ops.kda import chunk_kda
+    a = ((1, 32, 4096, 128), BF16)
+    txt = _compile(_grads(chunk_kda, 5), one_chip, a, a, a,
+                   ((1, 32, 4096, 128), F32), ((1, 32, 4096), F32))
+    assert "apex_kda_fwd" in txt and "apex_kda_bwd" in txt
+
+
 def test_flash_attention_segments_dropout_at_the_1024_tile(one_chip):
     """A long 16-bit sequence tiles at 1024 (PR 32): the variant that
     holds most beside the 4 MiB score tile — segment ids, the dropout
