@@ -20,6 +20,7 @@ believes measured routing is active when the design default is).
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import re
@@ -27,6 +28,8 @@ import warnings
 from typing import NamedTuple, Optional
 
 import jax
+
+from apex_tpu.telemetry import retrace
 
 # measured dispatch preferences: op family -> False means "the XLA
 # oracle path beat the Pallas kernel on hardware, prefer it".
@@ -505,6 +508,40 @@ def interpret_mode() -> bool:
     # run at lowering time, no device needed — checked per call so tests
     # can flip it with monkeypatch
     return os.environ.get("APEX_TPU_FORCE_MOSAIC", "") != "1"
+
+
+def lowered_once(scope: str, static_argnums=()):
+    """Decorator for a module-level function that calls Pallas kernels,
+    ``fn(*args, interpret)``: every call with the same shapes and the
+    same ``static_argnums`` arguments shares ONE traced jaxpr, which
+    jax lowers once a program (a private function called from each
+    site) where a ``pallas_call`` traced at each site lowers its kernel
+    to Mosaic at each (unrolled layers: one lowering a layer and pass).
+
+    The body runs under ``jax.named_scope(scope)``: a shared function's
+    operations carry no name of its callers' scopes.  ``interpret``
+    (``interpret_mode()`` at the call) is a static argument, so a
+    trace made for the interpreter is never handed to a TPU lowering.
+    Each trace is counted in the process's set-up account
+    (``retrace.kernel_trace``, its summary's ``kernel_traces``).
+    Call sites pass arrays and hashable statics: a fresh
+    ``functools.partial`` or closure there would miss the cache."""
+    def wrap(fn):
+        def traced(interpret, *args):
+            retrace.kernel_trace()
+            with jax.named_scope(scope):
+                return fn(*args, interpret=interpret)
+
+        jitted = jax.jit(traced, static_argnums=(0,) + tuple(
+            n + 1 for n in static_argnums))
+
+        @functools.wraps(fn)
+        def call(*args):
+            return jitted(interpret_mode(), *args)
+
+        return call
+
+    return wrap
 
 
 def pallas_enabled() -> bool:

@@ -314,6 +314,8 @@ class ProcessAccount(RetraceCounter):
         self.marks: List[float] = []
         # (name, start, end, thread), time.time() seconds
         self.phases: List[tuple] = []
+        # time.time() of each trace of a kernel program (kernel_trace)
+        self.kernel_marks: List[float] = []
         self._reported = False
 
     def install(self) -> bool:
@@ -339,6 +341,15 @@ class ProcessAccount(RetraceCounter):
         if done:
             self.close()
 
+    def kernel_trace(self) -> None:
+        """A kernel program (``ops/_dispatch.py:lowered_once``) is
+        traced.  A trace is lowered once in each program that holds it,
+        so in a process of several such programs the lowerings are more
+        than the traces."""
+        with self._lock:
+            if len(self.kernel_marks) < self.max_spans:
+                self.kernel_marks.append(time.time())
+
     def add_phase(self, name: str, start: float, end: float) -> None:
         with self._lock:
             if len(self.phases) < self.max_spans:
@@ -352,19 +363,23 @@ class ProcessAccount(RetraceCounter):
         the kept spans (wall time: they do not overlap), the cache's
         hits and misses among them, each phase's ``seconds`` with the
         kinds' seconds inside it and ``own_s``, what is left of it
-        without them, and the programs' rows."""
+        without them, the programs' rows, and ``kernel_traces``: how
+        often a kernel program was traced."""
         with self._lock:
             spans, phases = list(self.spans), list(self.phases)
+            kernels = list(self.kernel_marks)
         if before is not None:
             spans = [sp for sp in spans if sp.end <= before]
             phases = [ph for ph in phases if ph[2] <= before]
+            kernels = [t for t in kernels if t <= before]
         backend = [sp for sp in spans if sp.kind == "backend"]
         out = _by_kind(spans)
         out.update(
             cache_hits=sum(sp.cache == "hit" for sp in backend),
             cache_misses=sum(sp.cache == "miss" for sp in backend),
             cache_retrieval_s=sum(sp.retrieval_s for sp in backend),
-            programs=_program_rows(spans), phases={})
+            programs=_program_rows(spans), phases={},
+            kernel_traces=len(kernels))
         for name, start, end, thread in phases:
             inside = _by_kind(sp for sp in spans if sp.thread == thread
                               and sp.start >= start and sp.end <= end)
@@ -422,7 +437,8 @@ class ProcessAccount(RetraceCounter):
             line("to the first quiet step", marks[quiet] - self.started,
                  f"step {quiet}")
         line("trace", s["trace_s"], f"{s['trace_n']} outermost calls")
-        line("lower", s["lower_s"], f"{s['lower_n']} programs")
+        line("lower", s["lower_s"], f"{s['lower_n']} programs, "
+             f"{s['kernel_traces']} kernel programs traced")
         line("backend (load or compile)", s["backend_s"],
              f"{s['backend_n']} programs")
         line("  of it the cache's retrieval", s["cache_retrieval_s"],
@@ -462,6 +478,12 @@ def mark_step() -> None:
     attribute test after."""
     if _PROCESS.open:
         _PROCESS.mark_step()
+
+
+def kernel_trace() -> None:
+    """A kernel program is traced: a stamp while the account is open."""
+    if _PROCESS.open:
+        _PROCESS.kernel_trace()
 
 
 class _Phase:

@@ -101,7 +101,9 @@ SUITES = {
                       # spans as profiler events; the hot path's own
                       # apex/* spans and apex_* scopes
                       "tests/test_telemetry_spans.py",
-                      "tests/test_hot_path_scopes.py"],
+                      "tests/test_hot_path_scopes.py",
+                      # the set-up account's kernel traces
+                      "tests/test_kernel_traces.py"],
     # the performance observatory: trace parsing, attribution/overlap,
     # cost-model MFU, report CLI, and the perf regression gate
     "run_profiler": ["tests/test_profiler.py"],

@@ -1,9 +1,10 @@
 """Kimi Delta Attention's chunked scan (``ops/kda.py``) against the
 token-by-token recurrence, forward and every input's gradient, at
 lengths around the chunk of 64, at the published gate initialisation
-and at decays ten times stronger; its two neighbours (the short
-convolution, the L2 norm) against plain formulas.  CPU, interpreted
-Pallas; nothing is timed."""
+and at decays ten times stronger, over several heads and batches, with
+bfloat16 operands and with decays that would overflow a chunk-start
+exponent; its two neighbours (the short convolution, the L2 norm)
+against plain formulas.  CPU, interpreted Pallas; nothing is timed."""
 
 import math
 
@@ -54,6 +55,50 @@ def test_chunked_scan_agrees_with_the_recurrence(s, strength):
         # 200 positions and the stronger decays), 3e-5 leaves three times
         scale = float(jnp.max(jnp.abs(w))) or 1.0
         np.testing.assert_allclose(g / scale, w / scale, atol=3e-5, rtol=0,
+                                   err_msg=name)
+
+
+def _grads(fn, args, ct):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * ct),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+
+
+@pytest.mark.parametrize("b, h, s, dtype, log_decay, atol, gtol", [
+    # two batches of three heads, a length that is not a chunk multiple:
+    # six heads, not a multiple of four, one head a grid step
+    (2, 3, 100, jnp.float32, None, 2e-6, 3e-5),
+    # bfloat16 q, k, v, whose gradients come back in bfloat16; an odd
+    # count of heads, one a grid step
+    (1, 3, 130, jnp.bfloat16, None, 2e-2, 2e-2),
+    # -20 a step: exp(G) of a chunk's later rows underflows to 0 and
+    # exp(-G) would overflow; the split products never take either
+    (1, 2, 128, jnp.float32, -20.0, 2e-6, 3e-5),
+    # eight heads at a ragged length: two grid rows of two block-diagonal
+    # pairs each
+    (2, 4, 130, jnp.float32, None, 2e-6, 3e-5),
+], ids=["ragged_six_heads", "bfloat16", "overflowing_decay", "two_pairs"])
+def test_kernels_agree_with_the_recurrence_in_every_gradient(
+        b, h, s, dtype, log_decay, atol, gtol):
+    q, k, v, g, beta = _inputs(11 + s, s, 1.0, b=b, h=h)
+    if log_decay is not None:
+        g = jnp.full_like(g, log_decay)
+    q, k, v = (t.astype(dtype) for t in (q, k, v))
+    got = kda.chunk_kda(q, k, v, g, beta)
+    assert got.shape == (b, h, s, 16) and got.dtype == dtype
+    args32 = [t.astype(jnp.float32) for t in (q, k, v)] + [g, beta]
+    want = kda.kda_recurrent_ref(*args32)
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=atol,
+                               rtol=0)
+    ct = jax.random.normal(jax.random.key(5), got.shape)
+    gots = _grads(kda.chunk_kda, (q, k, v, g, beta), ct)
+    wants = _grads(kda.kda_recurrent_ref, args32, ct)
+    for name, x, w, arg in zip("q k v g beta".split(), gots, wants,
+                               (q, k, v, g, beta)):
+        assert x.dtype == arg.dtype, name
+        x = x.astype(jnp.float32)
+        assert bool(jnp.all(jnp.isfinite(x))), name
+        scale = float(jnp.max(jnp.abs(w))) or 1.0
+        np.testing.assert_allclose(x / scale, w / scale, atol=gtol, rtol=0,
                                    err_msg=name)
 
 

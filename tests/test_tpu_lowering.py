@@ -178,3 +178,55 @@ def test_lower_multi_tensor_family():
               p.astype(jnp.bfloat16))
     lower_tpu(lambda g: mt.flat_unscale_norm(g, 1 / 128.0),
               p.astype(jnp.bfloat16))
+
+
+# --------------------------------------------------------------------------
+# KDA: each kernel lowered once a program
+# --------------------------------------------------------------------------
+
+def test_four_kda_layers_lower_each_kernel_once(monkeypatch):
+    """The gradient of four distinct checkpointed layers around
+    ``chunk_kda``: ``apex_kda_fwd`` lowered at most twice (the primal
+    forward, which ``jax.checkpoint`` traces apart, and the custom VJP's
+    forward rule),
+    ``apex_kda_bwd`` once, every kernel under the ``apex_kda`` scope, and
+    the process account's ``kernel_traces`` the number of kernels
+    lowered: one trace a lowering, where a ``pallas_call`` traced at each
+    site would be twelve."""
+    import re
+    from apex_tpu.ops import kda
+    from apex_tpu.telemetry import retrace
+
+    account = retrace.ProcessAccount()
+    account.open = True
+    monkeypatch.setattr(retrace, "_PROCESS", account)
+    jax.clear_caches()
+
+    def layer(i):
+        def f(x, w):
+            q = kda.l2_normalize(jnp.tanh(x * w[0] + i))
+            k = kda.l2_normalize(jnp.tanh(x * w[1] - i))
+            g = -jax.nn.softplus(x * w[3])
+            beta = jax.nn.sigmoid(x[..., 0] * w[4, 0])
+            return x + kda.chunk_kda(q, k, (x * w[2]).astype(jnp.bfloat16),
+                                     g, beta).astype(x.dtype)
+        return jax.checkpoint(f)
+
+    def loss(x, ws):
+        for i in range(4):
+            x = layer(i)(x, ws[i])
+        return jnp.sum(x ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(
+        jnp.zeros((1, 8, 256, 128)), jnp.zeros((4, 5, 128))).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+    calls = re.findall(r"stablehlo\.custom_call @tpu_custom_call\(.*"
+                       r"kernel_name = \"(\w+)\".*loc\((#loc\d+)\)$",
+                       text, re.M)
+    names = [name for name, _ in calls]
+    assert names.count("apex_kda_fwd") in (1, 2), names
+    assert names.count("apex_kda_bwd") == 1, names
+    assert all("apex_kda/scan/" in locs[loc] for _, loc in calls), \
+        [locs[loc] for _, loc in calls]
+    assert account.summary()["kernel_traces"] == len(names) <= 3
