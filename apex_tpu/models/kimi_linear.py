@@ -39,16 +39,17 @@ tp=1.
 
 from __future__ import annotations
 
-import math
 from typing import Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.models.mixer_init import a_log_init, conv_init, dt_bias_init
 from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.ops.attention import flash_attention
-from apex_tpu.ops.kda import chunk_kda, l2_normalize, short_conv
+from apex_tpu.ops.kda import chunk_kda, l2_normalize
+from apex_tpu.ops.short_conv import short_conv
 from apex_tpu.ops.xentropy import softmax_cross_entropy
 from apex_tpu.transformer import tensor_parallel as tp
 from apex_tpu.transformer.moe import DroplessMoE
@@ -57,28 +58,6 @@ _INIT = nn.initializers.normal(0.02)
 # unit scale for the token table, as the expert decoder's and for its
 # reason (models/sparse_moe.py): at 0.02 every token routes alike
 _EMBED_INIT = nn.initializers.normal(1.0)
-
-
-def a_log_init(key, shape, dtype=jnp.float32):
-    """log U[1, 16] (FLA's KDA)."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def dt_bias_init(key, shape, dtype=jnp.float32, dt_min=1e-3, dt_max=0.1,
-                 floor=1e-4):
-    """softplus^-1 of a step log-uniform in [dt_min, dt_max] (FLA's KDA):
-    softplus(dt_bias) is the decay rate's initial scale."""
-    u = jax.random.uniform(key, shape, dtype)
-    dt = jnp.maximum(jnp.exp(u * (math.log(dt_max) - math.log(dt_min))
-                             + math.log(dt_min)), floor)
-    return dt + jnp.log(-jnp.expm1(-dt))
-
-
-def conv_init(key, shape, dtype=jnp.float32):
-    """torch's Conv1d default for a depthwise kernel of K taps:
-    U(-K^-1/2, K^-1/2)."""
-    bound = shape[0] ** -0.5
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
 def _column(inp, out, name, dtype):
@@ -113,7 +92,8 @@ class KDAMixer(nn.Module):
             u = _column(h, width, f"{name}_proj", dt)(x)
             w = self.param(f"{name}_conv", conv_init, (self.conv_size, width),
                            jnp.float32)
-            return short_conv(u, w).reshape(b, s, nh, d)
+            return short_conv(u, w, scope="apex_kda/conv").reshape(
+                b, s, nh, d)
 
         q, k, v = branch("q"), branch("k"), branch("v")
         q, k = l2_normalize(q), l2_normalize(k)

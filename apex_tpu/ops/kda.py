@@ -48,10 +48,10 @@ blocks of ``NEUMANN`` = 16, where A is nilpotent, the product
 doubles the blocks, T <- T - T A_b T (blocked forward substitution).
 Both are as accurate as row-by-row substitution.
 
-Also here: ``short_conv`` (the depthwise causal convolution and SiLU
-in front of q, k and v), ``l2_normalize`` and ``kda_recurrent_ref``,
-the per-token recurrence.  Scopes: ``apex_kda/conv``,
-``apex_kda/norm``, ``apex_kda/scan`` (the gate's is the model's).
+Also here: ``l2_normalize`` and ``kda_recurrent_ref``, the per-token
+recurrence (the convolution in front of q, k and v is
+``ops/short_conv.py``'s).  Scopes: ``apex_kda/norm``, ``apex_kda/scan``
+(the convolution's and the gate's are the model's).
 Shapes: (B, H, S, D); g (B, H, S, dk) float32, beta (B, H, S).
 """
 
@@ -80,18 +80,6 @@ def _dot(a, b, dims):
 # ---------------------------------------------------------------------------
 # the parts around the scan
 # ---------------------------------------------------------------------------
-
-def short_conv(x, w):
-    """SiLU of a depthwise causal convolution over time, no bias:
-    x (B, S, C), w (K, C) -> (B, S, C) in x's dtype,
-    y_t = silu(sum_j w_j x_{t - K + 1 + j}), float32 inside."""
-    with jax.named_scope("apex_kda/conv"):
-        kk, s = w.shape[0], x.shape[1]
-        xp = jnp.pad(x.astype(F32), ((0, 0), (kk - 1, 0), (0, 0)))
-        y = sum(w[j].astype(F32) * xp[:, j:j + s]
-                for j in range(kk))
-        return jax.nn.silu(y).astype(x.dtype)
-
 
 def l2_normalize(x, eps: float = 1e-6):
     """x / ||x|| over the last axis, float32 (FLA's ``l2norm``)."""

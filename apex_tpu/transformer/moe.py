@@ -264,17 +264,21 @@ def route_topk(x, router, top_k: int, norm_topk_prob: bool = True, *,
     return gates, experts.astype(jnp.int32)
 
 
-def _shared_expert(x, gate_up, down):
+def _shared_expert(x, gate_up, down, activation="swiglu"):
     """The expert every token goes through: down(silu(gate x) * up x),
-    gate_up (H, 2F) = [gate | up]; float32 sum of products, the
-    activation in float32, x's dtype out."""
+    gate_up (H, 2F) = [gate | up]; under ``activation`` ``"relu2"``
+    down(relu(up x)^2), gate_up the up matrix (H, F); float32 sum of
+    products, the activation in float32, x's dtype out."""
     with jax.named_scope("apex_moe/shared"):
         prec = matmul_precision(x.dtype)
         mid = jnp.dot(x, gate_up.astype(x.dtype),
                       preferred_element_type=jnp.float32, precision=prec)
-        gate, up = jnp.split(mid, 2, axis=-1)
-        return jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype),
-                       down.astype(x.dtype),
+        if activation == "relu2":
+            act = jnp.square(jax.nn.relu(mid))
+        else:
+            gate, up = jnp.split(mid, 2, axis=-1)
+            act = jax.nn.silu(gate) * up
+        return jnp.dot(act.astype(x.dtype), down.astype(x.dtype),
                        preferred_element_type=jnp.float32, precision=prec)
 
 
@@ -458,6 +462,36 @@ _gated_silu.defvjp(_gated_silu_fwd, _gated_silu_bwd)
 
 
 @jax.custom_vjp
+def _relu2(mid, n_used):
+    """relu(mid)^2 through float32 over the rows in use; zeros after
+    them (the non-gated experts' activation)."""
+    return _relu2_fwd(mid, n_used)[0]
+
+
+def _relu2_fwd(mid, n_used):
+    def chunk(start, size):
+        up = jax.nn.relu(_window(mid, start, size).astype(jnp.float32))
+        return (jnp.where(_in_use(start, size, n_used), up * up, 0.0),)
+    act, = _over_rows_in_use(n_used, mid, [(mid.shape, mid.dtype)], chunk)
+    return act, (mid, n_used)
+
+
+def _relu2_bwd(res, dact):
+    mid, n_used = res
+
+    def chunk(start, size):
+        up = jax.nn.relu(_window(mid, start, size).astype(jnp.float32))
+        d = _window(dact, start, size).astype(jnp.float32)
+        return (jnp.where(_in_use(start, size, n_used), 2.0 * up * d, 0.0),)
+    dmid, = _over_rows_in_use(
+        n_used, (mid, dact), [(mid.shape, mid.dtype)], chunk)
+    return dmid, None
+
+
+_relu2.defvjp(_relu2_fwd, _relu2_bwd)
+
+
+@jax.custom_vjp
 def _combine(ys, gates, routed):
     """y[t] = sum over the assignments (t, c) held here of gates[t, c]
     times their row of ys, float32 sum, ys' dtype.  The forward reads
@@ -527,7 +561,8 @@ def _route(gates, experts, expert_offset, held):
 def dropless_moe(x, router, gate_up, down, *, top_k: int,
                  expert_offset: int = 0, norm_topk_prob: bool = True,
                  score: str = "softmax", selection_bias=None,
-                 routed_scale: float = 1.0, shared=None):
+                 routed_scale: float = 1.0, shared=None,
+                 activation: str = "swiglu"):
     """One holder's share of a dropless top-k expert layer.
 
     x (T, H); router (H, E) over ALL E experts; this holder's experts
@@ -541,17 +576,21 @@ def dropless_moe(x, router, gate_up, down, *, top_k: int,
     with g from ``route_topk`` (normalised over all k chosen experts,
     held here or not; ``score``, ``selection_bias`` and
     ``routed_scale`` are its), and counts[e] the tokens routed to each
-    held expert.  ``shared`` = (gate_up (H, 2F'), down (F', H)): an
-    expert every token goes through, which this holder computes for
-    all of its tokens and adds (``apex_moe/shared``).  No token is
-    dropped: the assignments routed here are sorted by expert into a
-    buffer of T * min(k, held) rows (all that can arrive), and the
-    moves, the activation, the two grouped products and their backward
-    passes run over the rows in use
+    held expert.  ``activation`` ``"relu2"``: non-gated experts,
+    down_e(relu(up_e x[t])^2) with gate_up the up matrices (held, H, F).
+    ``shared`` = (gate_up (H, 2F'), down (F', H)), (H, F') under
+    ``"relu2"``: an expert every token goes through, which this holder
+    computes for all of its tokens and adds (``apex_moe/shared``).  No
+    token is dropped: the assignments routed here are sorted by expert
+    into a buffer of T * min(k, held) rows (all that can arrive), and
+    the moves, the activation, the two grouped products and their
+    backward passes run over the rows in use
     (``sum(counts)``, in chunks of ``_CHUNK``): the layer costs what is
     routed to it, up to the whole buffer when everything is.
     What the other holders' experts add is left out: the holders' parts
     sum to the whole layer."""
+    if activation not in ("swiglu", "relu2"):
+        raise ValueError(f"dropless_moe: unknown activation {activation!r}")
     gates, experts = route_topk(x, router, top_k, norm_topk_prob,
                                 score=score, selection_bias=selection_bias,
                                 routed_scale=routed_scale)
@@ -563,22 +602,26 @@ def dropless_moe(x, router, gate_up, down, *, top_k: int,
         prec = matmul_precision(x.dtype)
         mid = jax.lax.ragged_dot(xs, gate_up.astype(x.dtype), counts,
                                  precision=prec)
-        with jax.named_scope("apex_swiglu"):
-            act = _gated_silu(mid, routed.n_used)
+        if activation == "relu2":
+            act = _relu2(mid, routed.n_used)
+        else:
+            with jax.named_scope("apex_swiglu"):
+                act = _gated_silu(mid, routed.n_used)
         ys = jax.lax.ragged_dot(act, down.astype(x.dtype), counts,
                                 precision=prec)
     with jax.named_scope("apex_moe/combine"):
         y = _combine(ys, gates, routed)
     if shared is not None:
-        y = (y.astype(jnp.float32) + _shared_expert(x, *shared)).astype(
-            y.dtype)
+        y = (y.astype(jnp.float32)
+             + _shared_expert(x, *shared, activation)).astype(y.dtype)
     return y, counts
 
 
 def dropless_moe_ref(x, router, gate_up, down, *, top_k: int,
                      expert_offset: int = 0, norm_topk_prob: bool = True,
                      score: str = "softmax", selection_bias=None,
-                     routed_scale: float = 1.0, shared=None):
+                     routed_scale: float = 1.0, shared=None,
+                     activation: str = "swiglu"):
     """Dense oracle of ``dropless_moe``: every held expert applied to
     every token, weighted by its gate where chosen."""
     gates, experts = route_topk(x, router, top_k, norm_topk_prob,
@@ -589,31 +632,38 @@ def dropless_moe_ref(x, router, gate_up, down, *, top_k: int,
     hi = jax.lax.Precision.HIGHEST
     y = jnp.zeros(x.shape, jnp.float32)
     counts = []
+
+    def ffn(w_in, w_out):
+        mid = jnp.dot(xf, w_in.astype(jnp.float32), precision=hi)
+        if activation == "relu2":
+            act = jnp.square(jax.nn.relu(mid))
+        else:
+            gate, up = jnp.split(mid, 2, axis=-1)
+            act = jax.nn.silu(gate) * up
+        return jnp.dot(act, w_out.astype(jnp.float32), precision=hi)
+
     for e in range(held):
         chosen = experts == expert_offset + e
         g = jnp.sum(jnp.where(chosen, gates, 0.0), axis=-1)
-        gate, up = jnp.split(jnp.dot(xf, gate_up[e].astype(jnp.float32),
-                                     precision=hi), 2, axis=-1)
-        out = jnp.dot(jax.nn.silu(gate) * up, down[e].astype(jnp.float32),
-                      precision=hi)
-        y = y + g[:, None] * out
+        y = y + g[:, None] * ffn(gate_up[e], down[e])
         counts.append(jnp.sum(chosen))
     if shared is not None:
-        gate, up = jnp.split(jnp.dot(xf, shared[0].astype(jnp.float32),
-                                     precision=hi), 2, axis=-1)
-        y = y + jnp.dot(jax.nn.silu(gate) * up,
-                        shared[1].astype(jnp.float32), precision=hi)
+        y = y + ffn(*shared)
     return y.astype(x.dtype), jnp.stack(counts).astype(jnp.int32)
 
 
 class DroplessMoE(nn.Module):
     """``dropless_moe`` with its parameters: ``router`` (H, num_experts),
     ``gate_up`` (experts_held, H, 2 * ffn_hidden_size), ``down``
-    (experts_held, ffn_hidden_size, H), float32, normal(0.02).  With
+    (experts_held, ffn_hidden_size, H), float32, normal(0.02) (the down
+    matrices at 0.02 times ``down_init_scale``).  With
     ``score`` ``"sigmoid"`` also ``selection_bias`` (num_experts,),
     zeros, which the gradient does not reach (a balancing rule would
     write it between steps); with ``shared_ffn_hidden_size`` also
-    ``shared_gate_up`` (H, 2 * that) and ``shared_down``.
+    ``shared_gate_up`` (H, 2 * that) and ``shared_down``.  With
+    ``activation`` ``"relu2"`` (non-gated experts) ``up`` (experts_held,
+    H, ffn_hidden_size) in place of ``gate_up``, and ``shared_up`` (H,
+    shared_ffn_hidden_size) in place of ``shared_gate_up``.
     ``__call__(x (T, H)) -> (y, counts)``."""
     hidden_size: int
     ffn_hidden_size: int
@@ -625,6 +675,8 @@ class DroplessMoE(nn.Module):
     score: str = "softmax"
     routed_scale: float = 1.0
     shared_ffn_hidden_size: int = 0
+    activation: str = "swiglu"
+    down_init_scale: float = 1.0      # the down matrices' normal: 0.02 x
 
     @nn.compact
     def __call__(self, x):
@@ -636,9 +688,12 @@ class DroplessMoE(nn.Module):
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (h, self.num_experts),
                             jnp.float32)
-        gate_up = self.param("gate_up", init,
-                             (self.experts_held, h, 2 * f), jnp.float32)
-        down = self.param("down", init, (self.experts_held, f, h),
+        gated = self.activation != "relu2"
+        w_in, ways = ("gate_up", 2) if gated else ("up", 1)
+        gate_up = self.param(w_in, init, (self.experts_held, h, ways * f),
+                             jnp.float32)
+        down_init = nn.initializers.normal(0.02 * self.down_init_scale)
+        down = self.param("down", down_init, (self.experts_held, f, h),
                           jnp.float32)
         bias = shared = None
         if self.score == "sigmoid":
@@ -646,11 +701,13 @@ class DroplessMoE(nn.Module):
                               (self.num_experts,), jnp.float32)
         if self.shared_ffn_hidden_size:
             fs = self.shared_ffn_hidden_size
-            shared = (self.param("shared_gate_up", init, (h, 2 * fs),
+            shared = (self.param(f"shared_{w_in}", init, (h, ways * fs),
                                  jnp.float32),
-                      self.param("shared_down", init, (fs, h), jnp.float32))
+                      self.param("shared_down", down_init, (fs, h),
+                                 jnp.float32))
         return dropless_moe(x, router, gate_up, down, top_k=self.top_k,
                             expert_offset=self.expert_offset,
                             norm_topk_prob=self.norm_topk_prob,
                             score=self.score, selection_bias=bias,
-                            routed_scale=self.routed_scale, shared=shared)
+                            routed_scale=self.routed_scale, shared=shared,
+                            activation=self.activation)

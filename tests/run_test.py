@@ -44,11 +44,13 @@ SUITES = {
                         "tests/test_comm.py", "tests/test_moe.py",
                         "tests/test_sparse_moe_decoder.py",
                         "tests/test_kimi_linear_decoder.py",
+                        "tests/test_nemotron_h_decoder.py",
                         "tests/test_microbatches.py"],
     "run_fp16util": ["tests/test_fp16_rnn_reparam.py"],
     "run_attention": ["tests/test_attention.py",
                       "tests/test_sparse_index.py",
                       "tests/test_kda.py",
+                      "tests/test_ssd.py",
                       "tests/test_contrib_multihead_attn.py"],
     "run_contrib": ["tests/test_contrib_xentropy_clipgrad.py",
                     "tests/test_contrib_transducer.py",

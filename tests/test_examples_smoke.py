@@ -640,6 +640,16 @@ def test_gpt_kimi_linear_tiny(capsys):
     assert len(summary["expert_counts"]) == 3       # layers 2-4 hold experts
 
 
+def test_gpt_nemotron_h_tiny(capsys):
+    example = runpy.run_path("examples/gpt/train_nemotron_h.py")
+    summary = example["main"](["--cpu", "--steps", "3", "--layers", "7"])
+    out = capsys.readouterr().out
+    assert "hybrid Mamba-2 expert decoder MEMEM*E" in out
+    assert "4/16 experts top-2 + shared" in out and "step time" in out
+    assert summary["found_inf"] == 0
+    assert len(summary["expert_counts"]) == 3       # layers 1, 3, 6
+
+
 def test_train_tp_converges(capsys):
     _run("examples/simple/train_tp.py", [])
     assert "OK: loss" in capsys.readouterr().out

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from apex_tpu.ops import kda
+from apex_tpu.ops.short_conv import short_conv
 
 
 def _inputs(seed, s, strength, b=1, h=2, dk=32, dv=16):
@@ -125,14 +126,15 @@ def test_the_state_passes_through_padding_and_bfloat16_operands():
 def test_short_convolution_is_causal_and_depthwise():
     x = jax.random.normal(jax.random.key(0), (2, 10, 6))
     w = jax.random.normal(jax.random.key(1), (4, 6))
-    y = kda.short_conv(x, w)
+    y = short_conv(x, w, scope="apex_kda/conv")
     xp = np.pad(np.asarray(x), ((0, 0), (3, 0), (0, 0)))
     want = sum(np.asarray(w)[j] * xp[:, j:j + 10] for j in range(4))
     np.testing.assert_allclose(y, want / (1 + np.exp(-want)), rtol=1e-5,
                                atol=1e-6)
     # the output at t reads no input after t
     x2 = x.at[:, 5:].set(0.0)
-    np.testing.assert_allclose(kda.short_conv(x2, w)[:, :5], y[:, :5])
+    np.testing.assert_allclose(
+        short_conv(x2, w, scope="apex_kda/conv")[:, :5], y[:, :5])
 
 
 def test_l2_normalize_gives_unit_rows():

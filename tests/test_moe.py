@@ -168,3 +168,91 @@ def test_router_jitter_eval_deterministic_no_rng():
     m0 = moe.ExpertParallelMLP(H, F, E, capacity_factor=2.0, axis=None)
     want, _ = m0.apply({"params": params}, x)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# dropless experts, non-gated: down(relu(up x)^2)
+# ---------------------------------------------------------------------------
+
+def _relu2_layer(seed=7, tokens=80, hidden=32, width=24, experts=32,
+                 shared=40):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    return {"x": jax.random.normal(ks[0], (tokens, hidden)),
+            "router": 0.3 * jax.random.normal(ks[1], (hidden, experts)),
+            "up": 0.3 * jax.random.normal(ks[2], (experts, hidden, width)),
+            "down": 0.3 * jax.random.normal(ks[3], (experts, width, hidden)),
+            "bias": 0.5 * jax.random.normal(ks[4], (experts,)),
+            "shared": tuple(0.3 * jax.random.normal(k, s) for k, s in zip(
+                jax.random.split(ks[5]), ((hidden, shared), (shared, hidden))))}
+
+
+def _relu2_share(layer, offset, held, fn=moe.dropless_moe, **kw):
+    return fn(layer["x"], layer["router"], layer["up"][offset:offset + held],
+              layer["down"][offset:offset + held], top_k=6,
+              expert_offset=offset, score="sigmoid",
+              selection_bias=layer["bias"], routed_scale=2.5,
+              shared=layer["shared"], activation="relu2", **kw)
+
+
+def test_relu2_dropless_matches_its_dense_oracle_and_gradients():
+    layer = _relu2_layer()
+    y, counts = _relu2_share(layer, 8, 8)
+    want, want_counts = _relu2_share(layer, 8, 8, moe.dropless_moe_ref)
+    np.testing.assert_allclose(y, want, rtol=0,
+                               atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    assert counts.tolist() == want_counts.tolist() and int(counts.sum()) > 0
+    ct = jax.random.normal(jax.random.key(9), layer["x"].shape)
+
+    def total(fn):
+        def f(x, r, up, down, shared):
+            return jnp.sum(_relu2_share(
+                dict(layer, x=x, router=r, up=up, down=down, shared=shared),
+                0, 8, fn)[0] * ct)
+        return f
+    args = (layer["x"], layer["router"], layer["up"][:8], layer["down"][:8],
+            layer["shared"])
+    got = jax.grad(total(moe.dropless_moe), range(5))(*args)
+    ref = jax.grad(total(moe.dropless_moe_ref), range(5))(*args)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref)):
+        np.testing.assert_allclose(g, w, rtol=0,
+                                   atol=1e-4 * float(jnp.max(jnp.abs(w))))
+
+
+def test_sixteen_holders_relu2_shares_with_the_shared_expert_once_sum_to_the_uncut_layer():
+    """Sixteen holders of two experts each, top-6 of 32 under a
+    non-zero selection bias: their parts, less the shared expert each
+    computed for all its tokens but once, are the whole layer as the
+    benchmark's plain reference computes it with every expert held."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.reference import nemotron_twotower_30b_a3b_adamw as ref
+
+    layer = _relu2_layer()
+    parts = [_relu2_share(layer, off, 2) for off in range(0, 32, 2)]
+    p = {"router": layer["router"], "selection_bias": layer["bias"],
+         "up": layer["up"], "down": layer["down"],
+         "shared_up": layer["shared"][0], "shared_down": layer["shared"][1]}
+    with jax.default_matmul_precision("highest"):
+        whole = ref.moe(p, layer["x"], top_k=6, routed_scale=2.5)
+        shared = ref.moe(dict(p, up=p["up"][:0], down=p["down"][:0]),
+                         layer["x"], top_k=6)
+    np.testing.assert_allclose(sum(y for y, _ in parts) - 15 * shared, whole,
+                               rtol=0, atol=2e-4)
+    assert sum(int(jnp.sum(c)) for _, c in parts) == 80 * 6
+
+
+def test_relu2_module_holds_up_matrices_and_no_gate():
+    layer = moe.DroplessMoE(32, 24, 16, 4, 2, 4, True, "sigmoid", 2.5, 40,
+                            activation="relu2")
+    x = jax.random.normal(jax.random.key(1), (10, 32))
+    params = layer.init(jax.random.key(0), x)["params"]
+    assert {k: v.shape for k, v in params.items()} == {
+        "router": (32, 16), "selection_bias": (16,), "up": (4, 32, 24),
+        "down": (4, 24, 32), "shared_up": (32, 40), "shared_down": (40, 32)}
+    with pytest.raises(ValueError, match="activation"):
+        moe.dropless_moe(x, params["router"], params["up"], params["down"],
+                         top_k=2, activation="gelu")

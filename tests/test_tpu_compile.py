@@ -138,6 +138,17 @@ def test_kda_scan_fwd_bwd_at_the_cell_width(one_chip):
     assert "apex_kda_fwd" in txt and "apex_kda_bwd" in txt
 
 
+def test_ssd_scan_fwd_bwd_at_the_cell_width(one_chip):
+    """The SSD scan at 64 heads of 64, 8 groups of a state of 128 and
+    8192 positions, forward and backward: both kernels, and the program
+    fits."""
+    from apex_tpu.ops.ssd import chunk_ssd
+    txt = _compile(_grads(chunk_ssd, 5), one_chip, ((1, 8192, 64, 64), BF16),
+                   ((1, 8192, 64), F32), ((64,), F32),
+                   ((1, 8192, 8, 128), BF16), ((1, 8192, 8, 128), BF16))
+    assert "apex_ssd_fwd" in txt and "apex_ssd_bwd" in txt
+
+
 def test_flash_attention_segments_dropout_at_the_1024_tile(one_chip):
     """A long 16-bit sequence tiles at 1024 (PR 32): the variant that
     holds most beside the 4 MiB score tile — segment ids, the dropout

@@ -230,3 +230,58 @@ def test_four_kda_layers_lower_each_kernel_once(monkeypatch):
     assert all("apex_kda/scan/" in locs[loc] for _, loc in calls), \
         [locs[loc] for _, loc in calls]
     assert account.summary()["kernel_traces"] == len(names) <= 3
+
+
+# --------------------------------------------------------------------------
+# SSD: one forward and one backward kernel program a step
+# --------------------------------------------------------------------------
+
+def test_three_mamba_layers_trace_one_forward_and_one_backward_kernel(
+        monkeypatch):
+    """The gradient of three distinct checkpointed layers around
+    ``chunk_ssd`` at the cell's widths (64 heads of 64, 8 groups of a
+    state of 128): the process account's ``kernel_traces`` is 2, one
+    forward and one backward kernel program for the whole step (the
+    primal and the custom VJP's forward rule share one trace), lowered
+    where jax places them: the forward pass's, the rematerialised
+    forward's and the backward's, each under the ``apex_ssd/scan``
+    scope."""
+    import re
+    from apex_tpu.ops import ssd
+    from apex_tpu.telemetry import retrace
+
+    account = retrace.ProcessAccount()
+    account.open = True
+    monkeypatch.setattr(retrace, "_PROCESS", account)
+    jax.clear_caches()
+    s, h, p, g, n = 256, 64, 64, 8, 128
+
+    def layer(i):
+        def f(x, w):
+            xs = (x * w[0]).reshape(1, s, h, p).astype(jnp.bfloat16)
+            dt = jax.nn.softplus(x[..., :h] * w[1, :h] + i)
+            B = jnp.tanh(x[..., :g * n] * w[2, :g * n]).reshape(1, s, g, n)
+            C = jnp.tanh(x[..., :g * n] * w[3, :g * n]).reshape(1, s, g, n)
+            y = ssd.chunk_ssd(xs, dt, -jnp.arange(1.0, h + 1),
+                              B.astype(jnp.bfloat16), C.astype(jnp.bfloat16))
+            return x + y.reshape(1, s, h * p).astype(x.dtype)
+        return jax.checkpoint(f)
+
+    def loss(x, ws):
+        for i in range(3):
+            x = layer(i)(x, ws[i])
+        return jnp.sum(x ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(
+        jnp.zeros((1, s, h * p)), jnp.zeros((3, 4, h * p))).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+    calls = re.findall(r"stablehlo\.custom_call @tpu_custom_call\(.*"
+                       r"kernel_name = \"(\w+)\".*loc\((#loc\d+)\)$",
+                       text, re.M)
+    names = [name for name, _ in calls]
+    assert account.summary()["kernel_traces"] == 2
+    assert names.count("apex_ssd_fwd") in (1, 2), names
+    assert names.count("apex_ssd_bwd") == 1, names
+    assert all("apex_ssd/scan/" in locs[loc] for _, loc in calls), \
+        [locs[loc] for _, loc in calls]
