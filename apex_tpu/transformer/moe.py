@@ -28,14 +28,16 @@ dropped tokens pass through the residual path of the caller).
 expert layer: top-k of a softmax router, no capacity and no dropped
 token, and TOLD WHICH EXPERTS IT HOLDS.  It routes over the router's
 whole width and computes its own experts' part of the result, with
-grouped matrix products (``lax.ragged_dot``) over the assignments
-routed here; the parts of all the holders add up to the whole layer.
+grouped matrix products (``ops/grouped_matmul.py``, Pallas kernels
+that visit only the rows in use) over the assignments routed here; the
+parts of all the holders add up to the whole layer.
 It contains no exchange: on one chip it is the chip's share, and an
 expert-parallel caller sums the parts.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -44,6 +46,7 @@ import jax.numpy as jnp
 
 from apex_tpu import comm
 from apex_tpu.ops.attention import matmul_precision
+from apex_tpu.ops.grouped_matmul import grouped_matmul
 from apex_tpu.transformer.tensor_parallel import mappings
 
 Array = jax.Array
@@ -296,8 +299,11 @@ def _take(x, idx):
 # the front.  Every pass over such a buffer is a loop over chunks of
 # ``_CHUNK`` rows whose trip count is read off ``n_used``: it costs the
 # rows routed here, and the rows past the last chunk stay the zeros the
-# buffer was made of (the grouped products are handed these buffers).
-# The loops live inside custom VJP rules, so JAX never differentiates
+# buffer was made of.  The grouped products are handed these buffers and
+# leave the rows past ``n_used`` of what they make undefined: every loop
+# that reads such rows selects them away (``_in_use``) before it sums or
+# stores them, so no value there reaches a result, NaN included.  The
+# loops live inside custom VJP rules, so JAX never differentiates
 # through one; each backward rule is its own loop.
 
 _CHUNK = 2048
@@ -584,9 +590,10 @@ def dropless_moe(x, router, gate_up, down, *, top_k: int,
     token is dropped: the assignments routed here are sorted by expert
     into a buffer of T * min(k, held) rows (all that can arrive), and
     the moves, the activation, the two grouped products and their
-    backward passes run over the rows in use
-    (``sum(counts)``, in chunks of ``_CHUNK``): the layer costs what is
-    routed to it, up to the whole buffer when everything is.
+    backward passes run over the rows in use (``sum(counts)``; the moves
+    and the activation in chunks of ``_CHUNK``, the products in row
+    tiles of 128): the layer costs what is routed to it, up to the
+    whole buffer when everything is.
     What the other holders' experts add is left out: the holders' parts
     sum to the whole layer."""
     if activation not in ("swiglu", "relu2"):
@@ -599,16 +606,15 @@ def dropless_moe(x, router, gate_up, down, *, top_k: int,
                                 gate_up.shape[0])
         xs = _dispatch(x, routed)
     with jax.named_scope("apex_moe/experts"):
-        prec = matmul_precision(x.dtype)
-        mid = jax.lax.ragged_dot(xs, gate_up.astype(x.dtype), counts,
-                                 precision=prec)
+        product = functools.partial(grouped_matmul, counts=counts,
+                                    precision=matmul_precision(x.dtype))
+        mid = product(xs, gate_up.astype(x.dtype))
         if activation == "relu2":
             act = _relu2(mid, routed.n_used)
         else:
             with jax.named_scope("apex_swiglu"):
                 act = _gated_silu(mid, routed.n_used)
-        ys = jax.lax.ragged_dot(act, down.astype(x.dtype), counts,
-                                precision=prec)
+        ys = product(act, down.astype(x.dtype))
     with jax.named_scope("apex_moe/combine"):
         y = _combine(ys, gates, routed)
     if shared is not None:

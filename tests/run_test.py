@@ -42,6 +42,7 @@ SUITES = {
     "run_transformer": ["tests/test_tensor_parallel.py",
                         "tests/test_pipeline_parallel.py",
                         "tests/test_comm.py", "tests/test_moe.py",
+                        "tests/test_grouped_matmul.py",
                         "tests/test_sparse_moe_decoder.py",
                         "tests/test_kimi_linear_decoder.py",
                         "tests/test_nemotron_h_decoder.py",

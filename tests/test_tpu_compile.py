@@ -266,18 +266,40 @@ def test_a_rematerialised_layer_runs_each_indexer_kernel_once(one_chip):
 
 def test_dropless_experts_are_grouped_products(one_chip):
     """One holder's share at the cell's size: 8 of 128 experts, 8192
-    tokens top-8; the two expert products and their transposes compile
-    to the compiler's grouped matmul, and no scatter moves a row."""
+    tokens top-8; the two expert products compile to the grouped-matmul
+    kernels (``ops/grouped_matmul.py``), their gradients to its
+    transposed product and weight gradient, within the kernels' VMEM;
+    no ``ragged-dot`` is left, and no scatter moves a row."""
     from apex_tpu.transformer.moe import dropless_moe
 
     def f(x, router, gate_up, down):
         return dropless_moe(x, router, gate_up, down, top_k=8)[0]
     specs = (((S8K, 2048), BF16), ((2048, 128), BF16),
              ((8, 2048, 1536), BF16), ((8, 768, 2048), BF16))
-    for text in (_compile(f, one_chip, *specs),
-                 _compile(_grads(f, 4), one_chip, *specs)):
-        assert "ragged-dot" in text and "tpu_custom_call" in text
+    for text, kernels in (
+            (_compile(f, one_chip, *specs), ("apex_gmm_fwd",)),
+            (_compile(_grads(f, 4), one_chip, *specs),
+             ("apex_gmm_fwd", "apex_gmm_dlhs", "apex_gmm_dw"))):
+        _assert_kernels(text, *kernels)
+        assert "ragged-dot" not in text
         assert " scatter(" not in text
+
+
+def test_relu2_experts_compile_at_the_mamba_cells_widths(one_chip):
+    """The Mamba cell's expert layer, 8 of 128 non-gated experts of 1856
+    (no lane multiple) over 2688, 8192 tokens top-6: the three
+    grouped-matmul kernels compile with the whole contraction a block,
+    within their VMEM."""
+    from apex_tpu.transformer.moe import dropless_moe
+
+    def f(x, router, up, down):
+        return dropless_moe(x, router, up, down, top_k=6, score="sigmoid",
+                            activation="relu2")[0]
+    specs = (((S8K, 2688), BF16), ((2688, 128), BF16),
+             ((8, 2688, 1856), BF16), ((8, 1856, 2688), BF16))
+    text = _compile(_grads(f, 4), one_chip, *specs)
+    _assert_kernels(text, "apex_gmm_fwd", "apex_gmm_dlhs", "apex_gmm_dw")
+    assert "ragged-dot" not in text
 
 
 # ---------------------------------------------------------------------------

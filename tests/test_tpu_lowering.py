@@ -285,3 +285,90 @@ def test_three_mamba_layers_trace_one_forward_and_one_backward_kernel(
     assert names.count("apex_ssd_bwd") == 1, names
     assert all("apex_ssd/scan/" in locs[loc] for _, loc in calls), \
         [locs[loc] for _, loc in calls]
+
+
+# --------------------------------------------------------------------------
+# the expert layers' grouped products: one trace a kernel program a step
+# --------------------------------------------------------------------------
+
+def test_three_expert_layers_trace_each_grouped_matmul_kernel_once(
+        monkeypatch):
+    """The gradient of three distinct checkpointed layers around
+    ``dropless_moe`` (non-gated, top-6 of 32, 8 held): the process
+    account's ``kernel_traces`` is 6, one program a (kernel, shape) for
+    the whole step — the two products forward (the primal, the custom
+    VJP's forward rule and the rematerialised forward share one trace),
+    their two transposed products and two weight gradients — where a
+    ``pallas_call`` traced at each site would be 24; each kernel under
+    the ``apex_moe/experts`` scope, and no ``ragged_dot`` left."""
+    import re
+    from apex_tpu.telemetry import retrace
+    from apex_tpu.transformer.moe import dropless_moe
+
+    account = retrace.ProcessAccount()
+    account.open = True
+    monkeypatch.setattr(retrace, "_PROCESS", account)
+    jax.clear_caches()
+    h, f = 256, 384
+
+    def layer(i):
+        def g(x, w):
+            y, _ = dropless_moe(x, w["router"] + i, w["up"], w["down"],
+                                top_k=6, score="sigmoid",
+                                activation="relu2")
+            return x + y
+        return jax.checkpoint(g)
+
+    def loss(x, ws):
+        for i in range(3):
+            x = layer(i)(x, jax.tree_util.tree_map(lambda v: v[i], ws))
+        return jnp.sum(x.astype(jnp.float32) ** 2)
+
+    ws = {"router": jnp.zeros((3, h, 32), jnp.bfloat16),
+          "up": jnp.zeros((3, 8, h, f), jnp.bfloat16),
+          "down": jnp.zeros((3, 8, f, h), jnp.bfloat16)}
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(
+        jnp.zeros((256, h), jnp.bfloat16), ws).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+    calls = re.findall(r"stablehlo\.custom_call @tpu_custom_call\(.*"
+                       r"kernel_name = \"(\w+)\".*loc\((#loc\d+)\)$",
+                       text, re.M)
+    names = [name for name, _ in calls]
+    assert account.summary()["kernel_traces"] == 6
+    # a program lowered where jax places it: the forward kernels in the
+    # forward pass and in the rematerialised forward
+    assert {n: names.count(n) for n in set(names)} == {
+        "apex_gmm_fwd": 4, "apex_gmm_dlhs": 2, "apex_gmm_dw": 2}, names
+    assert all("apex_moe/experts/" in locs[loc] for _, loc in calls), \
+        [locs[loc] for _, loc in calls]
+    assert "ragged_dot" not in text
+
+
+@pytest.mark.parametrize("hidden, width, gated", [
+    (2688, 1856, False),     # the Mamba cell's relu2 experts
+    (2048, 768, True),       # the expert cell's gated experts
+    (2304, 1024, True),      # the hybrid cell's
+], ids=["mamba", "keye", "kimi"])
+def test_grouped_matmul_kernels_lower_at_the_cells_widths(hidden, width,
+                                                          gated):
+    """Both products of a held expert layer and their gradients at the
+    cell's widths and whole buffer (8192 tokens, top-8 or top-6)."""
+    from apex_tpu.ops import grouped_matmul as gm
+    rows = 8192 * (8 if gated else 6)
+    bf = jnp.bfloat16
+    counts = jax.ShapeDtypeStruct((8,), jnp.int32)
+
+    @jax.jit
+    def f(lhs, rhs, c, dout):
+        out, vjp = jax.vjp(
+            lambda a, b: gm.grouped_matmul(a, b, c), lhs, rhs)
+        return out, vjp(dout)
+    for k, n in ((hidden, (2 if gated else 1) * width), (width, hidden)):
+        text = f.trace(
+            jax.ShapeDtypeStruct((rows, k), bf),
+            jax.ShapeDtypeStruct((8, k, n), bf), counts,
+            jax.ShapeDtypeStruct((rows, n), bf)).lower(
+                lowering_platforms=("tpu",)).as_text()
+        for name in ("apex_gmm_fwd", "apex_gmm_dlhs", "apex_gmm_dw"):
+            assert f'kernel_name = "{name}"' in text, name
