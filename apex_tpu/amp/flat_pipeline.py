@@ -98,7 +98,7 @@ class GradAccum(NamedTuple):
     @staticmethod
     def zeros(plan: BucketPlan) -> "GradAccum":
         return GradAccum(
-            bufs=[jnp.zeros((b.size,), jnp.float32)
+            bufs=[jnp.zeros(b.shape, jnp.float32)
                   for b in plan.buckets],
             found_inf=jnp.int32(0), count=jnp.int32(0))
 

@@ -26,16 +26,17 @@ from apex_tpu.ops.multi_tensor import flat_l2norm, flat_scale
 
 
 def _is_packed(grads) -> bool:
-    """A per-bucket flat-buffer list (BucketPlan layout): a PLAIN
-    list/tuple of 1-D float arrays — exact types only, so NamedTuple
+    """A per-bucket buffer list (BucketPlan layout: 1-D buffers, and a
+    one-leaf bucket's in its leaf's shape): a PLAIN list/tuple of float
+    arrays of one axis or more — exact types only, so NamedTuple
     pytrees (whose constructors take positional fields and would not
     survive the packed-path rebuild) keep the ravel_pytree path.
     Clipping by GLOBAL norm is layout-invariant, so treating a genuine
-    list-of-vectors pytree this way returns the same values — only the
+    list-of-arrays pytree this way returns the same values — only the
     (faster) code path differs."""
     if type(grads) not in (list, tuple) or not grads:
         return False
-    return all(getattr(g, "ndim", None) == 1
+    return all(getattr(g, "ndim", 0) >= 1
                and hasattr(g, "dtype")
                and jnp.issubdtype(g.dtype, jnp.floating) for g in grads)
 

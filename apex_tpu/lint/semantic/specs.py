@@ -102,12 +102,14 @@ def _optimizer(name, masters=False, **kw):
 
 
 def _step_args(opt):
+    """The step program's arguments: the packed operands as ``step()``
+    gives them (the optimizer's own ``_step_args``), all-ones
+    gradients, step 1 and the float hypers."""
     import jax
     import jax.numpy as jnp
     grads = jax.tree_util.tree_map(jnp.ones_like, opt.params)
-    work = opt._param_bufs if opt._plan is not None else opt.params
-    masters = opt._master_bufs if opt._plan is not None else None
-    return (work, masters, opt.opt_state, grads, jnp.int32(1),
+    params, masters, state = opt._step_args(grads)[:3]
+    return (params, masters, state, grads, jnp.int32(1),
             jnp.float32(1.0), _traced_hypers(opt), jnp.int32(0))
 
 

@@ -15,7 +15,12 @@ from apex_tpu.multi_tensor_apply.multi_tensor_apply import (
     flatten_tensors,
     unflatten_tensors,
 )
-from apex_tpu.multi_tensor_apply.packer import Bucket, BucketPlan, LeafSpec
+from apex_tpu.multi_tensor_apply.packer import (
+    Bucket,
+    BucketPlan,
+    LeafSpec,
+    PackedState,
+)
 
 __all__ = [
     "MultiTensorApply",
@@ -27,4 +32,5 @@ __all__ = [
     "Bucket",
     "BucketPlan",
     "LeafSpec",
+    "PackedState",
 ]

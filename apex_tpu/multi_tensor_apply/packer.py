@@ -5,13 +5,20 @@ every step (cheap on CUDA — it's host pointer math).  On TPU the analog
 must not re-trace or re-concatenate per step, so the plan is computed
 ONCE at optimizer init: dtype-homogeneous parameter leaves are assigned
 to buckets, each bucket a single flat HBM buffer with static
-shape+offset metadata.  The jitted optimizer step then runs one flat
-Pallas kernel per bucket (see apex_tpu.ops.multi_tensor), and the
-packed buffers are the persistent representation — params, masters and
+shape+offset metadata.  The jitted optimizer step then runs one chain
+of sweeps per bucket (see apex_tpu.ops.multi_tensor), and the packed
+buffers are the persistent representation — params, masters and
 optimizer state stay packed BETWEEN steps.  Unpacking (static
 ``lax.slice`` + reshape per leaf, offsets are Python ints) happens only
 on the rare host-facing paths: ``state_dict()``, ``load_state_dict()``
 and the ``params`` property.
+
+A bucket that holds ONE leaf keeps that leaf's shape (``Bucket.shape``)
+for every buffer of it: its pack is the leaf, its unpack the buffer.
+Nothing in such a bucket needs the flat layout (its one segment is the
+whole buffer, and every update is elementwise or per leaf), while on
+TPU raveling a tiled (R, C) matrix into a 1-D buffer is a relayout
+XLA pays as copies on every pack and unpack.
 
 Per-tensor semantics (LAMB trust ratios, NovoGrad per-tensor second
 moments) survive packing through each bucket's ``segment_sizes``: the
@@ -42,6 +49,37 @@ class Bucket(NamedTuple):
     model_dtype: Any      # model-param dtype (== dtype without masters)
     leaves: Tuple[LeafSpec, ...]
     size: int             # total element count (exact, unpadded)
+
+    @property
+    def leaf_shaped(self) -> bool:
+        """True for a bucket of one (non-scalar) leaf: its buffers keep
+        the leaf's shape instead of the flat 1-D layout."""
+        return len(self.leaves) == 1 and len(self.leaves[0].shape) > 0
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        """The shape of every parameter-mirroring buffer of the
+        bucket: the leaf's own where ``leaf_shaped``, else 1-D."""
+        return self.leaves[0].shape if self.leaf_shaped else (self.size,)
+
+
+class PackedState(dict):
+    """An optimizer state in a plan's packed layout: field name -> one
+    buffer per bucket.  A dict of a type of its own, so the layout is
+    known without reading shapes: a per-leaf state of a plain-list
+    params tree, each leaf alone in a bucket, has the same structure
+    and shapes, in the leaf order rather than the bucket order."""
+
+
+def _packed_state_keys(d):
+    keys = tuple(sorted(d))
+    return [(jax.tree_util.DictKey(k), d[k]) for k in keys], keys
+
+
+jax.tree_util.register_pytree_with_keys(
+    PackedState, _packed_state_keys,
+    lambda keys, values: PackedState(zip(keys, values)),
+    flatten_func=lambda d: ([d[k] for k in sorted(d)], tuple(sorted(d))))
 
 
 def _leaf_arrays(tree) -> List[jax.Array]:
@@ -132,16 +170,22 @@ class BucketPlan:
 
     # ---- packing ---------------------------------------------------------
     def pack(self, tree: Pytree, dtypes=None) -> List[jax.Array]:
-        """Pytree -> one flat buffer per bucket.  Trace-safe (the
-        jitted step packs the incoming grads this way: one concatenate
-        per bucket, not per leaf).  ``dtypes``: per-bucket target dtype
-        (defaults to whatever concatenation yields — homogeneous
-        inputs keep their dtype)."""
+        """Pytree -> one buffer per bucket.  Trace-safe (the jitted
+        step packs the incoming grads this way: one concatenate per
+        bucket, not per leaf; a leaf-shaped bucket's buffer is its
+        leaf).  ``dtypes``: per-bucket target dtype (defaults to
+        whatever concatenation yields — homogeneous inputs keep their
+        dtype)."""
         leaves = _leaf_arrays(tree)
         out = []
         for bi, b in enumerate(self.buckets):
-            parts = [jnp.ravel(leaves[s.index]) for s in b.leaves]
-            buf = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+            if b.leaf_shaped:
+                # the leaf itself (the reshape only makes an array of
+                # a host one)
+                buf = jnp.reshape(leaves[b.leaves[0].index], b.shape)
+            else:
+                buf = jnp.concatenate(
+                    [jnp.ravel(leaves[s.index]) for s in b.leaves])
             want = dtypes[bi] if dtypes is not None else None
             if want is not None and buf.dtype != want:
                 buf = buf.astype(want)
@@ -163,29 +207,48 @@ class BucketPlan:
         return self.pack(tree)
 
     def is_packed(self, obj) -> bool:
-        """True iff ``obj`` is a per-bucket flat-buffer list matching
-        this plan: one 1-D buffer per bucket, each exactly bucket-sized.
-        Shape-only (works on tracers); used by step()/clip_grad to
-        accept already-packed gradients without re-packing."""
+        """True iff ``obj`` is a per-bucket buffer list matching this
+        plan: one buffer per bucket, each of the bucket's shape, and
+        not a tree of this plan (``reads_as_tree``).  Shape-only (works
+        on tracers); used by step() and the flat pipeline to accept
+        already-packed gradients without re-packing."""
         if not isinstance(obj, (list, tuple)) \
                 or len(obj) != len(self.buckets):
             return False
-        return all(
-            getattr(buf, "ndim", None) == 1
-            and tuple(buf.shape) == (b.size,)
-            for buf, b in zip(obj, self.buckets))
+        return all(tuple(getattr(buf, "shape", ())) == b.shape
+                   for buf, b in zip(obj, self.buckets)) \
+            and not self.reads_as_tree(obj)
+
+    def reads_as_tree(self, obj) -> bool:
+        """True where ``obj`` has this plan's own tree structure.  Only
+        a params tree that is a plain list of arrays, each alone in a
+        bucket of its shape, has the structure of its packed buffers
+        too, and the bucket order (dtype groups first) need not be the
+        leaf order: such an object is read as the tree.  Packed buffers
+        known as such go in as the tree of them (``unpack_grads``: a
+        reorder, no copy), or, for an optimizer state, as a
+        ``PackedState``."""
+        return jax.tree_util.tree_structure(obj) == self.treedef
 
     # ---- unpacking -------------------------------------------------------
-    def _unpack_leaves(self, bufs: Sequence[jax.Array],
-                       dtypes=None) -> List[jax.Array]:
+    def _unpack_leaves(self, bufs: Sequence[Optional[jax.Array]],
+                       dtypes=None) -> List[Optional[jax.Array]]:
+        """Per-leaf arrays in leaf-index order; the leaves of a bucket
+        whose buffer is None stay None."""
         leaves: List[Optional[jax.Array]] = [None] * self.n_leaves
         for bi, b in enumerate(self.buckets):
             buf = bufs[bi]
+            if buf is None:
+                continue
             want = dtypes[bi] if dtypes is not None else None
             for s in b.leaves:
-                # static offsets -> lax.slice: XLA sees fixed layout
-                leaf = jax.lax.slice(buf, (s.offset,),
-                                     (s.offset + s.size,)).reshape(s.shape)
+                if b.leaf_shaped:
+                    leaf = jnp.reshape(buf, s.shape)  # the buffer itself
+                else:
+                    # static offsets -> lax.slice: XLA sees fixed layout
+                    leaf = jax.lax.slice(
+                        buf, (s.offset,),
+                        (s.offset + s.size,)).reshape(s.shape)
                 if want is not None and leaf.dtype != want:
                     leaf = leaf.astype(want)
                 leaves[s.index] = leaf
@@ -207,9 +270,14 @@ class BucketPlan:
     def unpack_model(self, bufs: Sequence[jax.Array]) -> Pytree:
         """Per-bucket flat buffers -> pytree in the MODEL dtypes."""
         return jax.tree_util.tree_unflatten(
-            self.treedef,
-            self._unpack_leaves(bufs,
-                                [b.model_dtype for b in self.buckets]))
+            self.treedef, self.model_leaves(bufs))
+
+    def model_leaves(self, bufs: Sequence[Optional[jax.Array]]
+                     ) -> List[Optional[jax.Array]]:
+        """``unpack_model``'s leaves in leaf-index order; the leaves of
+        a bucket whose buffer is None stay None."""
+        return self._unpack_leaves(bufs,
+                                   [b.model_dtype for b in self.buckets])
 
     # ---- optimizer-state packing ----------------------------------------
     # Generic rule covering every fused optimizer's state layout:
@@ -257,11 +325,14 @@ class BucketPlan:
         # so either unpack is correct.  State dtypes (f32 moments even
         # for bf16 work buffers) are preserved: no work-dtype cast here.
         # A 2-D (num leaves, H) buffer is the row-stacked per-leaf-
-        # vector layout (fp8 amax history) — unambiguous: the flat
-        # pack always yields 1-D buffers.
-        if all(getattr(bufs[bi], "ndim", None) == 2
-               and bufs[bi].shape[0] == len(b.leaves)
-               for bi, b in enumerate(self.buckets)):
+        # vector layout (fp8 amax history), unless every buffer has its
+        # bucket's own shape: the pack of a field that mirrors the
+        # params (a leaf-shaped bucket's buffer may be 2-D too).
+        mirror = all(tuple(getattr(bufs[bi], "shape", ())) == b.shape
+                     for bi, b in enumerate(self.buckets))
+        if not mirror and all(getattr(bufs[bi], "ndim", None) == 2
+                              and bufs[bi].shape[0] == len(b.leaves)
+                              for bi, b in enumerate(self.buckets)):
             leaves: List[Optional[jax.Array]] = [None] * self.n_leaves
             for bi, b in enumerate(self.buckets):
                 for j, s in enumerate(b.leaves):
@@ -295,7 +366,8 @@ class BucketPlan:
         """Human/bench-facing plan summary."""
         return [{"dtype": str(np.dtype(b.dtype)),
                  "model_dtype": str(np.dtype(b.model_dtype)),
-                 "leaves": len(b.leaves), "elements": b.size}
+                 "leaves": len(b.leaves), "elements": b.size,
+                 "leaf_shaped": b.leaf_shaped}
                 for b in self.buckets]
 
     # ---- layout (de)serialization ----------------------------------------
@@ -319,7 +391,9 @@ class BucketPlan:
         a flat bucket buffer back into per-leaf arrays on the host with
         no device traffic, and (b) decide whether a restoring
         optimizer's own plan matches bit-for-bit (same doc ==> packed
-        buffers can be adopted directly)."""
+        buffers can be adopted directly).  A leaf-shaped bucket's
+        buffer has the same bytes, row-major, as its flat one, so the
+        record holds for both: the payload is stored flat."""
         return {
             "paths": self.leaf_paths(),
             "buckets": [

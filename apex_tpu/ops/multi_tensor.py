@@ -12,7 +12,8 @@ an on-device i32 flag (never a host sync — the reference's host-side
 overflow read is a known sync point, SURVEY.md §3.2).
 
 Pallas kernels (ONE pallas_call whose grid walks (rows, 128)-shaped
-VMEM tiles), each with a pure-jnp oracle suffixed ``_ref`` that tests
+VMEM tiles of the raveled buffer, or, where a buffer keeps a leaf's
+tiled shape, (rows, columns) tiles of that shape), each with a pure-jnp oracle suffixed ``_ref`` that tests
 compare against and that runs when Pallas is disabled: ``flat_scale``,
 ``flat_axpby``, ``flat_accumulate``, ``flat_unscale_norm``,
 ``flat_l2norm``.  ``flat_amax_scale_update`` is ``jnp`` over the static
@@ -29,7 +30,8 @@ opaque to it and pays a pad, a slice, a select and a cast around itself
 
 from __future__ import annotations
 
-from typing import Tuple
+import math
+from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,30 +50,81 @@ def _round_up(n: int, m: int) -> int:
     return (n + m - 1) // m * m
 
 
-def _as_tiles(x: jax.Array) -> Tuple[jax.Array, int]:
-    """Pad a 1-D buffer with zeros and view it as (rows, 128) tiles.
+class _Tiles(NamedTuple):
+    """A buffer as the 2-D array a kernel's grid walks: ``x2d``, the
+    ``grid``, the operands' ``spec`` and the buffer's ``shape``."""
+    x2d: jax.Array
+    grid: Tuple[int, int]
+    spec: Callable[[], pl.BlockSpec]
+    shape: Tuple[int, ...]
+
+
+def _native_block(shape, itemsize):
+    """(row block, column block) for tiling a buffer in its own shape,
+    or None.  It takes a buffer of two axes or more whose last is a
+    whole number of lanes, whose rows (the other axes merged) a row
+    block divides, and whose second-last axis keeps whole sublane
+    tiles through that merge: the (R, C) view is then the buffer's own
+    tiled layout, where a ravel to (n/128, 128) is a relayout (a
+    leaf-shaped bucket, ``BucketPlan``)."""
+    if len(shape) < 2 or shape[-1] % LANE:
+        return None
+    if len(shape) > 2 and shape[-2] % (SUBLANE * max(4 // itemsize, 1)):
+        return None
+    rows = math.prod(shape[:-1])
+    br = next((b for b in (128, 64, 32) if rows % b == 0), None)
+    if br is None:
+        return None
+    return br, next(b for b in (512, 256, LANE) if shape[-1] % b == 0)
+
+
+def _as_tiles(x: jax.Array, *others: jax.Array) -> Tuple[_Tiles, ...]:
+    """``x`` and the ``others`` of its shape as kernel tiles alike: in
+    their own shape where ``_native_block`` allows (the leading axes
+    merged, no copy), else raveled, padded with zeros to whole
+    (BLOCK_ROWS, 128) blocks and viewed as (rows, 128).
 
     Rows are padded to a whole grid block so kernels never read
     out-of-bounds garbage (it would poison the non-finite flag).
     """
-    n = x.size
-    rows = _round_up(max(pl.cdiv(n, LANE), 1), BLOCK_ROWS)
-    pad = rows * LANE - n
-    if pad:
-        x = jnp.pad(x, (0, pad))
-    return x.reshape(rows, LANE), n
+    block = None
+    if all(o.shape == x.shape for o in others):
+        block = _native_block(
+            x.shape, min(jnp.dtype(a.dtype).itemsize for a in (x,) + others))
+    return tuple(_tiles(a, block) for a in (x,) + others)
 
 
-def _from_tiles(x2d: jax.Array, n: int) -> jax.Array:
-    return x2d.reshape(-1)[:n]
+def _tiles(x: jax.Array, block) -> _Tiles:
+    if block is not None:
+        x2d = x.reshape(-1, x.shape[-1])
+        grid = (x2d.shape[0] // block[0], x2d.shape[1] // block[1])
+    else:
+        n = x.size
+        rows = _round_up(max(pl.cdiv(n, LANE), 1), BLOCK_ROWS)
+        pad = rows * LANE - n
+        flat = jnp.ravel(x)
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        x2d = flat.reshape(rows, LANE)
+        block = (BLOCK_ROWS, LANE)
+        grid = (rows // BLOCK_ROWS, 1)
+    return _Tiles(x2d, grid,
+                  lambda: pl.BlockSpec(block, lambda i, j: (i, j)),
+                  tuple(x.shape))
 
 
-def _grid(rows: int) -> int:
-    return pl.cdiv(rows, BLOCK_ROWS)
+def _from_tiles(x2d: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
+    """The tiles' leading elements in the input's ``shape``."""
+    n = math.prod(shape)
+    if x2d.size != n:
+        x2d = x2d.reshape(-1)[:n]
+    return x2d.reshape(shape)
 
 
-def _vec_spec():
-    return pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))
+def _first_step():
+    """True on the grid's first step: where the kernels' scalar
+    outputs start."""
+    return jnp.logical_and(pl.program_id(0) == 0, pl.program_id(1) == 0)
 
 
 def _scalar_out_spec():
@@ -112,9 +165,7 @@ def _all_finite(x):
 # ---------------------------------------------------------------------------
 
 def _scale_kernel(s_ref, x_ref, o_ref, flag_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(_first_step())
     def _():
         flag_ref[0] = 0
 
@@ -134,21 +185,21 @@ def flat_scale(x: jax.Array, scale: jax.Array, out_dtype=None):
     out_dtype = out_dtype or x.dtype
     if not op_enabled("multi_tensor"):
         return flat_scale_ref(x, scale, out_dtype)
-    x2d, n = _as_tiles(x)
+    (t,) = _as_tiles(x)
     scale = jnp.asarray([scale], jnp.float32).reshape(1)
     out, flag = pl.pallas_call(
         _scale_kernel,
-        grid=(_grid(x2d.shape[0]),),
-        in_specs=[_smem_spec(), _vec_spec()],
-        out_specs=[_vec_spec(), _scalar_out_spec()],
+        grid=t.grid,
+        in_specs=[_smem_spec(), t.spec()],
+        out_specs=[t.spec(), _scalar_out_spec()],
         out_shape=[
-            jax.ShapeDtypeStruct(x2d.shape, out_dtype),
+            jax.ShapeDtypeStruct(t.x2d.shape, out_dtype),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         interpret=interpret_mode(),
         name="apex_multi_tensor_scale",
-    )(scale, x2d)
-    return _from_tiles(out, n), flag[0]
+    )(scale, t.x2d)
+    return _from_tiles(out, t.shape), flag[0]
 
 
 def flat_scale_ref(x, scale, out_dtype=None):
@@ -163,9 +214,7 @@ def flat_scale_ref(x, scale, out_dtype=None):
 # ---------------------------------------------------------------------------
 
 def _axpby_kernel(s_ref, x_ref, y_ref, o_ref, flag_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(_first_step())
     def _():
         flag_ref[0] = 0
 
@@ -180,22 +229,21 @@ def flat_axpby(a, x: jax.Array, b, y: jax.Array, out_dtype=None):
     out_dtype = out_dtype or x.dtype
     if not op_enabled("multi_tensor"):
         return flat_axpby_ref(a, x, b, y, out_dtype)
-    x2d, n = _as_tiles(x)
-    y2d, _ = _as_tiles(y)
+    t, ty = _as_tiles(x, y)
     s = jnp.stack([jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)])
     out, flag = pl.pallas_call(
         _axpby_kernel,
-        grid=(_grid(x2d.shape[0]),),
-        in_specs=[_smem_spec(), _vec_spec(), _vec_spec()],
-        out_specs=[_vec_spec(), _scalar_out_spec()],
+        grid=t.grid,
+        in_specs=[_smem_spec(), t.spec(), t.spec()],
+        out_specs=[t.spec(), _scalar_out_spec()],
         out_shape=[
-            jax.ShapeDtypeStruct(x2d.shape, out_dtype),
+            jax.ShapeDtypeStruct(t.x2d.shape, out_dtype),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         interpret=interpret_mode(),
         name="apex_multi_tensor_axpby",
-    )(s, x2d, y2d)
-    return _from_tiles(out, n), flag[0]
+    )(s, t.x2d, ty.x2d)
+    return _from_tiles(out, t.shape), flag[0]
 
 
 def flat_axpby_ref(a, x, b, y, out_dtype=None):
@@ -212,9 +260,7 @@ def flat_axpby_ref(a, x, b, y, out_dtype=None):
 # ---------------------------------------------------------------------------
 
 def _accumulate_kernel(s_ref, a_ref, g_ref, o_ref, flag_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(_first_step())
     def _():
         flag_ref[0] = 0
 
@@ -243,23 +289,22 @@ def flat_accumulate(acc: jax.Array, g: jax.Array, scale=1.0):
         raise ValueError(f"accumulator must be f32, got {acc.dtype}")
     if not op_enabled("multi_tensor"):
         return flat_accumulate_ref(acc, g, scale)
-    a2d, n = _as_tiles(acc)
-    g2d, _ = _as_tiles(g)
+    t, tg = _as_tiles(acc, g)
     s = jnp.asarray([scale], jnp.float32).reshape(1)
     out, flag = pl.pallas_call(
         _accumulate_kernel,
-        grid=(_grid(a2d.shape[0]),),
-        in_specs=[_smem_spec(), _vec_spec(), _vec_spec()],
-        out_specs=[_vec_spec(), _scalar_out_spec()],
+        grid=t.grid,
+        in_specs=[_smem_spec(), t.spec(), t.spec()],
+        out_specs=[t.spec(), _scalar_out_spec()],
         out_shape=[
-            jax.ShapeDtypeStruct(a2d.shape, jnp.float32),
+            jax.ShapeDtypeStruct(t.x2d.shape, jnp.float32),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         input_output_aliases={1: 0},
         interpret=interpret_mode(),
         name="apex_multi_tensor_accumulate",
-    )(s, a2d, g2d)
-    return _from_tiles(out, n), flag[0]
+    )(s, t.x2d, tg.x2d)
+    return _from_tiles(out, t.shape), flag[0]
 
 
 def flat_accumulate_ref(acc, g, scale=1.0):
@@ -277,9 +322,7 @@ def flat_accumulate_ref(acc, g, scale=1.0):
 # ---------------------------------------------------------------------------
 
 def _unscale_norm_kernel(s_ref, x_ref, o_ref, acc_ref, flag_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(_first_step())
     def _():
         acc_ref[0] = jnp.float32(0.0)
         flag_ref[0] = 0
@@ -306,22 +349,22 @@ def flat_unscale_norm(x: jax.Array, inv_scale, out_dtype=None):
     out_dtype = out_dtype or x.dtype
     if not op_enabled("multi_tensor"):
         return flat_unscale_norm_ref(x, inv_scale, out_dtype)
-    x2d, n = _as_tiles(x)
+    (t,) = _as_tiles(x)
     s = jnp.asarray([inv_scale], jnp.float32).reshape(1)
     out, acc, flag = pl.pallas_call(
         _unscale_norm_kernel,
-        grid=(_grid(x2d.shape[0]),),
-        in_specs=[_smem_spec(), _vec_spec()],
-        out_specs=[_vec_spec(), _scalar_out_spec(), _scalar_out_spec()],
+        grid=t.grid,
+        in_specs=[_smem_spec(), t.spec()],
+        out_specs=[t.spec(), _scalar_out_spec(), _scalar_out_spec()],
         out_shape=[
-            jax.ShapeDtypeStruct(x2d.shape, out_dtype),
+            jax.ShapeDtypeStruct(t.x2d.shape, out_dtype),
             jax.ShapeDtypeStruct((1,), jnp.float32),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         interpret=interpret_mode(),
         name="apex_multi_tensor_unscale_norm",
-    )(s, x2d)
-    return _from_tiles(out, n), acc[0], flag[0]
+    )(s, t.x2d)
+    return _from_tiles(out, t.shape), acc[0], flag[0]
 
 
 def flat_unscale_norm_ref(x, inv_scale, out_dtype=None):
@@ -336,9 +379,7 @@ def flat_unscale_norm_ref(x, inv_scale, out_dtype=None):
 # ---------------------------------------------------------------------------
 
 def _l2norm_kernel(x_ref, acc_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(_first_step())
     def _():
         acc_ref[0] = jnp.float32(0.0)
 
@@ -350,16 +391,16 @@ def flat_l2norm(x: jax.Array) -> jax.Array:
     """Global L2 norm of a flat buffer (f32 accumulation)."""
     if not op_enabled("multi_tensor"):
         return flat_l2norm_ref(x)
-    x2d, _ = _as_tiles(x)
+    (t,) = _as_tiles(x)
     acc = pl.pallas_call(
         _l2norm_kernel,
-        grid=(_grid(x2d.shape[0]),),
-        in_specs=[_vec_spec()],
+        grid=t.grid,
+        in_specs=[t.spec()],
         out_specs=_scalar_out_spec(),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
         interpret=interpret_mode(),
         name="apex_multi_tensor_l2norm",
-    )(x2d)
+    )(t.x2d)
     return jnp.sqrt(acc[0])
 
 
@@ -466,10 +507,15 @@ def flat_adagrad(p, g, h, *, lr, eps, weight_decay=0.0, grad_scale=1.0,
 # ---------------------------------------------------------------------------
 
 def _segments(x, sizes):
-    """The flat buffer's segments as static slices, in f32."""
-    if sum(sizes) != x.shape[0]:
+    """The flat buffer's segments as static slices, in f32.  A buffer
+    of one segment is that segment whatever its shape (a leaf-shaped
+    bucket, ``BucketPlan``)."""
+    if sum(sizes) != x.size:
         raise ValueError(f"segment sizes sum to {sum(sizes)}, the buffer "
-                         f"holds {x.shape[0]} elements")
+                         f"holds {x.size} elements")
+    if len(sizes) == 1:
+        yield _f32(x)
+        return
     offset = 0
     for size in sizes:
         yield _f32(jax.lax.slice(x, (offset,), (offset + size,)))
@@ -500,7 +546,11 @@ def flat_segment_absmax(x, sizes):
 def flat_segment_broadcast(values, sizes):
     """Per-element buffer holding each segment's scalar over its static
     extent (``values``: ``(len(sizes),)``): a concatenate of broadcasts
-    the compiler builds inside the program."""
+    the compiler builds inside the program.  One segment is the whole
+    buffer, of whatever shape: its scalar, which broadcasts against
+    it."""
+    if len(sizes) == 1:
+        return values[0]
     return jnp.concatenate([jnp.full((size,), values[j], values.dtype)
                             for j, size in enumerate(sizes)])
 
@@ -561,10 +611,10 @@ def flat_amax_scale_update_ref(buf, sizes, amax_history, scale, *,
     """Oracle: per-segment amax via scatter-max through an
     element->segment id vector instead of the static slices; identical
     update math (bit-exact by test)."""
-    seg_ids = flat_segment_broadcast(
-        jnp.arange(len(sizes), dtype=jnp.int32), sizes)
+    seg_ids = jnp.repeat(jnp.arange(len(sizes), dtype=jnp.int32),
+                         jnp.asarray(sizes), total_repeat_length=buf.size)
     amax = jnp.zeros((len(sizes),), jnp.float32).at[seg_ids].max(
-        jnp.abs(_f32(buf)))
+        jnp.abs(_f32(jnp.ravel(buf))))
     return _amax_scale_math(amax, amax_history, scale, fp8_max, margin,
                             backoff_factor, max_scale, min_scale,
                             update)

@@ -21,9 +21,12 @@ between steps; the per-leaf pytree view is rebuilt lazily (one compiled
 unpack program) only for ``state_dict()``, ``load_state_dict()`` and the
 ``params``/``masters`` properties, and the checkpoint layout is
 unchanged — old per-leaf checkpoints load into bucketed optimizers and
-vice versa.  ``fuse_buckets=False`` (or any
-tree the packer declines: non-float leaves, multi-device shardings)
-falls back to the traced per-leaf update.
+vice versa.  A bucket of one leaf keeps the leaf's shape
+(``Bucket.leaf_shaped``); with masters, its model-dtype buffer IS the
+leaf ``params`` hands out, so the step neither reads nor donates it.
+``fuse_buckets=False`` (or any tree the packer declines: non-float
+leaves, multi-device shardings) falls back to the traced per-leaf
+update.
 
 Ownership on the bucketed path (docs/optimizers.md): the optimizer
 COPIES IN whatever it is handed (constructor, ``params`` / ``masters``
@@ -31,8 +34,10 @@ setters, ``load_state_dict``, ``load_packed_snapshot``: the pack, or a
 copy where the pack would be the caller's own array), the step DONATES
 the packed parameters, masters and state and updates them in place, and
 everything handed OUT (``step()``'s result, ``params``, ``masters``,
-``state_dict()``, ``packed_snapshot()``) is a fresh array the caller
-may keep across any number of steps.
+``state_dict()``, ``packed_snapshot()``) is an array the step never
+donates — a fresh one, or the model-dtype buffer of a one-leaf bucket
+with masters, which the step replaces without reading — so the caller
+may keep it across any number of steps.
 
 Master weights: when params are bf16/fp16 and ``master_weights=True`` the
 facade keeps f32 masters, steps those, and writes back model-dtype params
@@ -41,13 +46,15 @@ facade keeps f32 masters, steps those, and writes back model-dtype params
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.multi_tensor_apply.packer import BucketPlan
+from apex_tpu.multi_tensor_apply.packer import BucketPlan, PackedState
 from apex_tpu.telemetry import _tape
+from apex_tpu.telemetry import hostmetrics as _hostmetrics
 from apex_tpu.telemetry.retrace import mark_step, phased
 from apex_tpu.telemetry.spans import span
 
@@ -98,13 +105,14 @@ def _replica_mesh(tree: Pytree):
     return None
 
 
-def _own_pack(bufs, tree: Pytree) -> List[jax.Array]:
-    """``bufs`` as packed from ``tree``, none of them one of its
-    leaves: a one-leaf bucket of a flat leaf that already has the
-    target dtype packs to the leaf ITSELF (``BucketPlan.pack``), and
-    the step donates what the optimizer holds — that one is copied."""
-    theirs = {id(x) for x in jax.tree_util.tree_leaves(tree)}
-    return [jnp.copy(b) if id(b) in theirs else b for b in bufs]
+def _apart(tree: Pytree, other: Pytree) -> Pytree:
+    """``tree`` with every leaf that is also a leaf of ``other``
+    copied.  A one-leaf bucket packs to its leaf ITSELF where the dtype
+    matches, and unpacks to its buffer itself (``BucketPlan``); the
+    step donates what the optimizer holds, so nothing packed in from a
+    caller's tree, or unpacked out to one, may be the same array."""
+    theirs = {id(x) for x in jax.tree_util.tree_leaves(other)}
+    return tree_map(lambda x: jnp.copy(x) if id(x) in theirs else x, tree)
 
 
 def _owned(buf) -> jax.Array:
@@ -264,6 +272,7 @@ class FusedOptimizerBase:
             self._adopt(params, masters)
             self.opt_state = self.init_state_packed(self._plan, work)
             self._full_step_impl = self._full_step_flat
+            self._emit_plan_counters()
         else:
             self._params_tree = params
             self._masters_tree = masters
@@ -300,6 +309,14 @@ class FusedOptimizerBase:
                                tree_map(_host_sharding, self.opt_state)))
         return jax.jit(self._full_step_impl, **self._donation)
 
+    def _emit_plan_counters(self):
+        """The plan's leaf-shaped buckets and the parameters they hold,
+        as host counters (static: once a plan)."""
+        shaped = [b for b in self._plan.buckets if b.leaf_shaped]
+        _hostmetrics.emit("optim/leaf_shaped_buckets", len(shaped))
+        _hostmetrics.emit("optim/leaf_shaped_params",
+                          sum(b.size for b in shaped))
+
     @property
     def _donation(self):
         """``jax.jit``'s arguments that say what the step program
@@ -308,7 +325,10 @@ class FusedOptimizerBase:
         optimizer holds (``_adopt``).  ``keep_unused``: with masters
         the program never reads the packed model-dtype parameters, and
         an argument jit prunes cannot give its buffer to the output
-        that replaces it.  The per-leaf path's trees are the caller's."""
+        that replaces it.  Those of the ``_shared`` buckets are the
+        caller's too: ``_step_args`` passes None in their place, so
+        they are not arguments at all.  The per-leaf path's trees are
+        the caller's."""
         if self._plan is None:
             return {"donate_argnums": (2,)}
         work = 0 if self._master_bufs is None else 1
@@ -322,14 +342,58 @@ class FusedOptimizerBase:
         into buffers of the optimizer's own.  ``params`` stays as the
         cached tree the model reads; the masters' tree is not kept —
         the ``masters`` property unpacks on demand, as after a step."""
-        self._param_bufs = _own_pack(self._plan.pack_model(params), params)
+        self._param_bufs = _apart(self._plan.pack_model(params), params)
         self._master_bufs = (
-            _own_pack(self._plan.pack_work(masters), masters)
+            _apart(self._plan.pack_work(masters), masters)
             if masters is not None else None)
         self._params_cache = params
         self._masters_cache = None
-        self._unpack_model_jit = jax.jit(self._plan.unpack_model)
+        self._unpack_model_jit = jax.jit(self._plan.model_leaves)
         self._unpack_work_jit = jax.jit(self._plan.unpack)
+
+    @property
+    def _shared(self):
+        """Indices of the buckets whose packed model-dtype buffer is
+        also the params leaf handed out: leaf-shaped ones with masters
+        of another dtype.  The step never reads such a buffer — it
+        writes a fresh one from the masters — so it is handed out as
+        it is, and neither passed to the step nor donated."""
+        if self._master_bufs is None:
+            return ()
+        return tuple(bi for bi, b in enumerate(self._plan.buckets)
+                     if b.leaf_shaped and b.model_dtype != b.dtype)
+
+    def _held_model_bufs(self) -> List[jax.Array]:
+        """``_param_bufs``, once none of the ``_shared`` ones is
+        deleted.  Such a buffer is also the leaf ``params`` handed
+        out, so a caller that donated that leaf to a program of its
+        own deleted it (docs/optimizers.md, Ownership); the next step
+        writes a fresh one from the masters."""
+        for bi in self._shared:
+            if self._param_bufs[bi].is_deleted():
+                raise RuntimeError(
+                    f"bucket {bi}'s parameters were deleted: with "
+                    "masters, a one-leaf bucket's model-dtype buffer IS "
+                    "the leaf `params` and `step()` hand out, and a "
+                    "program that donated that leaf deleted it.  Do not "
+                    "donate what the optimizer hands out, or take this "
+                    "after the next step(), which writes it anew")
+        return self._param_bufs
+
+    def _model_view(self, bufs) -> Pytree:
+        """The params pytree of packed model-dtype ``bufs``: the
+        ``_shared`` buckets' buffers as they are, the others' leaves
+        from the one compiled unpack program (fresh arrays)."""
+        shared = self._shared
+        plan = self._plan
+        if len(shared) == len(bufs):
+            leaves = [None] * plan.n_leaves
+        else:
+            leaves = self._unpack_model_jit(
+                [None if bi in shared else b for bi, b in enumerate(bufs)])
+        for bi in shared:
+            leaves[plan.buckets[bi].leaves[0].index] = bufs[bi]
+        return jax.tree_util.tree_unflatten(plan.treedef, leaves)
 
     # ---- packed views ----------------------------------------------------
     @property
@@ -337,13 +401,13 @@ class FusedOptimizerBase:
         """The current params pytree.  On the bucketed path this unpacks
         lazily — ONE compiled slice-and-reshape program per step, cached
         until the next step — so the packed buffers stay the canonical
-        representation."""
+        representation.  A ``_shared`` bucket's leaf is its packed
+        buffer itself: no copy, and no program."""
         if self._plan is None:
             return self._params_tree
         if self._params_cache is None:
             with span("apex/optim/unpack_model"):
-                self._params_cache = self._unpack_model_jit(
-                    self._param_bufs)
+                self._params_cache = self._model_view(self._param_bufs)
         return self._params_cache
 
     @params.setter
@@ -351,7 +415,7 @@ class FusedOptimizerBase:
         if self._plan is None:
             self._params_tree = value
         else:
-            self._param_bufs = _own_pack(self._plan.pack_model(value),
+            self._param_bufs = _apart(self._plan.pack_model(value),
                                          value)
             self._params_cache = value
 
@@ -375,7 +439,7 @@ class FusedOptimizerBase:
             self._master_bufs = None
             self._masters_cache = None
         else:
-            self._master_bufs = _own_pack(self._plan.pack_work(value),
+            self._master_bufs = _apart(self._plan.pack_work(value),
                                           value)
             self._masters_cache = None
 
@@ -390,9 +454,9 @@ class FusedOptimizerBase:
         per bucket).  ``init_state`` and the packs are ONE program over
         the work tree whose outputs are the buffers, so no per-leaf
         state tree is ever live beside them."""
-        return jax.jit(lambda work: {
+        return jax.jit(lambda work: PackedState({
             k: plan.pack_state_field(v)
-            for k, v in self.init_state(work).items()})(work)
+            for k, v in self.init_state(work).items()}))(work)
 
     def _step_math(self, params, grads, opt_state, step, grad_scale, hypers):
         """Pure per-leaf update on the (possibly master) params."""
@@ -457,7 +521,8 @@ class FusedOptimizerBase:
                 with jax.named_scope("apex_optim/skip_select"):
                     new_fp8 = _select(keep, new_fp8, fp8_state)
             new_state.update(new_fp8)
-        return new_bufs, new_state, new_model if cast_model else None
+        return (new_bufs, PackedState(new_state),
+                new_model if cast_model else None)
 
     def _fp8_slot_update(self, new_work_bufs, fp8_state, step):
         """The packed fp8 weight-scale slots' delayed-scaling
@@ -506,7 +571,7 @@ class FusedOptimizerBase:
             slots = place_on_host(slots)
         # a new opt_state STRUCTURE: the jitted step re-traces on the
         # next call (jit keys on pytree structure), no re-jit needed
-        self.opt_state = {**self.opt_state, **slots}
+        self.opt_state = PackedState({**self.opt_state, **slots})
 
     def fp8_scales(self, opt_state=None) -> Pytree:
         """Per-leaf pytree of the current fp8 weight scales (scalar
@@ -572,26 +637,32 @@ class FusedOptimizerBase:
                                     step, grad_scale, hypers, found_inf)
 
     def _state_is_packed(self, opt_state) -> bool:
-        """True only for the plan's OWN packed layout: every field is a
-        per-bucket list whose buffers structurally match the plan (1-D,
-        bucket-sized flat or per-leaf-scalar vector).  A per-leaf state
-        pytree that merely happens to be a list of the right length
-        (e.g. list-shaped params) must not be mistaken for packed."""
+        """True only for the plan's OWN packed layout: a
+        ``PackedState`` (what this optimizer builds and hands out), or
+        a dict whose every field is a per-bucket list whose buffers
+        structurally match the plan (the bucket's shape, or a
+        per-leaf-scalar vector) and is not a tree of the plan.  A
+        per-leaf state pytree that merely happens to be a list of the
+        right length (list-shaped params) must not be mistaken for
+        packed: where each leaf sits alone in a bucket of its shape,
+        only the type tells the two apart (``reads_as_tree``)."""
         if self._plan is None or not isinstance(opt_state, dict) \
                 or not opt_state:
             return False
+        if isinstance(opt_state, PackedState):
+            return True
         buckets = self._plan.buckets
         for field in opt_state.values():
             if not isinstance(field, (list, tuple)) \
-                    or len(field) != len(buckets):
+                    or len(field) != len(buckets) \
+                    or self._plan.reads_as_tree(field):
                 return False
             for buf, b in zip(field, buckets):
                 if getattr(buf, "ndim", None) == 2 \
                         and buf.shape[0] == len(b.leaves):
                     continue    # row-stacked per-leaf vectors (fp8)
-                if getattr(buf, "ndim", None) != 1:
-                    return False
-                if tuple(buf.shape) not in ((b.size,), (len(b.leaves),)):
+                if tuple(getattr(buf, "shape", ())) not in (
+                        b.shape, (len(b.leaves),)):
                     return False
         return True
 
@@ -631,7 +702,7 @@ class FusedOptimizerBase:
                 found_inf = grads.found_inf
             if clip_coef is None:
                 clip_coef = getattr(grads, "clip_coef", None)
-            grads = grads.bufs
+            grads = self._bundled(grads)
         gs = _fold_clip(grad_scale, clip_coef)
         hypers = dict(self.hypers)
         if packed:
@@ -642,7 +713,8 @@ class FusedOptimizerBase:
             new_bufs, new_state, _ = self._flat_step_math(
                 work_bufs, grad_bufs, opt_state, step, gs, hypers,
                 _keep_flag(found_inf))
-            return self._plan.unpack(new_bufs), new_state
+            # the state comes back in the container it came in
+            return self._plan.unpack(new_bufs), type(opt_state)(new_state)
         new_params, new_state = self._step_math(
             params, grads, opt_state, step, gs, hypers)
         if found_inf is not None:
@@ -688,7 +760,7 @@ class FusedOptimizerBase:
                 found_inf = grads.found_inf
             if clip_coef is None:
                 clip_coef = getattr(grads, "clip_coef", None)
-            grads = grads.bufs
+            grads = self._bundled(grads)
         with span("apex/optim/clock"):
             self.step_count = self.step_count + 1
         with span("apex/optim/args"):
@@ -723,6 +795,16 @@ class FusedOptimizerBase:
                                             self.step_count)
         return self.params
 
+    def _bundled(self, grads):
+        """An ``amp.FlatGrads`` bundle's buffers, packed by
+        construction, as the step reads them: the tree of them where
+        the plan's tree has the structure of its buffers
+        (``BucketPlan.reads_as_tree``; a reorder, no copy)."""
+        bufs = grads.bufs
+        if self._plan.reads_as_tree(bufs):
+            return self._plan.unpack_grads(bufs)
+        return bufs
+
     def _replicated_step(self, mesh):
         """The step program for gradients that arrive replicated over
         ``mesh`` — the data-parallel layout: every device holds the
@@ -749,9 +831,13 @@ class FusedOptimizerBase:
             k: jnp.asarray(v, jnp.float32) if isinstance(v, float) else v
             for k, v in self.hypers.items()
             if isinstance(v, (int, float)) and not isinstance(v, bool)}
-        params, masters = ((self._param_bufs, self._master_bufs)
-                           if self._plan is not None else
-                           (self._params_tree, self._masters_tree))
+        if self._plan is not None:
+            shared = self._shared
+            params = [None if bi in shared else b
+                      for bi, b in enumerate(self._param_bufs)]
+            masters = self._master_bufs
+        else:
+            params, masters = self._params_tree, self._masters_tree
         return (params, masters, self.opt_state, grads, self.step_count,
                 jnp.asarray(grad_scale, jnp.float32), traced_hypers,
                 found_inf)
@@ -804,6 +890,7 @@ class FusedOptimizerBase:
                 "True and a tree the packer accepted)")
         if max_bucket_bytes == self._plan.max_bucket_bytes:
             return False
+        self._held_model_bufs()
         params = self.params              # cached lazy unpack
         masters = self.masters
         state = self.opt_state
@@ -816,13 +903,14 @@ class FusedOptimizerBase:
             work, params if masters is not None else None,
             max_bucket_bytes=max_bucket_bytes)
         self._adopt(params, masters)
-        self.opt_state = {
-            k: _own_pack(self._plan.pack_state_field(v), v)
-            for k, v in state_trees.items()}
+        self.opt_state = PackedState({
+            k: _apart(self._plan.pack_state_field(v), v)
+            for k, v in state_trees.items()})
         if self.offload_state:
             self.opt_state = place_on_host(self.opt_state)
         # fresh jit: the step body closes over the plan
         self._jit_step = self._build_jit_step()
+        self._emit_plan_counters()
         return True
 
     # ---- bucket-native checkpoint capture --------------------------------
@@ -857,7 +945,8 @@ class FusedOptimizerBase:
             "step": int(self.step_count),
             "hypers": dict(self.hypers),
             "plan": self._plan,
-            "param_bufs": [_device_copy(b) for b in self._param_bufs],
+            "param_bufs": [_device_copy(b)
+                           for b in self._held_model_bufs()],
             "master_bufs": ([_device_copy(b) for b in self._master_bufs]
                             if self._master_bufs is not None else None),
             "state": {k: [_device_copy(b) for b in v]
@@ -869,66 +958,69 @@ class FusedOptimizerBase:
         """Inverse of :meth:`packed_snapshot` — adopt packed buffers
         directly (one host->device put per bucket, zero per-leaf
         traffic).  Buffers may be numpy (fresh from a checkpoint read)
-        or jax arrays; the caller has already validated the layout
-        against this optimizer's plan (checkpoint.py compares the v2
-        header's plan doc with ``self._plan.layout()``)."""
+        or jax arrays, flat or in the buckets' shapes; the caller has
+        already validated the layout against this optimizer's plan
+        (checkpoint.py compares the v2 header's plan doc with
+        ``self._plan.layout()``)."""
         if self._plan is None:
             raise ValueError(
                 "load_packed_snapshot requires the bucketed path")
         self.step_count = jnp.int32(step)
         self.hypers.update(hypers)
-        # copied in: the step donates what it is given here, and the
-        # caller's snapshot must stay readable (and loadable again)
-        self._param_bufs = [_owned(b) for b in param_bufs]
-        self._master_bufs = ([_owned(b) for b in master_bufs]
-                             if master_bufs is not None else None)
-        self._params_cache = None
-        self._masters_cache = None
-        # the v2 payload stores every state buffer flattened; a
-        # non-flat slot (the fp8 (n_leaves, H) amax history) adopts the
-        # LIVE slot's shape back — same element count, the layout
-        # check upstream already matched the plan
-        old = self.opt_state
-
-        def _shaped(b, o):
-            # metadata-only reshape (numpy and jax alike): never a
-            # copy, never an extra device placement
-            want = (tuple(o.shape)
-                    if o is not None and hasattr(o, "shape") else None)
+        # the v2 payload stores every buffer flattened, and a snapshot
+        # may come from a writer that held a leaf-shaped bucket flat: a
+        # buffer takes the shape its live slot has (a leaf-shaped
+        # bucket's, the fp8 (n_leaves, H) amax history's) — same
+        # element count, the layout check upstream already matched the
+        # plan
+        def _shaped(b, want):
+            # a reshape of host data is metadata only
             if want is not None \
-                    and tuple(getattr(b, "shape", ())) != want \
-                    and getattr(b, "size", None) == o.size:
+                    and tuple(getattr(b, "shape", ())) != tuple(want) \
+                    and getattr(b, "size", None) == math.prod(want):
                 b = b.reshape(want)
             return b
 
+        shapes = [b.shape for b in self._plan.buckets]
+        # copied in: the step donates what it is given here, and the
+        # caller's snapshot must stay readable (and loadable again)
+        self._param_bufs = [_owned(_shaped(b, want))
+                            for b, want in zip(param_bufs, shapes)]
+        self._master_bufs = ([_owned(_shaped(b, want))
+                              for b, want in zip(master_bufs, shapes)]
+                             if master_bufs is not None else None)
+        self._params_cache = None
+        self._masters_cache = None
+        old = self.opt_state
         if self.offload_state:
             # adopt each buffer straight onto the existing (host)
             # placement — asarray-then-place_on_host would stage the
             # whole state in HBM, the state-size spike offloading
             # exists to avoid (the load_state_dict mirror of the
             # packed_snapshot in-place rule)
-            self.opt_state = {
-                k: [jax.device_put(_shaped(b, o), o.sharding)
+            self.opt_state = PackedState({
+                k: [jax.device_put(_shaped(b, o.shape), o.sharding)
                     for b, o in zip(v, old[k])]
-                for k, v in state.items()}
+                for k, v in state.items()})
         else:
-            self.opt_state = {
-                k: [_owned(_shaped(b, o))
+            self.opt_state = PackedState({
+                k: [_owned(_shaped(b, getattr(o, "shape", None)))
                     for b, o in zip(v, old.get(k, [None] * len(v)))]
-                for k, v in state.items()}
+                for k, v in state.items()})
 
     # ---- serialization (torch Optimizer.state_dict shape) ---------------
     def state_dict(self):
         if self._plan is not None:
             # unpack to the per-leaf checkpoint layout (unchanged across
             # packing, so per-leaf and bucketed optimizers interload).
-            # The slices are fresh buffers — safe against the next
-            # step()'s donation of the packed state.
+            # The slices are fresh buffers, and a leaf-shaped bucket's
+            # leaf is copied — safe against the next step()'s donation
+            # of the packed state.
             state = self.opt_state
             if self.offload_state:
                 state = place_on_device(state)
-            state_tree = {k: self._plan.unpack_state_field(v)
-                          for k, v in state.items()}
+            state_tree = _apart({k: self._plan.unpack_state_field(v)
+                                 for k, v in state.items()}, state)
             return {
                 "step": int(self.step_count),
                 "hypers": dict(self.hypers),
@@ -954,9 +1046,9 @@ class FusedOptimizerBase:
         if self._plan is not None:
             # per-leaf checkpoint layout -> packed buffers of our own:
             # the checkpoint dict is never aliased by the donating step
-            self.opt_state = {
-                k: _own_pack(self._plan.pack_state_field(v), v)
-                for k, v in sd["state"].items()}
+            self.opt_state = PackedState({
+                k: _apart(self._plan.pack_state_field(v), v)
+                for k, v in sd["state"].items()})
         else:
             # copy: step() donates opt_state to the compiled update, and
             # the caller's checkpoint dict must stay readable after we

@@ -116,7 +116,10 @@ def _reduce_one_flat_buffer(b, axis_name, world, pre, post,
     if pre != 1.0:
         bf = bf / pre
     if decompose == "reduce_scatter" and world > 1:
-        n = bf.shape[0]
+        # scattered flat: a leaf-shaped bucket (BucketPlan) is raveled
+        # for the collective and takes its shape back after it
+        shape, n = bf.shape, bf.size
+        bf = jnp.ravel(bf)
         pad = (-n) % world
         if pad:
             bf = jnp.pad(bf, (0, pad))
@@ -125,6 +128,7 @@ def _reduce_one_flat_buffer(b, axis_name, world, pre, post,
         bf = jax.lax.all_gather(bf, axis_name, axis=0, tiled=True)
         if pad:
             bf = jax.lax.slice(bf, (0,), (n,))
+        bf = bf.reshape(shape)
     else:
         bf = jax.lax.psum(bf, axis_name)
     if post != 1.0:

@@ -8,7 +8,8 @@ Contracts:
     (constructor, setters, ``load_state_dict``, ``load_packed_snapshot``)
     is copied in, whatever it hands out stays readable across steps —
     a one-leaf bucket of a flat leaf, which packs to the leaf itself,
-    among the cases;
+    among the cases; with masters, that bucket's model-dtype buffer is
+    the leaf ``params`` hands out, and the step does not donate it;
   * a snapshot taken before a step resumes to the same bits;
   * a skipped step writes the old bits back through the aliased buffers;
   * construction builds the state packed and keeps no masters' tree.
@@ -78,8 +79,16 @@ def _packed(opt):
 
 def _donated(opt):
     """The packed buffers the step donates: all of them, but for
-    FusedLAMB, which keeps its work buffers out (fused_lamb.py)."""
-    held = (opt._param_bufs, opt._master_bufs, opt.opt_state)
+    FusedLAMB, which keeps its work buffers out (fused_lamb.py), and
+    with masters the model-dtype buffer of the one-leaf bucket, which
+    is the leaf ``params`` hands out (``_shared``)."""
+    shared = opt._shared
+    assert shared == (() if opt._master_bufs is None else tuple(
+        bi for bi, b in enumerate(opt._plan.buckets)
+        if len(b.leaves) == 1))
+    params = [b for bi, b in enumerate(opt._param_bufs)
+              if bi not in shared]
+    held = (params, opt._master_bufs, opt.opt_state)
     donated = opt._donation["donate_argnums"]
     work = 0 if opt._master_bufs is None else 1
     assert donated == tuple(
@@ -227,10 +236,12 @@ def test_what_the_optimizer_hands_out_stays_readable(name, dtype):
         out.append(opt.step(_grads(params, 6 + i)))
     _readable(out)
     _same_bits(out[:5], want)
-    # no view handed out is a packed buffer under another name
-    packed = {id(b) for b in _packed(opt)}
-    assert not packed & {id(x) for x in jax.tree_util.tree_leaves(
+    # no view handed out is a buffer the step donates; with masters
+    # the one-leaf bucket's leaf IS its model-dtype buffer (one copy)
+    shown = {id(x) for x in jax.tree_util.tree_leaves(
         (opt.params, opt.masters))}
+    assert not {id(b) for b in _donated(opt)} & shown
+    assert {id(opt._param_bufs[bi]) for bi in opt._shared} <= shown
 
 
 # ---- snapshot, step, resume ------------------------------------------------

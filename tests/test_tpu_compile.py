@@ -419,6 +419,86 @@ def test_adam_bucket_step_is_one_sweep(one_chip):
     assert stats.temp_size_in_bytes < 2 ** 20
 
 
+def test_adam_step_keeps_a_one_leaf_bucket_in_its_shape(one_chip):
+    """The looped decoder's embedding table, (49152, 2048) bfloat16
+    with float32 masters: alone in its 128 MiB-capped bucket, its Adam
+    step is one fusion over the leaf's own shape, with no copy that
+    re-tiles the table into a flat buffer, under 1 MB of scratch, and
+    masters and moments updated in place.  The optimizer is built over
+    a one-element stand-in and given the plan of the real shape: its
+    step program reads the plan and the hypers, never the arrays it
+    was built on."""
+    from apex_tpu.multi_tensor_apply.packer import BucketPlan
+    from apex_tpu.optimizers import FusedAdam
+    shape = (49152, 2048)
+    n = shape[0] * shape[1]
+    opt = FusedAdam({"table": jnp.zeros((1, 1), BF16)}, lr=3e-4,
+                    beta1=0.9, beta2=0.95, weight_decay=0.1,
+                    master_weights=True, max_bucket_bytes=128 << 20)
+    opt._plan = BucketPlan.from_tree(
+        {"table": jax.ShapeDtypeStruct(shape, F32)},
+        {"table": jax.ShapeDtypeStruct(shape, BF16)},
+        max_bucket_bytes=128 << 20)
+    assert opt._shared == (0,)      # the step neither reads nor donates it
+
+    def sds(dtype, s=shape):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    args = ([None], [sds(F32)],
+            {k: [sds(F32)] for k in opt.opt_state}, {"table": sds(BF16)},
+            sds(I32, ()), sds(F32, ()),
+            {k: sds(F32, ()) for k, v in opt.hypers.items()
+             if isinstance(v, float) and not isinstance(v, bool)},
+            sds(I32, ()))
+    compiled = opt._jit_step.lower(*args).compile()
+    ops = _bucket_sized_ops(compiled.as_text(), n)
+    assert sum(ops.values()) == 1 and not ops.get("copy"), ops
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == 12 * n
+    assert stats.temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("stage", ["accumulate", "finalize"])
+def test_flat_pipeline_tiles_a_one_leaf_bucket_in_its_shape(one_chip,
+                                                            stage):
+    """The flat AMP pipeline over the looped decoder's table alone in
+    its bucket, (49152, 2048): its Pallas kernels tile the bucket in
+    its own shape, so neither a microbatch's accumulation (bf16
+    gradient into the donated f32 accumulator, in place) nor the
+    finalize's unscale-and-norm re-tiles it through a bucket-sized
+    copy."""
+    from apex_tpu import amp
+    from apex_tpu.amp.flat_pipeline import GradAccum
+    from apex_tpu.multi_tensor_apply.packer import BucketPlan
+    shape = (49152, 2048)
+    n = shape[0] * shape[1]
+    plan = BucketPlan.from_tree({"table": jax.ShapeDtypeStruct(shape, F32)},
+                                {"table": jax.ShapeDtypeStruct(shape, BF16)},
+                                max_bucket_bytes=128 << 20)
+    assert plan.buckets[0].leaf_shaped
+    pipe = amp.FlatGradPipeline(plan=plan)
+
+    def sds(dtype, s=shape):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    acc = GradAccum(bufs=[sds(F32)], found_inf=sds(I32, ()),
+                    count=sds(I32, ()))
+    if stage == "accumulate":
+        fn = jax.jit(pipe.accumulate, donate_argnums=0)
+        compiled = fn.lower(acc, {"table": sds(BF16)}).compile()
+    else:
+        fn = jax.jit(lambda a: pipe.finalize(a, inv_scale=2.0 ** -16))
+        compiled = fn.lower(acc).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    ops = _bucket_sized_ops(text, n)
+    kernel = ("apex_multi_tensor_accumulate" if stage == "accumulate"
+              else "apex_multi_tensor_unscale_norm")
+    assert ops == {kernel: 1}, ops
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 2 ** 20
+    if stage == "accumulate":
+        assert stats.alias_size_in_bytes >= 4 * n
+
+
 def test_sgd_bucket_step_is_one_sweep(one_chip):
     """ResNet-50's parameters as one bucket under the bucketed SGD
     step: one fusion reads p, g and the momentum buffer and writes p,
